@@ -23,6 +23,7 @@ import numpy as np
 
 from repro.index import scoring
 from repro.index.corpus import Corpus
+from repro.kernels.blocks import LANE_CHUNKS, mirror_tiles
 
 
 @dataclass
@@ -110,14 +111,25 @@ def _per_term_stats(term_ids, scores, offsets, df, vocab):
     hmean = nz / np.maximum(sinv, 1e-12)
     std = np.sqrt(np.maximum(s2 / nz - amean ** 2, 0.0))
 
-    # max + median from a per-term sort
-    order = np.lexsort((shifted, term_ids))
-    sorted_s = shifted[order]
+    # max + median from a per-term sort.  shifted > 0, so the bits of its
+    # float32 rounding order like the value: one in-place sort of int64
+    # (term, value) keys yields every term's ascending values.  Rounding to
+    # float32 first is exact here — the order statistics commute with a
+    # monotone rounding, and the table is float32.
+    keys = term_ids.astype(np.int64)
+    keys <<= 32
+    keys |= shifted.astype(np.float32).view(np.int32)
+    del shifted
+    keys.sort()
+
+    def value_at(i):
+        return (keys[i] & 0xFFFFFFFF).astype(np.uint32).view(np.float32)
+
     has = df > 0
     last = np.maximum(offsets[1:] - 1, 0)
-    mx = np.where(has, sorted_s[np.minimum(last, len(sorted_s) - 1)], 0.0)
+    mx = np.where(has, value_at(np.minimum(last, len(keys) - 1)), 0.0)
     mid = offsets[:-1] + np.maximum((df - 1) // 2, 0)
-    med = np.where(has, sorted_s[np.minimum(mid, len(sorted_s) - 1)], 0.0)
+    med = np.where(has, value_at(np.minimum(mid, len(keys) - 1)), 0.0)
 
     cols = np.stack([mx, amean, gmean, hmean, med, std], axis=1)
     return np.where(has[:, None], cols, 0.0).astype(np.float32)
@@ -135,6 +147,8 @@ def pack_tiles(docs: np.ndarray, terms: np.ndarray,
     tile, doc ids are rebased to be tile-local, and each bucket is padded to
     a common lane-aligned ``cap`` so the whole structure is a dense
     ``(n_tiles, cap)`` array the kernels can view with zero per-query copies.
+    ``n_tiles`` is rounded up to whole kernel tile groups with dead tiles
+    (doc -1), so the kernels never pad the mirror at serve time.
 
     The one tiling helper shared by the sealed build, the append-only delta
     tile-set, and the merge re-tile.
@@ -144,9 +158,11 @@ def pack_tiles(docs: np.ndarray, terms: np.ndarray,
       terms: (P,) term id of each posting.
       values: per-posting payload columns as (array, fill, dtype) tuples
         (e.g. exact scores, quantized impacts).
-      n_docs: shard size (defines the tile count).
+      n_docs: shard size (defines the tile count, ``mirror_tiles``).
       tile_d: docs per tile; must match the kernels' accumulator tile.
-      lane_multiple: pad cap to a multiple of this (TPU lane width).
+      lane_multiple: pad cap to a multiple of this (TPU lane width); a
+        data-derived cap above the widest kernel lane chunk
+        (``repro.kernels.blocks.LANE_CHUNKS``) pads to a multiple of that.
       tile_cap: pin the lane capacity to this static value instead of the
         data-derived one — the delta tile-set passes its postings capacity so
         every rebuild keeps a single jit signature as documents stream in.
@@ -157,7 +173,7 @@ def pack_tiles(docs: np.ndarray, terms: np.ndarray,
       is (n_tiles, cap) int32 with -1 padding, and ``bucketed_values`` is a
       list of (n_tiles, cap) arrays in ``values`` order.
     """
-    n_tiles = max(1, -(-n_docs // tile_d))
+    n_tiles = mirror_tiles(n_docs, tile_d)
     p = len(docs)
     tile = (docs // tile_d).astype(np.int64)
     counts = np.bincount(tile, minlength=n_tiles)
@@ -167,8 +183,15 @@ def pack_tiles(docs: np.ndarray, terms: np.ndarray,
         if tile_cap < cap:
             raise ValueError(f"tile_cap={tile_cap} below required cap={cap}")
         cap = tile_cap
+    elif cap > LANE_CHUNKS[0]:
+        # whole chunks: the kernels stream the lane axis in chunks of the
+        # widest width that divides cap (repro.kernels.blocks.lane_chunk)
+        cap = -(-cap // LANE_CHUNKS[0]) * LANE_CHUNKS[0]
 
-    order = np.argsort(tile, kind="stable")   # keeps (term, doc) order in-tile
+    # stable keeps (term, doc) order in-tile; 16-bit keys take numpy's
+    # radix sort
+    key = tile.astype(np.int16) if n_tiles <= np.iinfo(np.int16).max else tile
+    order = np.argsort(key, kind="stable")
     tsort = tile[order]
     starts = np.zeros(n_tiles + 1, np.int64)
     np.cumsum(counts, out=starts[1:])
@@ -193,7 +216,10 @@ def impact_order_layout(term: np.ndarray, doc: np.ndarray,
     per-shard slicer: the per-term impact-descending (doc-ascending within a
     level) permutation plus the (V, 256) cumulative level table
     ``level_cum[t, l] = # postings of t with impact >= l``."""
-    order = np.lexsort((doc, -impact.astype(np.int32), term))
+    # one int64 key (term, impact desc, doc) == the 3-key lexsort order
+    key = ((term.astype(np.int64) << 40)
+           | ((255 - impact.astype(np.int64)) << 32) | doc.astype(np.int64))
+    order = np.argsort(key, kind="stable")
     lvl = np.bincount(term.astype(np.int64) * 256 + impact,
                       minlength=vocab * 256).reshape(vocab, 256)
     level_cum = np.flip(np.cumsum(np.flip(lvl, axis=1), axis=1),
@@ -241,6 +267,7 @@ def assemble_index(term: np.ndarray, doc: np.ndarray, tf: np.ndarray,
 
     sims = scoring.all_similarity_scores(tf, df_p, cf_p, dl, score_n, avg_dl,
                                          total_tokens)  # (P, 6)
+    del dl, df_p, cf_p
     bm25_sc = sims[:, 1].astype(np.float32)
     impact, qmax = scoring.quantize_impacts(bm25_sc, n_levels, smax=smax)
 

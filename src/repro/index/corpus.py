@@ -63,6 +63,59 @@ def _zipf_probs(vocab: int, a: float) -> np.ndarray:
     return (p / p.sum()).astype(np.float64)
 
 
+def _map_draws(rng, n: int, width: tuple, work, chunk: int) -> None:
+    """Draw ``n`` uniform rows of shape ``width`` from ``rng`` in order, chunk
+    by chunk — the same stream as one ``random_sample`` — and run
+    ``work(lo, u)`` on each chunk, so memory stays O(chunk) at any
+    collection size."""
+    for lo in range(0, n, chunk):
+        work(lo, rng.random_sample((min(chunk, n - lo),) + width))
+
+
+def _zipf_draw(rng, cdf: np.ndarray, n: int) -> np.ndarray:
+    """``n`` inverse-CDF draws from ``cdf`` (int32 ids)."""
+    out = np.empty(n, np.int32)
+
+    def draw(lo, u):
+        out[lo:lo + len(u)] = np.minimum(np.searchsorted(cdf, u),
+                                         len(cdf) - 1)
+
+    _map_draws(rng, n, (), draw, 1 << 22)
+    return out
+
+
+def _bernoulli(rng, p: float, n: int) -> np.ndarray:
+    """``n`` draws of ``uniform < p``."""
+    out = np.empty(n, bool)
+
+    def draw(lo, u):
+        out[lo:lo + len(u)] = u < p
+
+    _map_draws(rng, n, (), draw, 1 << 22)
+    return out
+
+
+def _gumbel_topics(rng, doc_topics: np.ndarray,
+                   docs: np.ndarray) -> np.ndarray:
+    """Gumbel-max topic draw per token from its doc's topic mixture."""
+    out = np.empty(len(docs), np.int32)
+
+    def topics(lo, u):
+        logits = np.log(doc_topics[docs[lo:lo + len(u)]])
+        # gumbel = -log(-log(u + 1e-12) + 1e-12), in place on u
+        u += 1e-12
+        np.log(u, out=u)
+        np.negative(u, out=u)
+        u += 1e-12
+        np.log(u, out=u)
+        np.negative(u, out=u)
+        u += logits
+        out[lo:lo + len(u)] = np.argmax(u, axis=1)
+
+    _map_draws(rng, len(docs), (doc_topics.shape[1],), topics, 1 << 18)
+    return out
+
+
 def build_corpus(params: CorpusParams = CorpusParams()) -> Corpus:
     rng = np.random.RandomState(params.seed)
     n, v, k = params.n_docs, params.vocab, params.n_topics
@@ -84,20 +137,15 @@ def build_corpus(params: CorpusParams = CorpusParams()) -> Corpus:
     tok_doc = np.repeat(np.arange(n, dtype=np.int32), doclen)
 
     # background terms: inverse-CDF Zipf sampling
-    u = rng.random_sample(total)
-    tok_term = np.searchsorted(cdf, u).astype(np.int32)
-    tok_term = np.minimum(tok_term, v - 1)
+    tok_term = _zipf_draw(rng, cdf, total)
 
     # topical terms: topic id per token (gumbel-max over doc mixture), then a
     # topic-permuted Zipf draw so each topic concentrates on its own terms
-    topical = rng.random_sample(total) < params.topical_fraction
+    topical = _bernoulli(rng, params.topical_fraction, total)
     n_topical = int(topical.sum())
-    logits = np.log(doc_topics[tok_doc[topical]])
-    gumbel = -np.log(-np.log(rng.random_sample((n_topical, k)) + 1e-12) + 1e-12)
-    tok_topic = np.argmax(logits + gumbel, axis=1).astype(np.int32)
+    tok_topic = _gumbel_topics(rng, doc_topics, tok_doc[topical])
     topic_perm = np.stack([rng.permutation(v).astype(np.int32) for _ in range(k)])
-    base_draw = np.minimum(
-        np.searchsorted(cdf, rng.random_sample(n_topical)), v - 1)
+    base_draw = _zipf_draw(rng, cdf, n_topical)
     tok_term[topical] = topic_perm[tok_topic, base_draw]
 
     # URL-style docid reordering (Silvestri 2007; the paper's §2 notes this
@@ -113,7 +161,10 @@ def build_corpus(params: CorpusParams = CorpusParams()) -> Corpus:
     doc_topics = doc_topics[order]
 
     # aggregate to postings: unique (term, doc) with counts
-    key = tok_term.astype(np.int64) * n + tok_doc.astype(np.int64)
+    key = tok_term.astype(np.int64)
+    key *= n
+    key += tok_doc
+    del tok_term, tok_doc, topical
     uniq, counts = np.unique(key, return_counts=True)
     postings_term = (uniq // n).astype(np.int32)
     postings_doc = (uniq % n).astype(np.int32)
@@ -190,10 +241,7 @@ def synthesize_feed_docs(corpus: Corpus, n_docs: int,
 
     topical = rng.random_sample(total) < p.topical_fraction
     n_topical = int(topical.sum())
-    logits = np.log(doc_topics[tok_doc[topical]])
-    gumbel = -np.log(-np.log(rng.random_sample((n_topical, k)) + 1e-12)
-                     + 1e-12)
-    tok_topic = np.argmax(logits + gumbel, axis=1).astype(np.int32)
+    tok_topic = _gumbel_topics(rng, doc_topics, tok_doc[topical])
     base_draw = np.minimum(
         np.searchsorted(cdf, rng.random_sample(n_topical)), v - 1)
     tok_term[topical] = corpus.topic_perm[tok_topic, base_draw]

@@ -47,7 +47,7 @@ class IndexShardSpec(NamedTuple):
     quant_scale: float
     tile_d: int            # docs per bucketed serving tile
     tile_cap: int          # lane-padded postings capacity per tile
-    n_tiles: int
+    n_tiles: int           # bucketed mirror rows (whole tile groups)
 
 
 class IndexShard(NamedTuple):
@@ -146,10 +146,16 @@ def shard_from_index(index: InvertedIndex, doc_lo: int = 0,
     docs = d
     score = s
 
-    # impact-ordered: per-term sort by impact desc
-    order, level_cum = impact_order_layout(t, d, im, v)
-    docs_imp = d[order]
-    imp = im[order]
+    # impact-ordered: per-term sort by impact desc (a shard covering the
+    # whole index has exactly the index's own layout)
+    if n_local == index.n_docs:
+        docs_imp = index.docs_imp.astype(np.int32)
+        imp = index.imp_sorted.astype(np.int32)
+        level_cum = index.level_cum
+    else:
+        order, level_cum = impact_order_layout(t, d, im, v)
+        docs_imp = d[order]
+        imp = im[order]
 
     # sparse block-max
     if len(d):
@@ -185,7 +191,6 @@ def shard_from_index(index: InvertedIndex, doc_lo: int = 0,
             tile_cap=tile_cap)
 
     n_blocks = (n_local + bs - 1) // bs
-    n_tiles = max(1, (n_local + tile_d - 1) // tile_d)
     spec = IndexShardSpec(
         n_docs=n_local, vocab=v, n_postings=len(docs), n_blocks=n_blocks,
         n_block_entries=len(b_id), n_levels=256, block_size=bs,
@@ -195,7 +200,7 @@ def shard_from_index(index: InvertedIndex, doc_lo: int = 0,
                              if max_blocks_per_term is not None
                              else int(bm_df.max()) if len(bm_df) else 1),
         quant_scale=index.quant_scale,
-        tile_d=tile_d, tile_cap=tcap, n_tiles=n_tiles)
+        tile_d=tile_d, tile_cap=tcap, n_tiles=tile_docs.shape[0])
 
     shard = IndexShard(
         df=jnp.asarray(df),

@@ -12,6 +12,7 @@ from __future__ import annotations
 import numpy as np
 
 LOG2E = np.log2(np.e)
+SCORE_CHUNK = 1 << 22      # postings scored per pass
 
 
 def bm25(tf, df, dl, n_docs, avg_dl, k1=0.9, b=0.4):
@@ -52,16 +53,21 @@ def pl2(tf, cf, dl, n_docs, avg_dl, c=1.0):
 
 def all_similarity_scores(tf, df, cf, dl, n_docs, avg_dl, total_tokens):
     """(P, 6) score matrix for flat postings, column order matching
-    repro.core.features.SIM_NAMES."""
-    cols = [
-        tfidf(tf, df, dl, n_docs, avg_dl),
-        bm25(tf, df, dl, n_docs, avg_dl),
-        ql_dirichlet(tf, cf, dl, total_tokens),
-        bose_einstein(tf, cf, n_docs),
-        dph(tf, cf, dl, n_docs, avg_dl),
-        pl2(tf, cf, dl, n_docs, avg_dl),
-    ]
-    return np.stack([c.astype(np.float32) for c in cols], axis=1)
+    repro.core.features.SIM_NAMES.
+
+    Every score is elementwise in its posting, so postings are scored in
+    chunks — the same result as one pass, with O(SCORE_CHUNK) temporaries."""
+    out = np.empty((len(tf), 6), np.float32)
+    for lo in range(0, len(tf), SCORE_CHUNK):
+        s = slice(lo, lo + SCORE_CHUNK)
+        t, d, c, l_ = tf[s], df[s], cf[s], dl[s]
+        out[s, 0] = tfidf(t, d, l_, n_docs, avg_dl)
+        out[s, 1] = bm25(t, d, l_, n_docs, avg_dl)
+        out[s, 2] = ql_dirichlet(t, c, l_, total_tokens)
+        out[s, 3] = bose_einstein(t, c, n_docs)
+        out[s, 4] = dph(t, c, l_, n_docs, avg_dl)
+        out[s, 5] = pl2(t, c, l_, n_docs, avg_dl)
+    return out
 
 
 def quantize_impacts(scores: np.ndarray, n_levels: int = 255,

@@ -14,13 +14,18 @@ a backend switch (see ``repro.isn.backend``):
 
 * ``"pallas"`` / ``"interpret"`` — the exact pass runs on
   ``repro.kernels.blockmax_score`` over the shard's **build-time bucketed
-  postings mirror** (``IndexShard.tile_*``): a (Q, n_tiles) grid where each
-  step term-matches one doc-tile bucket against one query and reduces with
-  a one-hot MXU matmul.  Pruned tiles are *skipped via predication*
-  (``pl.when``), so latency is proportional to surviving work — which is
-  precisely why DAAT keeps its data-dependent tail (the paper's Fig. 3)
-  while budgeted SAAT does not.  ``interpret=True`` runs the identical
-  kernel program under the Pallas interpreter on CPU (tests).
+  postings mirror** (``IndexShard.tile_*``): a (query blocks, tile groups,
+  lane chunks) grid where each step term-matches doc-tile buckets against a
+  block of queries and reduces with a one-hot MXU matmul.  A tile pruned
+  for every query of the block (up to 64 queries,
+  ``repro.kernels.blocks.query_rows``) skips its matmul (``pl.when``), so
+  the kernel's time follows the union of the block's surviving tiles, not
+  each query's own.  The per-query ``work``/``blocks`` counters that
+  ``CostModel`` charges model a per-query DAAT engine — the data-dependent
+  tail of the paper's Fig. 3 that budgeted SAAT does not have; how closely
+  the compiled kernel follows them is not measured.  ``interpret=True``
+  runs the identical kernel program under the Pallas interpreter on CPU
+  (tests).
 * ``"jnp"`` — vectorized batched gather + one fused scatter over the CSR
   mirror; identical results, the portable fast path on CPU hosts.
 

@@ -17,8 +17,9 @@ Queries are served as a batch through a backend switch
 
 * ``"pallas"`` / ``"interpret"`` — the accumulation dispatches through
   ``repro.kernels.impact_accumulate`` over the shard's build-time bucketed
-  postings mirror (``IndexShard.tile_*``): a (Q, n_tiles) grid, one doc
-  tile per step, term matching in-register, one-hot MXU matmul reduction.
+  postings mirror (``IndexShard.tile_*``): a (query blocks, tile groups,
+  lane chunks) grid, term matching in-register, one-hot MXU matmul
+  reduction shared by the block's queries.
   The level cut rides in as the per-query scalar ``lstar``.
   ``interpret=True`` runs the identical kernel program on CPU (tests).
 * ``"jnp"`` — vectorized batched gather of the per-term impact-ordered

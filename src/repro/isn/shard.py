@@ -21,13 +21,14 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.index.postings import IndexShard
 from repro.isn.daat import daat_serve
 from repro.isn.saat import saat_serve
+from repro.kernels.blocks import mirror_tiles
 
 SDS = jax.ShapeDtypeStruct
 
@@ -128,7 +129,7 @@ def hybrid_serve_fn(mesh, *, n_docs_shard: int, n_model: int, k_shard: int,
                 P(*qspec, None) if qspec else P(None, None))
     out_specs = (P(*qspec, None), P(*qspec, None), P(*qspec), P(*qspec))
     return shard_map(serve, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+                     out_specs=out_specs, check_vma=False)
 
 
 def _stacked_index_specs(cfg, n_model: int):
@@ -136,7 +137,7 @@ def _stacked_index_specs(cfg, n_model: int):
     v, p, pb = cfg.vocab, cfg.postings_per_shard, cfg.block_entries_per_shard
     m = n_model
     n_docs_shard = cfg.n_docs // n_model
-    nt = max(1, -(-n_docs_shard // cfg.tile_d))
+    nt = mirror_tiles(n_docs_shard, cfg.tile_d)
     tc = cfg.tile_cap
 
     def s(shape, dt=jnp.int32):

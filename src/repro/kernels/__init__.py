@@ -8,22 +8,30 @@ Serving kernels share one **bucketed postings layout**: at index-build
 time every posting of a shard is tiled into the ``(n_tiles, tile_cap)``
 bucket of its ``tile_d``-doc tile (``IndexShard.tile_docs/terms/scores/
 imps`` — see ``repro.index.postings``), doc ids rebased tile-locally and
-buckets lane-padded.  A batched kernel then runs a (Q, n_tiles) grid: the
-tile buckets are indexed by the tile coordinate only, so the whole query
-batch reads the same shard-resident blocks zero-copy; term matching
-happens in-register and each step reduces one bucket into a
-``(1, tile_d)`` accumulator tile with a one-hot MXU matmul.
+buckets lane-padded.  A batched kernel then runs a (query blocks, tile
+groups, lane chunks) grid: the tile buckets are indexed by the tile
+coordinates only, so the whole query batch reads the same shard-resident
+blocks zero-copy; term matching happens in-register and each step reduces
+a lane chunk of a few buckets into a ``(query rows, tile_d)`` accumulator
+tile per bucket with a one-hot MXU matmul shared by the block's queries.
+Every block obeys the TPU (8, 128) tiling rule (``blocks.py``), and the
+lane chunk keeps VMEM and compile time independent of ``tile_cap``.
 
-* ``blockmax_score`` — DAAT/BMW exact scoring.  Per-block survival flags
-  ride in per (query, tile); pruned tiles skip their load/matmul entirely
-  via ``pl.when``, so latency tracks the *surviving* work per query.
+* ``blockmax_score`` — DAAT/BMW exact scoring.  Per-block survival rides
+  in as a per-doc 0/1 mask; tiles pruned for every query of a query block
+  (up to 64 rows) skip their matmul via ``pl.when``, so time follows the
+  union of the block's surviving work, not each query's own.
 * ``impact_accumulate`` — SAAT/JASS accumulation.  The ρ budget arrives as
   the per-query impact-level cut ``lstar``; compiled cost is a
   deterministic function of the layout (the structural 200 ms guarantee).
 * ``qd_feature_gather`` — Stage-2 LTR featurization: per-(query,
   candidate) term-score aggregates {Σ score, max, match count} over the
   batch's compacted posting lanes, reduced with the same one-hot MXU
-  matmul idiom (grid (Q, lane-tiles), accumulating output block).
+  matmul idiom (grid (query blocks, lane tiles), accumulating output
+  block).
+* ``dense_topk`` — dense Stage-1: tiled query×doc scores on the MXU, then
+  the tiled top-k merge (``repro.kernels.topk.topk_from_tiles``).
+* ``topk`` — the tiled top-k merge over the kernels' accumulator tiles.
 * ``score_histogram`` — histogram-based top-k over quantized accumulators.
 * ``flash_attention`` — attention kernels for the stage-2/LM workloads.
 

@@ -1,26 +1,19 @@
 """Block-max pruned DAAT scoring — the TPU adaptation of BMW's skip logic.
 
-Same bucketed one-hot-matmul layout as ``impact_accumulate`` (one doc tile
-per grid step) plus the BMW ingredient: a per-tile *survival predicate*
-derived from the block upper bounds.  Pruned tiles skip their matmul
-entirely via ``pl.when`` — on TPU the grid step reduces to a predicated
-no-op, so latency is proportional to the number of *surviving* blocks.
-This is structurally why DAAT keeps a data-dependent tail (the paper's
-Fig. 3): the amount of surviving work varies per query, whereas the SAAT
-kernel's grid is budget-bounded.
+Same bucketed one-hot-matmul layout as ``impact_accumulate`` (query block ×
+tile group × lane chunk grid over the shard's build-time bucketed mirror,
+term matching in-register) plus the BMW ingredient: per-(query, doc)
+*survival* from the block upper bounds.  Survival rides in as a 0/1 doc
+mask; a tile whose docs are pruned for every query of the query block (up
+to 64 rows, ``query_rows``) skips its matmul via ``pl.when``, so the
+kernel's time follows the union of the block's surviving tiles.  The
+mirror's blocks are still fetched for skipped tiles; only their compute is
+saved.  The SAAT kernel's grid, by contrast, is budget-bounded.
 
-VMEM per step at TILE_D=128, CAP=1024: postings 8 KB + tile 512 B.  The
-survive flags ride in as an int32 vector indexed per grid step.
-
-Two entry points:
-
-* ``blockmax_score_bucketed`` — single query over per-query bucketed
-  postings (the original layout; ``ops.blockmax_score`` buckets on the fly).
-* ``blockmax_score_batched`` — a (Q, n_tiles) grid over the shard's
-  build-time bucketed mirror (``IndexShard.tile_*``): tile buckets are
-  indexed by the tile coordinate only (zero-copy across the query batch) and
-  term matching runs in-kernel, so a whole query batch is served by one
-  grid launch.
+All lanes of one doc share the doc's pruning block, so masking a doc's
+accumulated score equals masking its lanes.  Scores are float32; the
+matmul runs at ``Precision.HIGHEST``, so scores are not rounded to
+bfloat16.
 """
 
 from __future__ import annotations
@@ -31,131 +24,75 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _score_kernel(docs_ref, scores_ref, survive_ref, acc_ref, *, tile_d: int):
-    i = pl.program_id(0)
-
-    @pl.when(survive_ref[0] > 0)
-    def _():
-        local = docs_ref[0, :]
-        sc = scores_ref[0, :]
-        live = local >= 0
-        v = jnp.where(live, sc, 0.0)
-        d = jnp.where(live, local, -1)
-        onehot = (d[:, None]
-                  == jax.lax.broadcasted_iota(jnp.int32, (1, tile_d), 1)
-                  ).astype(jnp.float32)
-        acc = jax.lax.dot_general(v[None, :], onehot,
-                                  (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        acc_ref[0, :] = acc[0, :]
-
-    @pl.when(survive_ref[0] == 0)
-    def _():
-        acc_ref[0, :] = jnp.zeros((tile_d,), jnp.float32)
+from repro.kernels.blocks import (TILES_PER_STEP, check_mirror, lane_chunk,
+                                  pad_axis, query_rows, round_up)
 
 
-def _score_kernel_batched(qterms_ref, survive_b_ref, survive_t_ref,
-                          docs_ref, terms_ref, scores_ref, acc_ref, *,
-                          tile_d: int, block_size: int, bpt: int):
-    """One (query, doc-tile) grid step over the shard's bucketed mirror.
+def _score_kernel(qterms_ref, keep_ref, docs_ref, terms_ref, scores_ref,
+                  acc_ref, *, tile_d: int):
+    """One (query block, tile group, lane chunk) grid step."""
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
 
-    The tile buckets (docs/terms/scores) are indexed by the tile coordinate
-    only, so the same HBM blocks serve every query in the batch — the
-    bucketed shard mirror is read zero-copy.  Term matching happens
-    in-register: a lane is live iff its term is one of the query's terms AND
-    its pruning block survives.  Pruned tiles skip the load/matmul entirely
-    via ``pl.when``, which is what makes DAAT latency track the surviving
-    work per query.
-    """
+    qt = qterms_ref[...]                          # (QB, L) query terms, -1 pad
+    ch = docs_ref.shape[1]
+    for i in range(TILES_PER_STEP):
+        cols = slice(i * tile_d, (i + 1) * tile_d)
+        keep = keep_ref[:, cols]                  # (QB, TILE_D) doc survival
 
-    @pl.when(survive_t_ref[0, 0] > 0)
-    def _():
-        local = docs_ref[0, :]                    # (CAP,) tile-local, -1 pad
-        tterm = terms_ref[0, :]                   # (CAP,) term ids, -1 pad
-        sc = scores_ref[0, :]
-        qt = qterms_ref[0, :]                     # (L,) query terms, -1 pad
-        match = jnp.any(tterm[:, None] == qt[None, :], axis=1)
-        # block-in-tile survival: bpt is tiny (tile_d/block_size), so a
-        # compare-reduce beats a vector gather on the VPU
-        blk = jnp.where(local >= 0, local, 0) // block_size
-        sb = survive_b_ref[0, 0, :]               # (bpt,) int32 flags
-        blk_oh = blk[:, None] == jax.lax.broadcasted_iota(
-            jnp.int32, (1, bpt), 1)
-        blk_live = jnp.sum(jnp.where(blk_oh, sb[None, :], 0), axis=1) > 0
-        live = (local >= 0) & match & blk_live
-        v = jnp.where(live, sc, 0.0)
-        d = jnp.where(live, local, -1)
-        onehot = (d[:, None]
-                  == jax.lax.broadcasted_iota(jnp.int32, (1, tile_d), 1)
-                  ).astype(jnp.float32)
-        acc = jax.lax.dot_general(v[None, :], onehot,
-                                  (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        acc_ref[0, 0, :] = acc[0, :]
-
-    @pl.when(survive_t_ref[0, 0] == 0)
-    def _():
-        acc_ref[0, 0, :] = jnp.zeros((tile_d,), jnp.float32)
+        @pl.when(jnp.max(keep) > 0)
+        def _score(i=i, cols=cols, keep=keep):
+            local = docs_ref[i:i + 1, :]          # (1, CH) tile-local, -1 pad
+            tterm = terms_ref[i:i + 1, :]
+            sc = scores_ref[i:i + 1, :]
+            match = tterm == qt[:, 0:1]
+            for l in range(1, qt.shape[1]):
+                match = match | (tterm == qt[:, l:l + 1])
+            v = jnp.where(match & (local >= 0), sc, 0.0)     # (QB, CH)
+            onehot = (jax.lax.broadcasted_iota(jnp.int32, (tile_d, ch), 0)
+                      == local).astype(jnp.float32)
+            part = jax.lax.dot_general(
+                v, onehot, (((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+            acc_ref[:, cols] += part * keep
 
 
-@functools.partial(jax.jit, static_argnames=("tile_d", "block_size",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("tile_d", "interpret"))
 def blockmax_score_batched(tile_docs: jnp.ndarray, tile_terms: jnp.ndarray,
                            tile_scores: jnp.ndarray, qterms: jnp.ndarray,
-                           survive_b: jnp.ndarray, survive_t: jnp.ndarray,
-                           *, tile_d: int, block_size: int,
-                           interpret: bool = True) -> jnp.ndarray:
+                           keep: jnp.ndarray, *, tile_d: int,
+                           interpret: bool) -> jnp.ndarray:
     """Batched exact scoring over the shard's bucketed postings mirror.
 
     Args:
       tile_docs/tile_terms/tile_scores: (n_tiles, CAP) bucketed shard mirror
-        (tile-local doc ids with -1 padding) — shared across the batch.
+        (tile-local doc ids with -1 padding; ``pack_tiles``: whole tile
+        groups and lane multiples) — read in place, shared across the batch.
       qterms: (Q, L) query term ids, -1 for masked-out slots.
-      survive_b: (Q, n_tiles, bpt) int32 per-block survival flags.
-      survive_t: (Q, n_tiles) int32 per-tile survival (any block survives).
+      keep: (Q, n_tiles · tile_d) float32 0/1 per-doc survival.
     Returns:
       (Q, n_tiles, tile_d) float32 accumulator tiles.
     """
-    n_tiles, cap = tile_docs.shape
+    check_mirror(tile_docs)
+    nt, cap = tile_docs.shape
     q, L = qterms.shape
-    bpt = tile_d // block_size
-    kern = functools.partial(_score_kernel_batched, tile_d=tile_d,
-                             block_size=block_size, bpt=bpt)
-    return pl.pallas_call(
-        kern,
-        grid=(q, n_tiles),
-        in_specs=[
-            pl.BlockSpec((1, L), lambda qi, t: (qi, 0)),
-            pl.BlockSpec((1, 1, bpt), lambda qi, t: (qi, t, 0)),
-            pl.BlockSpec((1, 1), lambda qi, t: (qi, t)),
-            pl.BlockSpec((1, cap), lambda qi, t: (t, 0)),
-            pl.BlockSpec((1, cap), lambda qi, t: (t, 0)),
-            pl.BlockSpec((1, cap), lambda qi, t: (t, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, tile_d), lambda qi, t: (qi, t, 0)),
-        out_shape=jax.ShapeDtypeStruct((q, n_tiles, tile_d), jnp.float32),
+    qb = query_rows(q)
+    qp = round_up(q, qb)
+    ch = lane_chunk(cap)
+    qt = pad_axis(qterms.astype(jnp.int32), 0, qp, -1)
+    kp = pad_axis(pad_axis(keep.astype(jnp.float32), 0, qp, 0.0),
+                  1, nt * tile_d, 0.0)
+    mirror = pl.BlockSpec((TILES_PER_STEP, ch), lambda a, t, c: (t, c))
+    cols = pl.BlockSpec((qb, TILES_PER_STEP * tile_d), lambda a, t, c: (a, t))
+    acc = pl.pallas_call(
+        functools.partial(_score_kernel, tile_d=tile_d),
+        grid=(qp // qb, nt // TILES_PER_STEP, cap // ch),
+        in_specs=[pl.BlockSpec((qb, L), lambda a, t, c: (a, 0)), cols,
+                  mirror, mirror, mirror],
+        out_specs=cols,
+        out_shape=jax.ShapeDtypeStruct((qp, nt * tile_d), jnp.float32),
         interpret=interpret,
-    )(qterms, survive_b, survive_t, tile_docs, tile_terms, tile_scores)
-
-
-@functools.partial(jax.jit, static_argnames=("tile_d", "interpret"))
-def blockmax_score_bucketed(docs_b: jnp.ndarray, scores_b: jnp.ndarray,
-                            survive_t: jnp.ndarray, *, tile_d: int,
-                            interpret: bool = True) -> jnp.ndarray:
-    """docs_b/scores_b: (n_tiles, CAP) bucketed postings (local ids, -1 pad);
-    survive_t: (n_tiles,) int32 tile-level survival flags."""
-    n_tiles, cap = docs_b.shape
-    kern = functools.partial(_score_kernel, tile_d=tile_d)
-    return pl.pallas_call(
-        kern,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((1, cap), lambda i: (i, 0)),
-            pl.BlockSpec((1, cap), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((1, tile_d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_tiles, tile_d), jnp.float32),
-        interpret=interpret,
-    )(docs_b, scores_b, survive_t)
+    )(qt, kp, tile_docs, tile_terms, tile_scores)
+    return acc[:q].reshape(q, nt, tile_d)

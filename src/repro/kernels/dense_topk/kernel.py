@@ -1,23 +1,18 @@
-"""Dense Stage-1 retrieval: batched tiled query×doc top-k on the MXU.
+"""Dense Stage-1 retrieval: batched tiled query×doc scoring on the MXU.
 
-The dense modality's serving kernel: one (Q, n_tiles) grid streams the
-(n_docs, d) embedding matrix through VMEM in ``tile_d``-doc tiles.  Each
-grid step scores its tile against one query row with a single MXU matmul
-(``(1, d) @ (d, tile_d)``) and folds the tile into a running per-query
-top-k held in revisited ``(1, k_pad)`` output blocks — the same
-concat-then-``top_k`` streaming merge as ``repro.models.recsys.
-streaming_topk``, moved inside the kernel so the full (Q, n_docs) score
-matrix never materializes.  The sequential TPU grid makes the revisited
-blocks safe accumulators (the idiom of ``qd_feature_gather``).
+The dense modality's serving kernel: one (query blocks, doc tiles) grid
+streams the (n_docs, d) embedding matrix through VMEM in ``tile_d``-doc
+tiles.  Each grid step scores its tile against a block of query rows with
+one MXU matmul (``(QB, d) @ (d, tile_d)``) and writes a ``(QB, tile_d)``
+score tile; ghost lanes in the ragged tail tile score ``float32 min``.  The
+top-k is the tiled hierarchical merge outside the kernel
+(``repro.isn.backend.topk_from_tiles``) — per-tile ``lax.top_k`` then a
+merge over per-tile candidates — which keeps the cascade-wide tie policy
+(equal scores → lower doc id).  Mosaic has no in-kernel ``top_k``.
 
-Tie-break: tiles are visited in ascending doc order, the running list sits
-*before* the new tile in the concat, and ``lax.top_k`` keeps the earliest
-position on ties — so equal scores resolve toward the lower doc id, the
-cascade-wide tie policy (``merge_shard_topk``).  Ghost lanes in the ragged
-tail tile score ``float32 min`` with id ``-1`` and can never surface while
-``k <= n_docs`` (the ops layer enforces it).
-
-VMEM per step is O(tile_d · d + k_pad), independent of n_docs.
+VMEM per step is O(QB · d + tile_d · d), independent of n_docs.  The
+matmul runs at ``Precision.HIGHEST``: the embeddings are grid-quantized,
+so the float32 dot products are exact (see ``ref.py``).
 """
 
 from __future__ import annotations
@@ -28,68 +23,46 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.blocks import pad_axis, query_rows, round_up
 
-def _dense_topk_kernel(q_ref, emb_ref, sc_ref, id_ref, *, k_pad: int,
-                       tile_d: int, n_docs: int):
-    """One (query, doc-tile) grid step: score the tile, merge the top-k."""
-    t = pl.program_id(1)
-    tile = emb_ref[...]                                   # (tile_d, d)
-    part = jax.lax.dot_general(q_ref[0:1, :], tile,
+
+def _dense_score_kernel(q_ref, emb_ref, sc_ref, *, tile_d: int, n_docs: int):
+    """One (query block, doc tile) grid step: score the tile."""
+    part = jax.lax.dot_general(q_ref[...], emb_ref[...],
                                (((1,), (1,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
-    ids = (t * tile_d
-           + jax.lax.broadcasted_iota(jnp.int32, (1, tile_d), 1))
-    fill = jnp.finfo(jnp.float32).min
-    live = ids < n_docs                                   # ragged tail tile
-    part = jnp.where(live, part, fill)
-    ids = jnp.where(live, ids, -1)
-
-    @pl.when(t == 0)
-    def _init():
-        sc_ref[0:1, :] = jnp.full((1, k_pad), fill, jnp.float32)
-        id_ref[0:1, :] = jnp.full((1, k_pad), -1, jnp.int32)
-
-    cat_sc = jnp.concatenate([sc_ref[0:1, :], part], axis=1)
-    cat_id = jnp.concatenate([id_ref[0:1, :], ids], axis=1)
-    best_sc, pos = jax.lax.top_k(cat_sc, k_pad)
-    sc_ref[0:1, :] = best_sc
-    id_ref[0:1, :] = jnp.take_along_axis(cat_id, pos, axis=1)
+    ids = (pl.program_id(1) * tile_d
+           + jax.lax.broadcasted_iota(jnp.int32, part.shape, 1))
+    sc_ref[...] = jnp.where(ids < n_docs, part, jnp.finfo(jnp.float32).min)
 
 
-@functools.partial(jax.jit, static_argnames=("k_pad", "tile_d", "n_docs",
+@functools.partial(jax.jit, static_argnames=("tile_d", "n_docs",
                                              "interpret"))
-def dense_topk_tiles(q_emb: jnp.ndarray, doc_emb: jnp.ndarray, *,
-                     k_pad: int, tile_d: int, n_docs: int,
-                     interpret: bool = True):
-    """Streaming top-k of ``q_emb @ doc_embᵀ`` over doc tiles.
+def dense_score_tiles(q_emb: jnp.ndarray, doc_emb: jnp.ndarray, *,
+                      tile_d: int, n_docs: int, interpret: bool):
+    """Tiled ``q_emb @ doc_embᵀ``.
 
     Args:
       q_emb: (Q, d) float32 query embeddings; d a lane multiple.
       doc_emb: (n_tiles·tile_d, d) float32, rows past ``n_docs`` are pad.
-      k_pad: results per query (lane multiple; callers slice back to k).
     Returns:
-      (scores, ids): (Q, k_pad) float32 / int32, score-descending, ties
-      toward the lower doc id; ghost entries score float32-min with id -1.
+      (Q, n_tiles, tile_d) float32 scores; ghost docs score float32-min.
     """
     q, d = q_emb.shape
     n_tiles = doc_emb.shape[0] // tile_d
     assert doc_emb.shape[0] == n_tiles * tile_d, (doc_emb.shape, tile_d)
-    kern = functools.partial(_dense_topk_kernel, k_pad=k_pad,
-                             tile_d=tile_d, n_docs=n_docs)
-    return pl.pallas_call(
-        kern,
-        grid=(q, n_tiles),
+    qb = query_rows(q)
+    qp = round_up(q, qb)
+    sc = pl.pallas_call(
+        functools.partial(_dense_score_kernel, tile_d=tile_d, n_docs=n_docs),
+        grid=(qp // qb, n_tiles),
         in_specs=[
-            pl.BlockSpec((1, d), lambda qi, t: (qi, 0)),
+            pl.BlockSpec((qb, d), lambda qi, t: (qi, 0)),
             pl.BlockSpec((tile_d, d), lambda qi, t: (t, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, k_pad), lambda qi, t: (qi, 0)),
-            pl.BlockSpec((1, k_pad), lambda qi, t: (qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((q, k_pad), jnp.float32),
-            jax.ShapeDtypeStruct((q, k_pad), jnp.int32),
-        ],
+        out_specs=pl.BlockSpec((qb, tile_d), lambda qi, t: (qi, t)),
+        out_shape=jax.ShapeDtypeStruct((qp, n_tiles * tile_d), jnp.float32),
         interpret=interpret,
-    )(q_emb, doc_emb)
+    )(pad_axis(q_emb, 0, qp, 0.0), doc_emb)
+    return sc[:q].reshape(q, n_tiles, tile_d)

@@ -76,7 +76,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                                              "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, tq: int = 128,
                     tk: int = 128, scale: float | None = None,
-                    interpret: bool = True):
+                    interpret: bool):
     """q: (B, H, S, D); k, v: (B, Hkv, S, D) -> (B, H, S, D)."""
     b, h, s, d = q.shape
     hkv = k.shape[1]
@@ -134,7 +134,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref, *,
 
 @functools.partial(jax.jit, static_argnames=("tk", "scale", "interpret"))
 def flash_decode(q, k, v, kv_len, *, tk: int = 512, scale: float | None = None,
-                 interpret: bool = True):
+                 interpret: bool):
     """q: (B, H, D); k, v: (B, Hkv, S, D); kv_len: (B,) -> (B, H, D).
 
     Returns the attention output after merging the per-split partials.

@@ -4,23 +4,29 @@ scatter loop (`acc[doc] += impact`).
 Hardware mapping
 ----------------
 A scalar scatter-add is hostile to the TPU's vector/matrix units, so the
-postings are *bucketed by document tile* (done by `ops.py` with one sort —
-the JASS ρ budget is an impact-level mask, so processing order inside a
-bucket is irrelevant) and each grid step reduces one bucket with a one-hot
-matmul:
+postings are *bucketed by document tile* at index-build time (the JASS ρ
+budget is an impact-level mask, so processing order inside a bucket is
+irrelevant) and each bucket is reduced with a one-hot matmul shared by a
+block of queries:
 
-    acc[tile] = impactsᵀ (1 × CAP)  @  onehot(local_doc) (CAP × TILE_D)
+    acc[q, tile] += V (QB × CH)  @  onehot(local_doc)ᵀ (CH × TILE_D)
 
-Capacity bound: postings are unique (term, doc) pairs, so a TILE_D-doc tile
-receives at most TILE_D × L postings for an L-term query — CAP = TILE_D × L
-can never overflow.  VMEM per step: CAP·(4+4) B + TILE_D·4 B ≈ 10 KB at
-TILE_D=128, L=8 — far under the ~16 MB budget, so several grid steps can be
-double-buffered.
+where ``V[q, lane]`` is the lane's impact if its term is one of query
+``q``'s terms and its impact reaches the query's level cut, else 0.
 
-The ρ budget appears as the scalar `lstar` (impact-level cut): lanes with
-impact < lstar contribute zero, and the *grid itself* is sized by the
-bucketed layout, so compiled cost is a deterministic function of ρ_max —
-the structural version of the paper's 200 ms guarantee.
+Grid and blocks (see ``repro.kernels.blocks``): (query blocks, tile groups,
+lane chunks).  A step reads ``TILES_PER_STEP`` tile buckets over one
+``CH``-lane chunk and adds into the ``(QB, TILES_PER_STEP · TILE_D)``
+output block, which stays resident across the innermost lane-chunk axis.
+VMEM per step is O(QB·CH + TILE_D·CH), independent of the shard's
+``tile_cap``.  Impacts are 8-bit integers, exact in bfloat16, and the
+partial sums stay below 2^24, so the bf16 matmul with f32 accumulation is
+exact.
+
+The ρ budget appears as the per-query cut ``lstar``: lanes with impact <
+lstar contribute zero, and the *grid itself* is sized by the bucketed
+layout, so compiled cost is a deterministic function of the shard — the
+structural version of the paper's 200 ms guarantee.
 """
 
 from __future__ import annotations
@@ -31,109 +37,74 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _accumulate_kernel(lstar_ref, docs_ref, imps_ref, acc_ref, *, tile_d: int):
-    """One bucket -> one accumulator tile."""
-    local = docs_ref[0, :]                        # (CAP,) int32, -1 = pad
-    imps = imps_ref[0, :]                         # (CAP,)
-    live = (local >= 0) & (imps >= lstar_ref[0])
-    v = jnp.where(live, imps, 0).astype(jnp.float32)
-    d = jnp.where(live, local, -1)
-    onehot = (d[:, None] == jax.lax.broadcasted_iota(jnp.int32, (1, tile_d), 1)
-              ).astype(jnp.float32)               # (CAP, TILE_D)
-    acc = jax.lax.dot_general(v[None, :], onehot,
-                              (((1,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    acc_ref[0, :] = acc[0, :].astype(jnp.int32)
+from repro.kernels.blocks import (TILES_PER_STEP, check_mirror, lane_chunk,
+                                  pad_axis, query_rows, round_up)
 
 
-def _accumulate_kernel_batched(qterms_ref, lstar_ref, docs_ref, terms_ref,
-                               imps_ref, acc_ref, *, tile_d: int):
-    """One (query, doc-tile) grid step over the shard's bucketed mirror.
+def _accumulate_kernel(qterms_ref, lstar_ref, docs_ref, terms_ref, imps_ref,
+                       acc_ref, *, tile_d: int):
+    """One (query block, tile group, lane chunk) grid step."""
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
 
-    The ρ budget arrives as the per-query impact-level cut ``lstar``: a lane
-    contributes iff its term is one of the query's terms AND its impact
-    reaches the cut.  The grid is (Q, n_tiles) with the tile buckets indexed
-    by the tile coordinate only — one launch serves the whole query batch
-    against a zero-copy view of the shard, and compiled cost stays a
-    deterministic function of the shard layout (the structural 200 ms
-    guarantee survives batching).
-    """
-    local = docs_ref[0, :]                        # (CAP,) tile-local, -1 pad
-    tterm = terms_ref[0, :]                       # (CAP,) term ids, -1 pad
-    imps = imps_ref[0, :]                         # (CAP,)
-    qt = qterms_ref[0, :]                         # (L,) query terms, -1 pad
-    match = jnp.any(tterm[:, None] == qt[None, :], axis=1)
-    live = (local >= 0) & match & (imps >= lstar_ref[0])
-    v = jnp.where(live, imps, 0).astype(jnp.float32)
-    d = jnp.where(live, local, -1)
-    onehot = (d[:, None] == jax.lax.broadcasted_iota(jnp.int32, (1, tile_d), 1)
-              ).astype(jnp.float32)
-    acc = jax.lax.dot_general(v[None, :], onehot,
-                              (((1,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    acc_ref[0, 0, :] = acc[0, :].astype(jnp.int32)
+    qt = qterms_ref[...]                          # (QB, L) query terms, -1 pad
+    cut = lstar_ref[...]                          # (QB, 1) level cuts
+    ch = docs_ref.shape[1]
+    for i in range(TILES_PER_STEP):
+        local = docs_ref[i:i + 1, :]              # (1, CH) tile-local, -1 pad
+        tterm = terms_ref[i:i + 1, :]             # (1, CH) term ids, -1 pad
+        imps = imps_ref[i:i + 1, :]               # (1, CH)
+        match = tterm == qt[:, 0:1]
+        for l in range(1, qt.shape[1]):
+            match = match | (tterm == qt[:, l:l + 1])
+        live = match & (imps >= cut) & (local >= 0)          # (QB, CH)
+        v = jnp.where(live, imps, 0).astype(jnp.bfloat16)
+        onehot = (jax.lax.broadcasted_iota(jnp.int32, (tile_d, ch), 0)
+                  == local).astype(jnp.bfloat16)             # (TILE_D, CH)
+        part = jax.lax.dot_general(v, onehot, (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+        cols = slice(i * tile_d, (i + 1) * tile_d)
+        acc_ref[:, cols] += part.astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("tile_d", "interpret"))
 def impact_accumulate_batched(tile_docs: jnp.ndarray, tile_terms: jnp.ndarray,
                               tile_imps: jnp.ndarray, qterms: jnp.ndarray,
                               lstar: jnp.ndarray, *, tile_d: int,
-                              interpret: bool = True) -> jnp.ndarray:
+                              interpret: bool) -> jnp.ndarray:
     """Batched impact accumulation over the shard's bucketed mirror.
 
     Args:
       tile_docs/tile_terms/tile_imps: (n_tiles, CAP) build-time bucketed
-        shard mirror — shared (zero-copy) across the query batch.
+        shard mirror (``pack_tiles``: whole tile groups and lane multiples)
+        — read in place and shared across the query batch.
       qterms: (Q, L) query term ids, -1 in masked-out slots.
       lstar: (Q,) int32 per-query impact-level cuts from the ρ budgets.
     Returns:
       (Q, n_tiles, tile_d) int32 accumulator tiles.
     """
-    n_tiles, cap = tile_docs.shape
+    check_mirror(tile_docs)
+    nt, cap = tile_docs.shape
     q, L = qterms.shape
-    kern = functools.partial(_accumulate_kernel_batched, tile_d=tile_d)
-    return pl.pallas_call(
-        kern,
-        grid=(q, n_tiles),
+    qb = query_rows(q)
+    qp = round_up(q, qb)
+    ch = lane_chunk(cap)
+    # padded query rows match no term, so they accumulate nothing
+    qt = pad_axis(qterms.astype(jnp.int32), 0, qp, -1)
+    cut = pad_axis(lstar.astype(jnp.int32).reshape(q, 1), 0, qp, 0)
+    mirror = pl.BlockSpec((TILES_PER_STEP, ch), lambda a, t, c: (t, c))
+    acc = pl.pallas_call(
+        functools.partial(_accumulate_kernel, tile_d=tile_d),
+        grid=(qp // qb, nt // TILES_PER_STEP, cap // ch),
         in_specs=[
-            pl.BlockSpec((1, L), lambda qi, t: (qi, 0)),
-            pl.BlockSpec((1,), lambda qi, t: (qi,)),
-            pl.BlockSpec((1, cap), lambda qi, t: (t, 0)),
-            pl.BlockSpec((1, cap), lambda qi, t: (t, 0)),
-            pl.BlockSpec((1, cap), lambda qi, t: (t, 0)),
+            pl.BlockSpec((qb, L), lambda a, t, c: (a, 0)),
+            pl.BlockSpec((qb, 1), lambda a, t, c: (a, 0)),
+            mirror, mirror, mirror,
         ],
-        out_specs=pl.BlockSpec((1, 1, tile_d), lambda qi, t: (qi, t, 0)),
-        out_shape=jax.ShapeDtypeStruct((q, n_tiles, tile_d), jnp.int32),
+        out_specs=pl.BlockSpec((qb, TILES_PER_STEP * tile_d),
+                               lambda a, t, c: (a, t)),
+        out_shape=jax.ShapeDtypeStruct((qp, nt * tile_d), jnp.int32),
         interpret=interpret,
-    )(qterms, lstar, tile_docs, tile_terms, tile_imps)
-
-
-@functools.partial(jax.jit, static_argnames=("tile_d", "interpret"))
-def impact_accumulate_bucketed(docs_b: jnp.ndarray, imps_b: jnp.ndarray,
-                               lstar: jnp.ndarray, *, tile_d: int,
-                               interpret: bool = True) -> jnp.ndarray:
-    """Run the Pallas kernel over a bucketed postings layout.
-
-    Args:
-      docs_b: (n_tiles, CAP) int32 — doc ids *local to each tile*, -1 padding.
-      imps_b: (n_tiles, CAP) int32.
-      lstar:  () int32 — impact-level cut from the ρ budget.
-      tile_d: docs per accumulator tile.
-    Returns:
-      (n_tiles, tile_d) int32 accumulator tiles (reshape to (N,) outside).
-    """
-    n_tiles, cap = docs_b.shape
-    kern = functools.partial(_accumulate_kernel, tile_d=tile_d)
-    return pl.pallas_call(
-        kern,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),            # lstar (replicated)
-            pl.BlockSpec((1, cap), lambda i: (i, 0)),      # docs bucket
-            pl.BlockSpec((1, cap), lambda i: (i, 0)),      # imps bucket
-        ],
-        out_specs=pl.BlockSpec((1, tile_d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_tiles, tile_d), jnp.int32),
-        interpret=interpret,
-    )(lstar.reshape(1), docs_b, imps_b)
+    )(qt, cut, tile_docs, tile_terms, tile_imps)
+    return acc[:q].reshape(q, nt, tile_d)

@@ -8,8 +8,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.impact_accumulate.kernel import (impact_accumulate_batched,
-                                                    impact_accumulate_bucketed)
+from repro.kernels.blocks import LANES, mirror_tiles, round_up
+from repro.kernels.impact_accumulate.kernel import impact_accumulate_batched
 from repro.kernels.impact_accumulate.ref import impact_accumulate_ref
 
 
@@ -17,7 +17,7 @@ from repro.kernels.impact_accumulate.ref import impact_accumulate_ref
 def impact_accumulate_tiles(tile_docs: jnp.ndarray, tile_terms: jnp.ndarray,
                             tile_imps: jnp.ndarray, qterms: jnp.ndarray,
                             lstar: jnp.ndarray, *, tile_d: int,
-                            interpret: bool = True) -> jnp.ndarray:
+                            interpret: bool) -> jnp.ndarray:
     """Batched SAAT accumulation over the shard's bucketed mirror.
 
     Thin dispatch onto ``impact_accumulate_batched``; exists so the engines
@@ -34,9 +34,13 @@ def impact_accumulate_tiles(tile_docs: jnp.ndarray, tile_terms: jnp.ndarray,
 def impact_accumulate(docs: jnp.ndarray, imps: jnp.ndarray,
                       lstar: jnp.ndarray, *, n_docs: int, tile_d: int = 128,
                       cap: int | None = None,
-                      interpret: bool = True) -> jnp.ndarray:
+                      interpret: bool) -> jnp.ndarray:
     """Accumulate postings (docs, imps) with impact >= lstar into a dense
     (n_docs,) accumulator via the bucketed MXU kernel.
+
+    The postings are bucketed per call and served as a one-query batch of
+    the shard-mirror kernel: every live lane carries term 0 and the query
+    asks for term 0.
 
     `cap` must be >= the max postings per doc tile.  For unique (term, doc)
     postings of an L-term query, cap = tile_d * L is a hard bound; callers
@@ -46,7 +50,8 @@ def impact_accumulate(docs: jnp.ndarray, imps: jnp.ndarray,
     """
     p = docs.shape[0]
     n_tiles = -(-n_docs // tile_d)
-    cap = cap if cap is not None else tile_d * 8
+    nt = mirror_tiles(n_docs, tile_d)                       # kernel rows
+    cap = round_up(cap if cap is not None else tile_d * 8, LANES)
 
     live = docs >= 0
     tile = jnp.where(live, docs // tile_d, n_tiles)         # pad -> ghost tile
@@ -61,16 +66,18 @@ def impact_accumulate(docs: jnp.ndarray, imps: jnp.ndarray,
     pos = jnp.arange(p, dtype=jnp.int32) - starts[tile_s]
 
     fits = (pos < cap) & (tile_s < n_tiles)
-    slot = jnp.where(fits, tile_s * cap + pos, n_tiles * cap)
-    docs_b = jnp.full((n_tiles * cap + 1,), -1, jnp.int32
+    slot = jnp.where(fits, tile_s * cap + pos, nt * cap)
+    docs_b = jnp.full((nt * cap + 1,), -1, jnp.int32
                       ).at[slot].set(jnp.where(fits, docs_s, -1))
-    imps_b = jnp.zeros((n_tiles * cap + 1,), jnp.int32
+    imps_b = jnp.zeros((nt * cap + 1,), jnp.int32
                        ).at[slot].set(jnp.where(fits, imps_s, 0))
 
-    acc_t = impact_accumulate_bucketed(
-        docs_b[:-1].reshape(n_tiles, cap), imps_b[:-1].reshape(n_tiles, cap),
-        lstar, tile_d=tile_d, interpret=interpret)
-    acc = acc_t.reshape(n_tiles * tile_d)[:n_docs]
+    docs_b = docs_b[:-1].reshape(nt, cap)
+    acc_t = impact_accumulate_batched(
+        docs_b, jnp.where(docs_b >= 0, 0, -1),
+        imps_b[:-1].reshape(nt, cap), jnp.zeros((1, 1), jnp.int32),
+        jnp.reshape(lstar, (1,)), tile_d=tile_d, interpret=interpret)
+    acc = acc_t.reshape(nt * tile_d)[:n_docs]
 
     # overflow fallback (cap exceeded): exact jnp scatter of the residue
     over = live[order] & ~fits & (tile_s < n_tiles)

@@ -18,7 +18,7 @@ LANE_MULTIPLE = 128   # TPU lane width: candidate axis is the minor dim
 @functools.partial(jax.jit, static_argnames=("p_tile", "interpret"))
 def qd_feature_gather(lane_docs: jnp.ndarray, lane_scores: jnp.ndarray,
                       cand: jnp.ndarray, *, p_tile: int = 512,
-                      interpret: bool = True):
+                      interpret: bool):
     """Pad lanes/candidates to kernel-friendly shapes and dispatch.
 
     The lane axis is padded to a multiple of ``p_tile`` with dead lanes and

@@ -45,7 +45,7 @@ def _hist_kernel(scores_ref, hist_ref, *, n_bins: int, tile_n: int):
 
 @functools.partial(jax.jit, static_argnames=("n_bins", "tile_n", "interpret"))
 def score_histogram(scores: jnp.ndarray, *, n_bins: int = 2048,
-                    tile_n: int = 2048, interpret: bool = True) -> jnp.ndarray:
+                    tile_n: int = 2048, interpret: bool) -> jnp.ndarray:
     """scores: (N,) int32 (N multiple of tile_n; pad with -1) -> (n_bins,)."""
     n = scores.shape[0]
     assert n % tile_n == 0

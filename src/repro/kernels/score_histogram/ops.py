@@ -13,7 +13,7 @@ from repro.kernels.score_histogram.ref import score_histogram_ref
 
 @functools.partial(jax.jit, static_argnames=("k", "n_bins", "interpret"))
 def histogram_topk(scores: jnp.ndarray, *, k: int, n_bins: int = 2048,
-                   interpret: bool = True):
+                   interpret: bool):
     """Exact top-k of an int32 score vector via histogram thresholding.
 
     Returns (values, indices) like jax.lax.top_k (ties broken by index).

@@ -20,14 +20,8 @@ def mesh_context(mesh):
 
     ``get_abstract_mesh()`` inside jit tracing only sees the mesh under
     ``use_abstract_mesh`` — model code (MoE shard_map, constraint helpers)
-    relies on it.  On jax 0.4.37 (no abstract-mesh API) the thread-local
-    fallback in ``repro.models.common`` carries the *concrete* mesh, which
-    every consumer (axis_names / shape / NamedSharding) accepts."""
-    if hasattr(jax.sharding, "use_abstract_mesh"):
-        abstract = mesh.abstract_mesh
-    else:
-        abstract = mesh
-    with mesh, use_abstract_mesh(abstract):
+    relies on it."""
+    with mesh, use_abstract_mesh(mesh.abstract_mesh):
         yield mesh
 
 

@@ -160,6 +160,9 @@ def main():
                          "a why-slow attribution (enables telemetry)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from repro.configs.cascade_presets import get_preset
     from repro.core.labels import LabelConfig, generate_labels
     from repro.index.corpus import CorpusParams, build_corpus, build_queries

@@ -17,7 +17,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -311,7 +311,7 @@ def _gnn_cell(arch_id, config, cell: ShapeCell, mesh, rules) -> Cell:
                 lambda p, b_: gnn.loss_fn_partitioned(p, config, b_,
                                                       flat_axes),
                 mesh=mesh, in_specs=(P(), b_specs), out_specs=P(),
-                check_rep=False)(params, batch)
+                check_vma=False)(params, batch)
 
         def train_step(params, opt, batch):
             loss, grads = jax.value_and_grad(loss_sharded)(params, batch)

@@ -14,9 +14,7 @@ the dry-run exercises on the production meshes.
 
 from __future__ import annotations
 
-import contextlib
 import math
-import threading
 from typing import Any, Callable
 
 import jax
@@ -25,38 +23,8 @@ from jax.sharding import PartitionSpec as P
 
 Pytree = Any
 
-# ---------------------------------------------------------------------------
-# abstract-mesh compat (jax 0.4.37)
-# ---------------------------------------------------------------------------
-
-# jax.sharding.{get,use}_abstract_mesh only exist on jax >= 0.5.  The
-# thread-local fallback preserves the contract the model stack relies on:
-# inside ``use_abstract_mesh(m)``, ``get_abstract_mesh()`` returns ``m`` —
-# including during jit tracing, which runs on the calling thread.
-_MESH_STACK = threading.local()
-
-
-def _fallback_get_abstract_mesh():
-    stack = getattr(_MESH_STACK, "stack", None)
-    return stack[-1] if stack else None
-
-
-@contextlib.contextmanager
-def _fallback_use_abstract_mesh(mesh):
-    stack = getattr(_MESH_STACK, "stack", None)
-    if stack is None:
-        stack = _MESH_STACK.stack = []
-    stack.append(mesh)
-    try:
-        yield mesh
-    finally:
-        stack.pop()
-
-
-get_abstract_mesh = getattr(jax.sharding, "get_abstract_mesh",
-                            _fallback_get_abstract_mesh)
-use_abstract_mesh = getattr(jax.sharding, "use_abstract_mesh",
-                            _fallback_use_abstract_mesh)
+get_abstract_mesh = jax.sharding.get_abstract_mesh
+use_abstract_mesh = jax.sharding.use_abstract_mesh
 
 # ---------------------------------------------------------------------------
 # logical sharding

@@ -26,7 +26,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models import common
@@ -174,6 +174,6 @@ def moe_forward(p, x, cfg: MoEConfig, rules=None):
                   P("model", None, None) if ep else P(None, None, None),
                   xspec),
         out_specs=(xspec, P()),
-        check_rep=False,
+        check_vma=False,
     )(p["router"], p["w_gate"], p["w_up"], p["w_down"], x)
     return shared_part(y), aux

@@ -16,7 +16,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.models import common, embedding
 from repro.models.attention import chunked_attention
@@ -290,7 +290,7 @@ def sharded_streaming_topk(q_emb, cand_emb, k: int, tile: int = 8192):
     return shard_map(local_fn, mesh=mesh,
                          in_specs=(qspec, P("model", None)),
                          out_specs=(qspec, qspec),
-                         check_rep=False)(q_emb, cand_emb)
+                         check_vma=False)(q_emb, cand_emb)
 
 
 def anytime_retrieval(query_emb, cand_emb, prior_order_len: jnp.ndarray,

@@ -241,6 +241,56 @@ def test_bucketed_mirror_is_lossless(shard):
     np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
 
 
+def test_unaligned_shard_mirror_is_never_padded(small_collection):
+    """A shard whose tile count is not a whole kernel tile group gets dead
+    tiles at build time, so the serving kernels read the resident mirror
+    as it is: the traced call pads nothing, an unaligned mirror is refused
+    rather than copied, and the dead tiles never reach a top-k."""
+    from repro.kernels.blockmax_score.ops import blockmax_score_tiles
+    from repro.kernels.blocks import TILES_PER_STEP
+    from repro.kernels.impact_accumulate.ops import impact_accumulate_tiles
+    corpus, index, ql = small_collection
+    tile_d, n_real = 128, 6
+    s, spec = shard_from_index(index, 0, (n_real - 1) * tile_d + 7, tile_d)
+    assert spec.n_tiles == s.tile_docs.shape[0] == TILES_PER_STEP
+    assert (np.asarray(s.tile_docs)[n_real:] == -1).all()
+
+    q = 8
+    qt = jnp.where(jnp.asarray(ql.mask[:q]) > 0, jnp.asarray(ql.terms[:q]),
+                   -1).astype(jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda td, tt, ti, qt, cut: impact_accumulate_tiles(
+        td, tt, ti, qt, cut, tile_d=tile_d, interpret=True))(
+        s.tile_docs, s.tile_terms, s.tile_imps, qt, jnp.zeros(q, jnp.int32))
+    assert " pad[" not in str(jaxpr)
+    with pytest.raises(ValueError, match="pack_tiles"):
+        impact_accumulate_tiles(s.tile_docs[:n_real], s.tile_terms[:n_real],
+                                s.tile_imps[:n_real], qt,
+                                jnp.zeros(q, jnp.int32), tile_d=tile_d,
+                                interpret=True)
+    with pytest.raises(ValueError, match="pack_tiles"):
+        blockmax_score_tiles(s.tile_docs[:n_real], s.tile_terms[:n_real],
+                             s.tile_scores[:n_real], qt,
+                             jnp.ones((q, spec.n_blocks), bool),
+                             tile_d=tile_d, block_size=spec.block_size,
+                             n_blocks=spec.n_blocks, interpret=True)
+
+    terms, mask = jnp.asarray(ql.terms[:q]), jnp.asarray(ql.mask[:q])
+    kw = dict(n_docs=spec.n_docs, n_blocks=spec.n_blocks,
+              block_size=spec.block_size, k=20, cap=spec.max_df,
+              bcap=spec.max_blocks_per_term)
+    a, b = (daat_serve(s, terms, mask, jnp.ones(q), backend=be, **kw)
+            for be in ("interpret", "jnp"))
+    assert int(np.asarray(a.topk_docs).max()) < spec.n_docs
+    np.testing.assert_array_equal(np.asarray(a.topk_docs),
+                                  np.asarray(b.topk_docs))
+    rho = jnp.full(q, 2048, jnp.int32)
+    a, b = (saat_serve(s, terms, mask, rho, n_docs=spec.n_docs, k=20,
+                       cap=2048, backend=be) for be in ("interpret", "jnp"))
+    assert int(np.asarray(a.topk_docs).max()) < spec.n_docs
+    np.testing.assert_array_equal(np.asarray(a.topk_docs),
+                                  np.asarray(b.topk_docs))
+
+
 # ---------------------------------------------------------------------------
 # backend plumbing
 # ---------------------------------------------------------------------------
