@@ -27,6 +27,7 @@ from repro.dense.embeddings import embed_queries
 from repro.isn.backend import merge_shard_topk
 from repro.kernels.dense_topk.ops import dense_topk
 from repro.kernels.dense_topk.ref import dense_topk_oracle
+from repro.serving.telemetry.spans import fetch
 
 SCORE_FILL = float(np.finfo(np.float32).min)
 
@@ -115,8 +116,8 @@ class DenseEngine:
             sc_list.append(sc)
             id_list.append(ids + self.doc_lo[s])
         if self.n_shards == 1 and self.delta_emb is None:
-            ids = np.asarray(id_list[0]).astype(np.int64)
-            sc = np.asarray(sc_list[0])
+            ids, sc = fetch(id_list[0], sc_list[0])
+            ids = ids.astype(np.int64)
             if drop is not None and drop[0].any():
                 ids[drop[0]] = -1
                 sc[drop[0]] = SCORE_FILL
@@ -131,8 +132,8 @@ class DenseEngine:
             cap = int(self.delta_emb.shape[0])
             dsc, dids = dense_topk(jnp.asarray(q_emb), self.delta_emb, cap,
                                    tile_d=self.tile_d, backend=self.backend)
-            dsc = np.asarray(dsc).copy()
-            dids = np.asarray(dids)
+            dsc, dids = fetch(dsc, dids)
+            dsc = dsc.copy()
             ghost = dids >= self.delta_live
             dsc[ghost] = SCORE_FILL
             dids = np.where(ghost, -1, dids + self.delta_lo)
@@ -142,8 +143,8 @@ class DenseEngine:
                 drop = np.concatenate(
                     [np.asarray(drop),
                      np.zeros((1, np.asarray(drop).shape[1]), bool)])
-        ids, sc = merge_shard_topk(sc_list, id_list, k, drop=drop)
-        return np.asarray(ids).astype(np.int64), np.asarray(sc)
+        ids, sc = fetch(*merge_shard_topk(sc_list, id_list, k, drop=drop))
+        return ids.astype(np.int64), sc
 
     def oracle(self, q_emb: np.ndarray, k: int):
         """Brute-force ground truth over the unsharded matrix: (ids,

@@ -94,5 +94,6 @@ def blockmax_score_batched(tile_docs: jnp.ndarray, tile_terms: jnp.ndarray,
         out_specs=cols,
         out_shape=jax.ShapeDtypeStruct((qp, nt * tile_d), jnp.float32),
         interpret=interpret,
+        name="blockmax_score_batched",
     )(qt, kp, tile_docs, tile_terms, tile_scores)
     return acc[:q].reshape(q, nt, tile_d)

@@ -64,5 +64,6 @@ def dense_score_tiles(q_emb: jnp.ndarray, doc_emb: jnp.ndarray, *,
         out_specs=pl.BlockSpec((qb, tile_d), lambda qi, t: (qi, t)),
         out_shape=jax.ShapeDtypeStruct((qp, n_tiles * tile_d), jnp.float32),
         interpret=interpret,
+        name="dense_score_tiles",
     )(pad_axis(q_emb, 0, qp, 0.0), doc_emb)
     return sc[:q].reshape(q, n_tiles, tile_d)

@@ -106,5 +106,6 @@ def impact_accumulate_batched(tile_docs: jnp.ndarray, tile_terms: jnp.ndarray,
                                lambda a, t, c: (a, t)),
         out_shape=jax.ShapeDtypeStruct((qp, nt * tile_d), jnp.int32),
         interpret=interpret,
+        name="impact_accumulate_batched",
     )(qt, cut, tile_docs, tile_terms, tile_imps)
     return acc[:q].reshape(q, nt, tile_d)

@@ -113,5 +113,6 @@ def qd_feature_gather_lanes(lane_docs: jnp.ndarray, lane_scores: jnp.ndarray,
             jax.ShapeDtypeStruct((qp, c), jnp.int32),
         ],
         interpret=interpret,
+        name="qd_feature_gather_lanes",
     )(cand, lane_docs, lane_scores)
     return bm25[:q], mx[:q], cnt[:q]

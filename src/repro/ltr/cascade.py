@@ -22,6 +22,7 @@ import numpy as np
 from repro.core import gbrt
 from repro.ltr.ranker import (LTRModel, Stage2Arrays, csr_search_iters,
                               qd_features, qd_features_batched)
+from repro.serving.telemetry.spans import fetch
 
 
 @dataclass
@@ -112,5 +113,6 @@ def rerank_batched(arrs: Stage2Arrays, ltr: LTRModel, terms, mask, topics,
         final = jnp.pad(final, ((0, 0), (0, t_final - kk)),
                         constant_values=-1)
         final = jnp.where(used[:, None] > 0, final, 0)
-    return CascadeResult(final=np.asarray(final).astype(np.int64),
-                         candidates_used=np.asarray(used).astype(np.int64))
+    final, used = fetch(final, used)
+    return CascadeResult(final=final.astype(np.int64),
+                         candidates_used=used.astype(np.int64))

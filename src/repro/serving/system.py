@@ -72,6 +72,9 @@ from repro.serving.scheduler import (RoutedBatch, SchedulerConfig,
                                      StageZeroScheduler)
 from repro.serving.spec import CascadeSpec, RoutingSpec
 from repro.serving.telemetry import QueryTrace, Span, Telemetry
+from repro.serving.telemetry.spans import (ACCOUNT, CACHE, REPLICAS, SERVE,
+                                           STAGE0, STAGE1, STAGE2, fetch,
+                                           span)
 from repro.serving.telemetry.export import (legacy_stats_view,
                                             render_json,
                                             render_prometheus)
@@ -455,11 +458,11 @@ class SearchSystem:
         x = F.extract(self.term_stats, self.df, jnp.asarray(terms),
                       jnp.asarray(mask))
         if self._stacked is not None:
-            p = np.expm1(np.asarray(
-                gbrt.predict_stacked(self._stacked, x, self._stack_depth)))
+            p = np.expm1(fetch(gbrt.predict_stacked(
+                self._stacked, x, self._stack_depth))[0])
             return p[0], p[1], p[2]
-        return tuple(np.expm1(np.asarray(gbrt.predict(self.models[n], x)))
-                     for n in ("k", "rho", "t"))
+        return tuple(np.expm1(p) for p in fetch(
+            *(gbrt.predict(self.models[n], x) for n in ("k", "rho", "t"))))
 
     def _modality(self, pt: np.ndarray) -> np.ndarray:
         """Stage-0 modality dispatch from the predicted lexical time:
@@ -573,6 +576,38 @@ class SearchSystem:
         t_bmw = np.zeros(q)
         t_shards = np.zeros((ns, q))
 
+        def gather(rows, sc_list, id_list, extra):
+            """Merge one engine's per-segment lists into ``topk``/
+            ``topk_sc`` and read them back, with ``extra`` (the engines'
+            work counts), in one device wait; returns ``extra`` on the
+            host."""
+            merged = None
+            if ns > 1 or self.delta is not None:
+                dr = None if drop is None else drop[:, rows]
+                if dr is not None and self.delta is not None:
+                    # the delta segment is local to the merge host — never
+                    # lost, never admission-dropped
+                    dr = np.concatenate(
+                        [dr, np.zeros((1, len(rows)), bool)])
+                merged = merge_shard_topk(sc_list, id_list, self.k_serve,
+                                          drop=dr)
+            debug = self._debug_shard_lists is not None
+            lists = (sc_list, id_list) if debug or merged is None else None
+            lists, merged, extra = fetch(lists, merged, extra)
+            if debug:
+                self._debug_shard_lists.append((rows, *lists))
+            if merged is None:
+                topk[rows] = lists[1][0]
+                topk_sc[rows] = lists[0][0].astype(np.float32)
+                if drop is not None and drop[0, rows].any():
+                    dead = rows[drop[0, rows]]
+                    topk[dead] = -1
+                    topk_sc[dead] = SCORE_FILL
+            else:
+                topk[rows] = merged[0]
+                topk_sc[rows] = merged[1].astype(np.float32)
+            return extra
+
         if len(routed.jass_rows):
             rows = routed.jass_rows
             rho_rows = routed.rho[rows]
@@ -586,7 +621,7 @@ class SearchSystem:
                                  for w in work_s]
             else:
                 rho_per_shard = [rho_rows]
-            sc_list, id_list = [], []
+            sc_list, id_list, work = [], [], []
             for s in range(ns):
                 res = saat_serve(self.shards[s], jnp.asarray(terms[rows]),
                                  jnp.asarray(mask[rows]),
@@ -598,8 +633,7 @@ class SearchSystem:
                                  backend=self.backend)
                 sc_list.append(res.topk_scores)
                 id_list.append(res.topk_docs + self.doc_lo[s])
-                t_shards[s, rows] = self.cost.saat_time(
-                    np.asarray(res.work).astype(np.float64))
+                work.append(res.work)
             if self.delta is not None:
                 # the delta pseudo-shard scans its slice of the same global
                 # cut; appended LAST so merge ties keep breaking toward the
@@ -614,32 +648,14 @@ class SearchSystem:
                                  tile_d=dsp.tile_d, backend=self.backend)
                 sc_list.append(res.topk_scores)
                 id_list.append(res.topk_docs + self.delta.base_docs)
-            if self._debug_shard_lists is not None:
-                self._debug_shard_lists.append(
-                    (rows, [np.asarray(a) for a in sc_list],
-                     [np.asarray(a) for a in id_list]))
-            if ns == 1 and self.delta is None:
-                topk[rows] = np.asarray(id_list[0])
-                topk_sc[rows] = np.asarray(sc_list[0]).astype(np.float32)
-                if drop is not None and drop[0, rows].any():
-                    dead = rows[drop[0, rows]]
-                    topk[dead] = -1
-                    topk_sc[dead] = SCORE_FILL
-            else:
-                dr = None if drop is None else drop[:, rows]
-                if dr is not None and self.delta is not None:
-                    # the delta segment is local to the merge host — never
-                    # lost, never admission-dropped
-                    dr = np.concatenate(
-                        [dr, np.zeros((1, len(rows)), bool)])
-                ids, sc = merge_shard_topk(sc_list, id_list, self.k_serve,
-                                           drop=dr)
-                topk[rows] = np.asarray(ids)
-                topk_sc[rows] = np.asarray(sc).astype(np.float32)
+            work = gather(rows, sc_list, id_list, work)
+            for s in range(ns):
+                t_shards[s, rows] = self.cost.saat_time(
+                    work[s].astype(np.float64))
 
         if len(routed.bmw_rows):
             rows = routed.bmw_rows
-            sc_list, id_list = [], []
+            sc_list, id_list, work = [], [], []
             for s in range(ns):
                 spec_s = self.shard_specs[s]
                 qcap = query_lane_budget(self._df_host[s], terms[rows],
@@ -655,8 +671,7 @@ class SearchSystem:
                                  tile_d=spec_s.tile_d, backend=self.backend)
                 sc_list.append(res.topk_scores)
                 id_list.append(res.topk_docs + self.doc_lo[s])
-                t_shards[s, rows] = self.cost.daat_time(
-                    np.asarray(res.work), np.asarray(res.blocks))
+                work.append((res.work, res.blocks))
             if self.delta is not None:
                 # rank-safe BMW over the capacity-padded delta segment: the
                 # qcap default (L * cap) is spec-static, so fill level never
@@ -672,26 +687,9 @@ class SearchSystem:
                                  tile_d=dsp.tile_d, backend=self.backend)
                 sc_list.append(res.topk_scores)
                 id_list.append(res.topk_docs + self.delta.base_docs)
-            if self._debug_shard_lists is not None:
-                self._debug_shard_lists.append(
-                    (rows, [np.asarray(a) for a in sc_list],
-                     [np.asarray(a) for a in id_list]))
-            if ns == 1 and self.delta is None:
-                topk[rows] = np.asarray(id_list[0])
-                topk_sc[rows] = np.asarray(sc_list[0]).astype(np.float32)
-                if drop is not None and drop[0, rows].any():
-                    dead = rows[drop[0, rows]]
-                    topk[dead] = -1
-                    topk_sc[dead] = SCORE_FILL
-            else:
-                dr = None if drop is None else drop[:, rows]
-                if dr is not None and self.delta is not None:
-                    dr = np.concatenate(
-                        [dr, np.zeros((1, len(rows)), bool)])
-                ids, sc = merge_shard_topk(sc_list, id_list, self.k_serve,
-                                           drop=dr)
-                topk[rows] = np.asarray(ids)
-                topk_sc[rows] = np.asarray(sc).astype(np.float32)
+            work = gather(rows, sc_list, id_list, work)
+            for s in range(ns):
+                t_shards[s, rows] = self.cost.daat_time(*work[s])
             t_bmw[rows] = self.cost.gather_time(t_shards[:, rows])
         return topk, topk_sc, t_bmw, t_shards
 
@@ -851,13 +849,14 @@ class SearchSystem:
         the cache disabled (the default) this method IS the direct
         cascade, bit-identical to the pre-cache system.
         """
-        if self.cache is None:
-            return self._serve_direct(terms, mask, topics,
+        with span(SERVE):
+            if self.cache is None:
+                return self._serve_direct(terms, mask, topics,
+                                          stage2_cap=stage2_cap,
+                                          shard_cap=shard_cap, now=now)
+            return self._serve_cached(terms, mask, topics,
                                       stage2_cap=stage2_cap,
                                       shard_cap=shard_cap, now=now)
-        return self._serve_cached(terms, mask, topics,
-                                  stage2_cap=stage2_cap,
-                                  shard_cap=shard_cap, now=now)
 
     def _serve_direct(self, terms: np.ndarray, mask: np.ndarray,
                       topics: np.ndarray | None = None, *,
@@ -870,280 +869,295 @@ class SearchSystem:
         now = float(self._clock if now is None else now)
         faulted = self.faults.active or shard_cap is not None
         if self.faults.active:
-            # drive recovery from the serve loop: probe unhealthy replicas
-            # against the schedule (a cleared window re-admits the replica)
-            probes, rec = self.pool.probe_unhealthy(
-                lambda r: self.faults.is_up(r.partition, r.replica_id, now))
-            self._fault_counters["probes"] += probes
-            self._fault_counters["recovered"] += rec
-        pk, pr, pt = self.stage0(terms, mask)
-        routed = self.sched.route(pk, pr, pt)
-        modality = None
-        if self.dense is not None:
-            # modality dispatch: dense-only rows leave the lexical
-            # sub-batches entirely (their replica picks below still pin the
-            # co-located partition replicas the dense engine runs on, so
-            # the failure protocol covers dense traffic too)
-            modality = self._modality(pt)
-            routed = self._restrict_lexical(routed, modality)
-        # route replicas before the engines run so the pool sees the whole
-        # batch in flight (power-of-two-choices balances against inflight)
-        picks, hedge_picks = self._pool_route(routed, q)
+            with span(REPLICAS):
+                # drive recovery from the serve loop: probe unhealthy
+                # replicas against the schedule (a cleared window re-admits
+                # the replica)
+                probes, rec = self.pool.probe_unhealthy(
+                    lambda r: self.faults.is_up(r.partition, r.replica_id,
+                                                now))
+                self._fault_counters["probes"] += probes
+                self._fault_counters["recovered"] += rec
+        with span(STAGE0):
+            pk, pr, pt = self.stage0(terms, mask)
+            routed = self.sched.route(pk, pr, pt)
+            modality = None
+            if self.dense is not None:
+                # modality dispatch: dense-only rows leave the lexical
+                # sub-batches entirely (their replica picks below still pin the
+                # co-located partition replicas the dense engine runs on, so
+                # the failure protocol covers dense traffic too)
+                modality = self._modality(pt)
+                routed = self._restrict_lexical(routed, modality)
+        with span(REPLICAS):
+            # route replicas before the engines run so the pool sees the whole
+            # batch in flight (power-of-two-choices balances against inflight)
+            picks, hedge_picks = self._pool_route(routed, q)
 
-        drop = None
-        coverage = None
-        if faulted:
-            # admission-chosen partial coverage: the trailing partitions
-            # are never requested — release their routed picks
-            dropped = np.zeros((ns, q), bool)
-            if shard_cap is not None:
-                cap = np.clip(np.asarray(shard_cap, np.int64), 1, ns)
-                for i in range(q):
-                    for s in range(int(cap[i]), ns):
-                        r = picks[i][s]
-                        if r is not None:
-                            r.inflight = max(r.inflight - 1, 0)
-                            picks[i][s] = None
-                        dropped[s, i] = True
-            # injected faults: timeout detection, bounded failover, loss
-            delay, mult, lost = self._fault_plan(picks, routed, now)
-            lost &= ~dropped
-            drop = lost | dropped
-            coverage = 1.0 - drop.sum(axis=0) / ns
-            n_deg = int((coverage < 1.0).sum())
-            self._fault_counters["degraded_queries"] += n_deg
+            drop = None
+            coverage = None
+            if faulted:
+                # admission-chosen partial coverage: the trailing partitions
+                # are never requested — release their routed picks
+                dropped = np.zeros((ns, q), bool)
+                if shard_cap is not None:
+                    cap = np.clip(np.asarray(shard_cap, np.int64), 1, ns)
+                    for i in range(q):
+                        for s in range(int(cap[i]), ns):
+                            r = picks[i][s]
+                            if r is not None:
+                                r.inflight = max(r.inflight - 1, 0)
+                                picks[i][s] = None
+                            dropped[s, i] = True
+                # injected faults: timeout detection, bounded failover, loss
+                delay, mult, lost = self._fault_plan(picks, routed, now)
+                lost &= ~dropped
+                drop = lost | dropped
+                coverage = 1.0 - drop.sum(axis=0) / ns
+                n_deg = int((coverage < 1.0).sum())
+                self._fault_counters["degraded_queries"] += n_deg
 
-        split_cache: dict = {}
-        topk, topk_sc, t_bmw, t_shards = self._stage1_full(
-            terms, mask, routed, split_cache, drop=drop)
+        with span(STAGE1):
+            split_cache: dict = {}
+            topk, topk_sc, t_bmw, t_shards = self._stage1_full(
+                terms, mask, routed, split_cache, drop=drop)
 
-        theta_skip = np.zeros(q, bool)
-        fallback = np.zeros(q, bool)
-        fb_extra = np.zeros(q)          # theta_low lexical-fallback latency
-        t_dense_mat = None              # (ns, Q) per-shard dense time
-        d_rows = (np.flatnonzero(modality != M_LEX)
-                  if self.dense is not None else np.zeros(0, np.int64))
-        if len(d_rows):
-            ds = self.cascade_spec.dense
-            q_emb = self.dense.embed(terms[d_rows], mask[d_rows])
-            d_ids, d_sc = self.dense.serve(
-                q_emb, self.k_serve,
-                drop=None if drop is None else drop[:, d_rows])
-            # shape-static per-shard dense time: every query scores every
-            # tile of every shard, so the matrix is query-independent
-            t_dense_mat = np.zeros((ns, q))
-            for s in range(ns):
-                t_dense_mat[s, d_rows] = float(
-                    self.cost.dense_time(self.dense.n_tiles(s)))
-            dmod = modality[d_rows]
-            only_rows = d_rows[dmod == M_DENSE]
-            both_rows = d_rows[dmod == M_BOTH]
-            # dense-only rows serve the dense list; both rows fuse the two
-            topk[only_rows] = d_ids[dmod == M_DENSE]
-            topk_sc[only_rows] = d_sc[dmod == M_DENSE]
-            if len(both_rows):
-                f_ids, f_sc = fuse(self.cascade_spec.fusion,
-                                   topk[both_rows], topk_sc[both_rows],
-                                   d_ids[dmod == M_BOTH],
-                                   d_sc[dmod == M_BOTH], self.k_serve)
-                topk[both_rows] = f_ids
-                topk_sc[both_rows] = f_sc
-            top_dense = d_sc[:, 0].astype(np.float64)
-            if np.isfinite(ds.theta_high):
-                # high-confidence shortcut: Stage-2 is skipped rank-safely
-                # (the existing zero-grid path serves the Stage-1 order)
-                theta_skip[d_rows] = top_dense >= ds.theta_high
-            if np.isfinite(ds.theta_low) and len(only_rows):
-                fb_rows = only_rows[top_dense[dmod == M_DENSE]
-                                    < ds.theta_low]
-                if len(fb_rows):
-                    # low-confidence dense-only rows re-issue a ρ-capped
-                    # lexical traversal — same cap and nominal-healthy
-                    # pricing as the scheduler's late hedge, so the route
-                    # stays inside worst_case_us
-                    fb_routed = RoutedBatch(
-                        jass_rows=fb_rows,
-                        bmw_rows=np.zeros(0, np.int64),
-                        hedged_rows=np.zeros(0, np.int64),
-                        k=routed.k,
-                        rho=np.minimum(
-                            routed.rho,
-                            float(self.sched.cfg.resolved_late_rho())))
-                    fb_topk, fb_sc, _, fb_tsh = self._stage1_full(
-                        terms, mask, fb_routed, split_cache)
-                    topk[fb_rows] = fb_topk[fb_rows]
-                    topk_sc[fb_rows] = fb_sc[fb_rows]
-                    fb_extra[fb_rows] = self.cost.gather_time(
-                        fb_tsh[:, fb_rows])
-                    fallback[fb_rows] = True
+            theta_skip = np.zeros(q, bool)
+            fallback = np.zeros(q, bool)
+            fb_extra = np.zeros(q)        # theta_low lexical-fallback latency
+            t_dense_mat = None              # (ns, Q) per-shard dense time
+            d_rows = (np.flatnonzero(modality != M_LEX)
+                      if self.dense is not None else np.zeros(0, np.int64))
+            if len(d_rows):
+                ds = self.cascade_spec.dense
+                q_emb = self.dense.embed(terms[d_rows], mask[d_rows])
+                d_ids, d_sc = self.dense.serve(
+                    q_emb, self.k_serve,
+                    drop=None if drop is None else drop[:, d_rows])
+                # shape-static per-shard dense time: every query scores every
+                # tile of every shard, so the matrix is query-independent
+                t_dense_mat = np.zeros((ns, q))
+                for s in range(ns):
+                    t_dense_mat[s, d_rows] = float(
+                        self.cost.dense_time(self.dense.n_tiles(s)))
+                dmod = modality[d_rows]
+                only_rows = d_rows[dmod == M_DENSE]
+                both_rows = d_rows[dmod == M_BOTH]
+                # dense-only rows serve the dense list; both rows fuse the two
+                topk[only_rows] = d_ids[dmod == M_DENSE]
+                topk_sc[only_rows] = d_sc[dmod == M_DENSE]
+                if len(both_rows):
+                    f_ids, f_sc = fuse(self.cascade_spec.fusion,
+                                       topk[both_rows], topk_sc[both_rows],
+                                       d_ids[dmod == M_BOTH],
+                                       d_sc[dmod == M_BOTH], self.k_serve)
+                    topk[both_rows] = f_ids
+                    topk_sc[both_rows] = f_sc
+                top_dense = d_sc[:, 0].astype(np.float64)
+                if np.isfinite(ds.theta_high):
+                    # high-confidence shortcut: Stage-2 is skipped rank-safely
+                    # (the existing zero-grid path serves the Stage-1 order)
+                    theta_skip[d_rows] = top_dense >= ds.theta_high
+                if np.isfinite(ds.theta_low) and len(only_rows):
+                    fb_rows = only_rows[top_dense[dmod == M_DENSE]
+                                        < ds.theta_low]
+                    if len(fb_rows):
+                        # low-confidence dense-only rows re-issue a ρ-capped
+                        # lexical traversal — same cap and nominal-healthy
+                        # pricing as the scheduler's late hedge, so the route
+                        # stays inside worst_case_us
+                        fb_routed = RoutedBatch(
+                            jass_rows=fb_rows,
+                            bmw_rows=np.zeros(0, np.int64),
+                            hedged_rows=np.zeros(0, np.int64),
+                            k=routed.k,
+                            rho=np.minimum(
+                                routed.rho,
+                                float(self.sched.cfg.resolved_late_rho())))
+                        fb_topk, fb_sc, _, fb_tsh = self._stage1_full(
+                            terms, mask, fb_routed, split_cache)
+                        topk[fb_rows] = fb_topk[fb_rows]
+                        topk_sc[fb_rows] = fb_sc[fb_rows]
+                        fb_extra[fb_rows] = self.cost.gather_time(
+                            fb_tsh[:, fb_rows])
+                        fallback[fb_rows] = True
 
-        if faulted:
-            # per-shard completion time under the plan: a served slot pays
-            # its retry wait plus the (possibly straggler-slowed) engine
-            # time; a lost slot pays the full detection chain; a dropped
-            # slot was never requested.  The query still waits for its
-            # slowest slot (scatter-gather), and pays merge fan-out only
-            # over the partitions that answered.
-            t_fault = np.where(dropped, 0.0,
-                               delay + np.where(lost, 0.0, t_shards * mult))
-            n_live = ns - drop.sum(axis=0)
-            gather_ov = (self.cost.gather_per_shard_us
-                         * np.maximum(n_live - 1, 0))
+        with span(ACCOUNT):
+            if faulted:
+                # per-shard completion time under the plan: a served slot pays
+                # its retry wait plus the (possibly straggler-slowed) engine
+                # time; a lost slot pays the full detection chain; a dropped
+                # slot was never requested.  The query still waits for its
+                # slowest slot (scatter-gather), and pays merge fan-out only
+                # over the partitions that answered.
+                t_fault = np.where(dropped, 0.0,
+                                   delay + np.where(lost, 0.0,
+                                                    t_shards * mult))
+                n_live = ns - drop.sum(axis=0)
+                gather_ov = (self.cost.gather_per_shard_us
+                             * np.maximum(n_live - 1, 0))
 
-            def _gather_fault(tmat, rows):
-                return tmat.max(axis=0) + gather_ov[rows]
+                def _gather_fault(tmat, rows):
+                    return tmat.max(axis=0) + gather_ov[rows]
 
-            t_bmw = np.zeros(q)
+                t_bmw = np.zeros(q)
+                if len(routed.bmw_rows):
+                    rows = routed.bmw_rows
+                    t_bmw[rows] = _gather_fault(t_fault[:, rows], rows)
+
+                def jass_fault_fn(rows, rho):
+                    work_s, _ = self._jass_split(terms, mask, rows, rho,
+                                                 split_cache)
+                    t = np.stack([self.cost.saat_time(w.astype(np.float64))
+                                  for w in work_s[:ns]])
+                    tf = np.where(dropped[:, rows], 0.0,
+                                  delay[:, rows]
+                                  + np.where(lost[:, rows], 0.0,
+                                             t * mult[:, rows]))
+                    return _gather_fault(tf, rows)
+
+                # the deadline re-issue goes to a fresh healthy replica, so
+                # it pays nominal JASS cost — the retry wait it could still
+                # incur is charged analytically via SchedulerConfig.retry_us()
+                lat01 = self.sched.resolve_times(
+                    routed, t_bmw, jass_fault_fn,
+                    late_jass_fn=self._jass_time(terms, mask, split_cache))
+                t_pool = t_fault
+                if t_dense_mat is not None:
+                    # dense requests ride the same failure protocol: a served
+                    # slot pays its retry wait + (possibly straggler-slowed)
+                    # dense engine time, lost/dropped slots exactly as lexical
+                    t_dense_eff = np.where(
+                        dropped, 0.0,
+                        delay + np.where(lost, 0.0, t_dense_mat * mult))
+                    t_pool = np.maximum(t_pool, t_dense_eff)
+                    tdr = np.zeros(q)
+                    tdr[d_rows] = (t_dense_eff[:, d_rows].max(axis=0)
+                                   + gather_ov[d_rows])
+            else:
+                lat01 = self.sched.resolve_times(
+                    routed, t_bmw, self._jass_time(terms, mask, split_cache))
+                t_pool = t_shards
+                if t_dense_mat is not None:
+                    # a partition replica hosting both engines is busy for the
+                    # max of its co-located work
+                    t_pool = np.maximum(t_pool, t_dense_mat)
+                    tdr = np.zeros(q)
+                    tdr[d_rows] = self.cost.gather_time(t_dense_mat[:, d_rows])
+            if len(d_rows):
+                # dense-only: predict + dense scatter-gather (+ any theta_low
+                # fallback); both: the two engines run in parallel, the query
+                # waits for the slower and pays the host-side fusion merge
+                pd = self.cost.predict_us
+                only = modality == M_DENSE
+                both = modality == M_BOTH
+                lat01 = np.where(only, pd + tdr + fb_extra, lat01)
+                lat01 = np.where(both,
+                                 pd + np.maximum(lat01 - pd, tdr)
+                                 + self.cost.fusion_us, lat01)
+            if self.delta is not None:
+                # every served query scans the delta segment; its arrays are
+                # capacity-padded, so the cost is one shape-static term —
+                # charged here, BEFORE budget enforcement trims Stage-2, and
+                # identically inside worst_case_us()
+                lat01 = lat01 + self._delta_us
+            t0 = np.full(q, self.cost.predict_us)
+            stage_latency = {"stage0": t0, "stage1": lat01 - t0}
+
             if len(routed.bmw_rows):
-                rows = routed.bmw_rows
-                t_bmw[rows] = _gather_fault(t_fault[:, rows], rows)
+                # online quantile-error signal for the t predictor: pinball
+                # loss of pred_t against the observed BMW engine time, at the
+                # predictor's own training tau — feeds _adapt_routing's
+                # hedge_deadline loop
+                tau = self.cascade_spec.stage0.tau_t
+                e = t_bmw[routed.bmw_rows] - pt[routed.bmw_rows]
+                pin = float(np.mean(np.maximum(tau * e, (tau - 1.0) * e)))
+                self._pinball_ewma = (
+                    pin if self._pinball_ewma is None
+                    else 0.8 * self._pinball_ewma + 0.2 * pin)
 
-            def jass_fault_fn(rows, rho):
-                work_s, _ = self._jass_split(terms, mask, rows, rho,
-                                             split_cache)
-                t = np.stack([self.cost.saat_time(w.astype(np.float64))
-                              for w in work_s[:ns]])
-                tf = np.where(dropped[:, rows], 0.0,
-                              delay[:, rows]
-                              + np.where(lost[:, rows], 0.0,
-                                         t * mult[:, rows]))
-                return _gather_fault(tf, rows)
+        with span(STAGE2):
+            final = None
+            used = None
+            enforce = self.sched.cfg.enforce_budget
+            trimmed = skipped = 0
+            if self.ltr is not None:
+                if topics is None:
+                    raise ValueError(
+                        "Stage-2 re-ranking needs per-query topics")
+                k2 = np.minimum(routed.k, self.k_serve)
+                if stage2_cap is not None:
+                    # admission-control degrade ladder: the cap is decided from
+                    # response-time slack (queueing included), before the
+                    # service-budget enforcement below
+                    k2 = np.minimum(k2, np.asarray(stage2_cap, np.int64))
+                if drop is not None:
+                    # degraded queries may hold fewer than k_serve real
+                    # candidates (-1 padding from the masked merge): never ask
+                    # Stage-2 to rank the padding
+                    k2 = np.minimum(k2, (topk >= 0).sum(axis=1))
+                if theta_skip.any():
+                    # dense confidence shortcut: the Stage-1 order is served
+                    # directly (rank-safe), zeroed BEFORE enforcement so these
+                    # rows never count as budget-driven skips
+                    k2 = np.where(theta_skip, 0, k2)
+                if enforce:
+                    # cascade hedge: a query whose Stage-1 time already ate
+                    # the budget gets its candidate grid trimmed (masked
+                    # re-rank) — or skipped outright — so ltr_time cannot
+                    # push it over.
+                    # When the Stage-1 bound holds, the Stage-2 reservation
+                    # guarantees afford >= k_serve and this is a no-op.
+                    afford = stage2_afford(self.cost, self.budget - lat01,
+                                           self.k_serve)
+                    trimmed = int(np.sum((0 < afford) & (afford < k2)))
+                    skipped = int(np.sum((afford == 0) & (k2 > 0)))
+                    k2 = np.minimum(k2, afford)
+                cand = topk if drop is None else np.where(topk >= 0, topk, 0)
+                res2 = self.stage2(terms, mask, topics,
+                                   cand.astype(np.int32), k2)
+                final, used = res2.final, res2.candidates_used
+                skip_rows = np.flatnonzero(k2 == 0)
+                if len(skip_rows):
+                    # zero-grid queries (enforcement skip or admission's
+                    # stage1-only rung) serve their Stage-1 order directly
+                    # (the rank-safe list) at zero Stage-2 cost
+                    final[skip_rows] = topk[skip_rows, :self.t_final]
+                stage_latency["stage2"] = np.where(
+                    used > 0, self.cost.ltr_time(used), 0.0)
+            else:
+                stage_latency["stage2"] = np.zeros(q)
 
-            # the deadline re-issue goes to a fresh healthy replica, so it
-            # pays nominal JASS cost — the retry wait it could still incur
-            # is charged analytically via SchedulerConfig.retry_us()
-            lat01 = self.sched.resolve_times(
-                routed, t_bmw, jass_fault_fn,
-                late_jass_fn=self._jass_time(terms, mask, split_cache))
-            t_pool = t_fault
-            if t_dense_mat is not None:
-                # dense requests ride the same failure protocol: a served
-                # slot pays its retry wait + (possibly straggler-slowed)
-                # dense engine time, lost/dropped slots exactly as lexical
-                t_dense_eff = np.where(dropped, 0.0,
-                                       delay + np.where(lost, 0.0,
-                                                        t_dense_mat * mult))
-                t_pool = np.maximum(t_pool, t_dense_eff)
-                tdr = np.zeros(q)
-                tdr[d_rows] = (t_dense_eff[:, d_rows].max(axis=0)
-                               + gather_ov[d_rows])
-        else:
-            lat01 = self.sched.resolve_times(
-                routed, t_bmw, self._jass_time(terms, mask, split_cache))
-            t_pool = t_shards
-            if t_dense_mat is not None:
-                # a partition replica hosting both engines is busy for the
-                # max of its co-located work
-                t_pool = np.maximum(t_pool, t_dense_mat)
-                tdr = np.zeros(q)
-                tdr[d_rows] = self.cost.gather_time(t_dense_mat[:, d_rows])
-        if len(d_rows):
-            # dense-only: predict + dense scatter-gather (+ any theta_low
-            # fallback); both: the two engines run in parallel, the query
-            # waits for the slower and pays the host-side fusion merge
-            pd = self.cost.predict_us
-            only = modality == M_DENSE
-            both = modality == M_BOTH
-            lat01 = np.where(only, pd + tdr + fb_extra, lat01)
-            lat01 = np.where(both,
-                             pd + np.maximum(lat01 - pd, tdr)
-                             + self.cost.fusion_us, lat01)
-        if self.delta is not None:
-            # every served query scans the delta segment; its arrays are
-            # capacity-padded, so the cost is one shape-static term —
-            # charged here, BEFORE budget enforcement trims Stage-2, and
-            # identically inside worst_case_us()
-            lat01 = lat01 + self._delta_us
-        t0 = np.full(q, self.cost.predict_us)
-        stage_latency = {"stage0": t0, "stage1": lat01 - t0}
+        with span(REPLICAS):
+            self._pool_complete(terms, mask, routed, picks, hedge_picks,
+                                t_pool, split_cache)
+            every = self.cascade_spec.routing.adapt_every
+            if every and self._batches % every == 0:
+                self._adapt_routing()
 
-        if len(routed.bmw_rows):
-            # online quantile-error signal for the t predictor: pinball
-            # loss of pred_t against the observed BMW engine time, at the
-            # predictor's own training tau — feeds _adapt_routing's
-            # hedge_deadline loop
-            tau = self.cascade_spec.stage0.tau_t
-            e = t_bmw[routed.bmw_rows] - pt[routed.bmw_rows]
-            pin = float(np.mean(np.maximum(tau * e, (tau - 1.0) * e)))
-            self._pinball_ewma = (pin if self._pinball_ewma is None
-                                  else 0.8 * self._pinball_ewma + 0.2 * pin)
-
-        final = None
-        used = None
-        enforce = self.sched.cfg.enforce_budget
-        trimmed = skipped = 0
-        if self.ltr is not None:
-            if topics is None:
-                raise ValueError("Stage-2 re-ranking needs per-query topics")
-            k2 = np.minimum(routed.k, self.k_serve)
-            if stage2_cap is not None:
-                # admission-control degrade ladder: the cap is decided from
-                # response-time slack (queueing included), before the
-                # service-budget enforcement below
-                k2 = np.minimum(k2, np.asarray(stage2_cap, np.int64))
-            if drop is not None:
-                # degraded queries may hold fewer than k_serve real
-                # candidates (-1 padding from the masked merge): never ask
-                # Stage-2 to rank the padding
-                k2 = np.minimum(k2, (topk >= 0).sum(axis=1))
-            if theta_skip.any():
-                # dense confidence shortcut: the Stage-1 order is served
-                # directly (rank-safe), zeroed BEFORE enforcement so these
-                # rows never count as budget-driven skips
-                k2 = np.where(theta_skip, 0, k2)
-            if enforce:
-                # cascade hedge: a query whose Stage-1 time already ate the
-                # budget gets its candidate grid trimmed (masked re-rank) —
-                # or skipped outright — so ltr_time cannot push it over.
-                # When the Stage-1 bound holds, the Stage-2 reservation
-                # guarantees afford >= k_serve and this is a no-op.
-                afford = stage2_afford(self.cost, self.budget - lat01,
-                                       self.k_serve)
-                trimmed = int(np.sum((0 < afford) & (afford < k2)))
-                skipped = int(np.sum((afford == 0) & (k2 > 0)))
-                k2 = np.minimum(k2, afford)
-            cand = topk if drop is None else np.where(topk >= 0, topk, 0)
-            res2 = self.stage2(terms, mask, topics, cand.astype(np.int32), k2)
-            final, used = res2.final, res2.candidates_used
-            skip_rows = np.flatnonzero(k2 == 0)
-            if len(skip_rows):
-                # zero-grid queries (enforcement skip or admission's
-                # stage1-only rung) serve their Stage-1 order directly
-                # (the rank-safe list) at zero Stage-2 cost
-                final[skip_rows] = topk[skip_rows, :self.t_final]
-            stage_latency["stage2"] = np.where(
-                used > 0, self.cost.ltr_time(used), 0.0)
-        else:
-            stage_latency["stage2"] = np.zeros(q)
-
-        self._pool_complete(terms, mask, routed, picks, hedge_picks,
-                            t_pool, split_cache)
-        every = self.cascade_spec.routing.adapt_every
-        if every and self._batches % every == 0:
-            self._adapt_routing()
-
-        lat = lat01 + stage_latency["stage2"]
-        # the serving clock advances by the batch's occupancy so fault
-        # windows expressed in cost-model time mean the same thing whether
-        # serve() is driven offline or by the online event loop
-        self._clock = now + (float(lat.max()) if q else 0.0)
-        dense_info = None
-        if self.dense is not None:
-            dense_info = {"modality": modality, "theta_skip": theta_skip,
-                          "fallback": fallback}
-        stats = self._build_stats(
-            lat, stage_latency, trimmed, skipped, faulted, coverage, now,
-            dense_info=dense_info)
-        if self.telemetry is not None:
-            self._record_traces(
-                q=q, now=now, lat=lat, stage_latency=stage_latency,
-                pk=pk, pr=pr, pt=pt, routed=routed, modality=modality,
-                theta_skip=theta_skip, fallback=fallback, used=used,
-                t_shards=t_shards, faulted=faulted,
-                delay=delay if faulted else None,
-                mult=mult if faulted else None,
-                lost=lost if faulted else None,
-                dropped=dropped if faulted else None, coverage=coverage)
+        with span(ACCOUNT):
+            lat = lat01 + stage_latency["stage2"]
+            # the serving clock advances by the batch's occupancy so fault
+            # windows expressed in cost-model time mean the same thing whether
+            # serve() is driven offline or by the online event loop
+            self._clock = now + (float(lat.max()) if q else 0.0)
+            dense_info = None
+            if self.dense is not None:
+                dense_info = {"modality": modality, "theta_skip": theta_skip,
+                              "fallback": fallback}
+            stats = self._build_stats(
+                lat, stage_latency, trimmed, skipped, faulted, coverage, now,
+                dense_info=dense_info)
+            if self.telemetry is not None:
+                self._record_traces(
+                    q=q, now=now, lat=lat, stage_latency=stage_latency,
+                    pk=pk, pr=pr, pt=pt, routed=routed, modality=modality,
+                    theta_skip=theta_skip, fallback=fallback, used=used,
+                    t_shards=t_shards, faulted=faulted,
+                    delay=delay if faulted else None,
+                    mult=mult if faulted else None,
+                    lost=lost if faulted else None,
+                    dropped=dropped if faulted else None, coverage=coverage)
         return PipelineResult(topk=topk, final=final, candidates_used=used,
                               latency=lat, stage_latency=stage_latency,
                               stats=stats, coverage=coverage,
@@ -1251,125 +1265,130 @@ class SearchSystem:
         ns = self.n_shards
         now = float(self._clock if now is None else now)
         cache = self.cache
-        epoch = self._cache_epoch(now)
-        pk, pr, pt = self.stage0(terms, mask)
-        routed = self._pure_route(pk, pr, pt)
-        # the resolved modality is part of the route: lexical, dense and
-        # fused entries for the same query must never collide (with dense
-        # disabled the suffix is b"" and keys are byte-identical)
-        modality = self._modality(pt) if self.dense is not None else None
-        is_jass = np.zeros(q, bool)
-        is_jass[routed.jass_rows] = True
+        with span(STAGE0):
+            pk, pr, pt = self.stage0(terms, mask)
+            routed = self._pure_route(pk, pr, pt)
+            # the resolved modality is part of the route: lexical, dense and
+            # fused entries for the same query must never collide (with dense
+            # disabled the suffix is b"" and keys are byte-identical)
+            modality = self._modality(pt) if self.dense is not None else None
+            is_jass = np.zeros(q, bool)
+            is_jass[routed.jass_rows] = True
 
-        cap = np.full(q, self.k_serve, np.int64)
-        if stage2_cap is not None:
-            cap = np.minimum(np.asarray(stage2_cap, np.int64), self.k_serve)
-        # the partial-coverage rung deliberately queries fewer partitions:
-        # those rows neither look up nor fill (a full-coverage cached
-        # result would silently upgrade the admission decision)
-        eligible = (np.ones(q, bool) if shard_cap is None
-                    else np.asarray(shard_cap, np.int64) >= ns)
+        with span(CACHE):
+            epoch = self._cache_epoch(now)
+            cap = np.full(q, self.k_serve, np.int64)
+            if stage2_cap is not None:
+                cap = np.minimum(np.asarray(stage2_cap, np.int64),
+                                 self.k_serve)
+            # the partial-coverage rung deliberately queries fewer partitions:
+            # those rows neither look up nor fill (a full-coverage cached
+            # result would silently upgrade the admission decision)
+            eligible = (np.ones(q, bool) if shard_cap is None
+                        else np.asarray(shard_cap, np.int64) >= ns)
 
-        keys1 = [None] * q
-        keys2 = [None] * q
-        l1_hit = np.zeros(q, bool)
-        l2_hit = np.zeros(q, bool)
-        l1_vals: dict = {}
-        l2_vals: dict = {}
-        for i in range(q):
-            if not eligible[i]:
-                cache.counters["skipped_partial"] += 1
-                continue
-            cache.counters["lookups"] += 1
-            qk = normalize_query(terms[i], mask[i],
-                                 None if topics is None else topics[i])
-            rs = route_sig(bool(is_jass[i]), float(routed.rho[i]),
-                           float(routed.k[i]),
-                           b"" if modality is None
-                           else b"|M%d" % modality[i])
-            keys1[i] = l1_key(qk, rs, self.k_serve, self.t_final,
-                              int(cap[i]))
-            v = cache.l1_get(keys1[i], epoch)
-            if v is not None:
-                l1_hit[i] = True
-                l1_vals[i] = v
-                cache.counters["l1_hits"] += 1
-                continue
-            keys2[i] = l2_key(qk, rs)
-            if self.ltr is not None:
-                v2 = cache.l2_get(keys2[i], epoch)
-                if v2 is not None:
-                    l2_hit[i] = True
-                    l2_vals[i] = v2
-                    cache.counters["l2_hits"] += 1
+            keys1 = [None] * q
+            keys2 = [None] * q
+            l1_hit = np.zeros(q, bool)
+            l2_hit = np.zeros(q, bool)
+            l1_vals: dict = {}
+            l2_vals: dict = {}
+            for i in range(q):
+                if not eligible[i]:
+                    cache.counters["skipped_partial"] += 1
                     continue
-            cache.counters["full_misses"] += 1
+                cache.counters["lookups"] += 1
+                qk = normalize_query(terms[i], mask[i],
+                                     None if topics is None else topics[i])
+                rs = route_sig(bool(is_jass[i]), float(routed.rho[i]),
+                               float(routed.k[i]),
+                               b"" if modality is None
+                               else b"|M%d" % modality[i])
+                keys1[i] = l1_key(qk, rs, self.k_serve, self.t_final,
+                                  int(cap[i]))
+                v = cache.l1_get(keys1[i], epoch)
+                if v is not None:
+                    l1_hit[i] = True
+                    l1_vals[i] = v
+                    cache.counters["l1_hits"] += 1
+                    continue
+                keys2[i] = l2_key(qk, rs)
+                if self.ltr is not None:
+                    v2 = cache.l2_get(keys2[i], epoch)
+                    if v2 is not None:
+                        l2_hit[i] = True
+                        l2_vals[i] = v2
+                        cache.counters["l2_hits"] += 1
+                        continue
+                cache.counters["full_misses"] += 1
 
-        hit_us = self.cost.cache_hit_us
-        topk = np.zeros((q, self.k_serve), np.int64)
-        final_rows: list = [None] * q
-        used = np.zeros(q, np.int64) if self.ltr is not None else None
-        t0 = np.full(q, self.cost.predict_us)
-        t1 = np.zeros(q)
-        t2 = np.zeros(q)
-        faulted = self.faults.active or shard_cap is not None
-        coverage = np.ones(q) if faulted else None
-        trimmed = skipped = 0
+            hit_us = self.cost.cache_hit_us
+            topk = np.zeros((q, self.k_serve), np.int64)
+            final_rows: list = [None] * q
+            used = np.zeros(q, np.int64) if self.ltr is not None else None
+            t0 = np.full(q, self.cost.predict_us)
+            t1 = np.zeros(q)
+            t2 = np.zeros(q)
+            faulted = self.faults.active or shard_cap is not None
+            coverage = np.ones(q) if faulted else None
+            trimmed = skipped = 0
 
-        rows1 = np.flatnonzero(l1_hit)
-        for i in rows1:
-            tk, f, u = l1_vals[i]
-            topk[i] = tk
-            if self.ltr is not None:
-                final_rows[i] = f
-                used[i] = u
-        t1[rows1] = hit_us
+            rows1 = np.flatnonzero(l1_hit)
+            for i in rows1:
+                tk, f, u = l1_vals[i]
+                topk[i] = tk
+                if self.ltr is not None:
+                    final_rows[i] = f
+                    used[i] = u
+            t1[rows1] = hit_us
 
         rows2 = np.flatnonzero(l2_hit)
         skip_flags = None
         if len(rows2):
-            vals = [l2_vals[i] for i in rows2]
-            if self.dense is not None:
-                # dense-mode L2 entries carry the fill-time theta-skip
-                # decision, so a hit replays the same Stage-2 shortcut the
-                # cold serve took
-                cand = np.stack([v[0] for v in vals])
-                skip_flags = np.array([bool(v[1]) for v in vals])
-            else:
-                cand = np.stack(vals)
-            topk[rows2] = cand
-            t1[rows2] = hit_us
-            k2 = np.minimum(np.minimum(routed.k[rows2], self.k_serve),
-                            cap[rows2]).astype(np.int64)
-            if skip_flags is not None:
-                k2[skip_flags] = 0
-            if self.sched.cfg.enforce_budget:
-                # same enforcement as the cold path, priced at the hit's
-                # actual stage-1 cost — a hit has the slack to afford the
-                # full grid whenever the reserve holds
-                afford = stage2_afford(
-                    self.cost,
-                    self.budget - (self.cost.predict_us + hit_us),
-                    self.k_serve)
-                trimmed += int(np.sum((0 < afford) & (afford < k2)))
-                skipped += int(np.sum((afford == 0) & (k2 > 0)))
-                k2 = np.minimum(k2, afford)
-            res2 = self.stage2(terms[rows2], mask[rows2], topics[rows2],
-                               cand.astype(np.int32), k2)
-            f2, u2 = res2.final, res2.candidates_used
-            skip = np.flatnonzero(k2 == 0)
-            if len(skip):
-                f2[skip] = cand[skip, :self.t_final]
-            for j, i in enumerate(rows2):
-                final_rows[i] = f2[j]
-                used[i] = u2[j]
-            t2[rows2] = np.where(u2 > 0, self.cost.ltr_time(u2), 0.0)
-            # promote: the fresh full-coverage re-rank is exactly an L1
-            # entry for this (query, route, stage-2 params) point
-            for j, i in enumerate(rows2):
-                cache.l1_put(keys1[i],
-                             (topk[i].copy(), f2[j].copy(), int(u2[j])),
-                             epoch)
+            with span(STAGE2):
+                vals = [l2_vals[i] for i in rows2]
+                if self.dense is not None:
+                    # dense-mode L2 entries carry the fill-time theta-skip
+                    # decision, so a hit replays the same Stage-2 shortcut the
+                    # cold serve took
+                    cand = np.stack([v[0] for v in vals])
+                    skip_flags = np.array([bool(v[1]) for v in vals])
+                else:
+                    cand = np.stack(vals)
+                topk[rows2] = cand
+                t1[rows2] = hit_us
+                k2 = np.minimum(np.minimum(routed.k[rows2], self.k_serve),
+                                cap[rows2]).astype(np.int64)
+                if skip_flags is not None:
+                    k2[skip_flags] = 0
+                if self.sched.cfg.enforce_budget:
+                    # same enforcement as the cold path, priced at the hit's
+                    # actual stage-1 cost — a hit has the slack to afford the
+                    # full grid whenever the reserve holds
+                    afford = stage2_afford(
+                        self.cost,
+                        self.budget - (self.cost.predict_us + hit_us),
+                        self.k_serve)
+                    trimmed += int(np.sum((0 < afford) & (afford < k2)))
+                    skipped += int(np.sum((afford == 0) & (k2 > 0)))
+                    k2 = np.minimum(k2, afford)
+                res2 = self.stage2(terms[rows2], mask[rows2], topics[rows2],
+                                   cand.astype(np.int32), k2)
+                f2, u2 = res2.final, res2.candidates_used
+                skip = np.flatnonzero(k2 == 0)
+                if len(skip):
+                    f2[skip] = cand[skip, :self.t_final]
+                for j, i in enumerate(rows2):
+                    final_rows[i] = f2[j]
+                    used[i] = u2[j]
+                t2[rows2] = np.where(u2 > 0, self.cost.ltr_time(u2), 0.0)
+            with span(CACHE):
+                # promote: the fresh full-coverage re-rank is exactly an L1
+                # entry for this (query, route, stage-2 params) point
+                for j, i in enumerate(rows2):
+                    cache.l1_put(keys1[i],
+                                 (topk[i].copy(), f2[j].copy(), int(u2[j])),
+                                 epoch)
 
         miss_rows = np.flatnonzero(~(l1_hit | l2_hit))
         sub = None
@@ -1415,51 +1434,53 @@ class SearchSystem:
             sb = sub.stats["budget"]
             trimmed += sb["stage2_trimmed"]
             skipped += sb["stage2_skipped"]
-            for j, i in enumerate(miss_rows):
-                if not eligible[i]:
-                    continue
-                if sub.coverage is not None and sub.coverage[j] < 1.0:
-                    cache.counters["skipped_partial"] += 1
-                    continue   # partial coverage is never cached
-                if self.ltr is not None:
-                    v2 = sub.topk[j].copy()
-                    if self.dense is not None:
-                        v2 = (v2, bool(sub.dense["theta_skip"][j]))
-                    cache.l2_put(keys2[i], v2, epoch)
-                    cache.l1_put(keys1[i],
-                                 (sub.topk[j].copy(), sub.final[j].copy(),
-                                  int(sub.candidates_used[j])), epoch)
-                else:
-                    cache.l1_put(keys1[i],
-                                 (sub.topk[j].copy(), None, None), epoch)
+            with span(CACHE):
+                for j, i in enumerate(miss_rows):
+                    if not eligible[i]:
+                        continue
+                    if sub.coverage is not None and sub.coverage[j] < 1.0:
+                        cache.counters["skipped_partial"] += 1
+                        continue   # partial coverage is never cached
+                    if self.ltr is not None:
+                        v2 = sub.topk[j].copy()
+                        if self.dense is not None:
+                            v2 = (v2, bool(sub.dense["theta_skip"][j]))
+                        cache.l2_put(keys2[i], v2, epoch)
+                        cache.l1_put(keys1[i],
+                                     (sub.topk[j].copy(), sub.final[j].copy(),
+                                      int(sub.candidates_used[j])), epoch)
+                    else:
+                        cache.l1_put(keys1[i],
+                                     (sub.topk[j].copy(), None, None), epoch)
 
-        final = (np.stack(final_rows) if self.ltr is not None else None)
-        lat = t0 + t1 + t2
-        stage_latency = {"stage0": t0, "stage1": t1, "stage2": t2}
-        # the batch advances the shared serving clock exactly like the
-        # direct path (the miss sub-serve's advance is overridden: the
-        # batch's occupancy is the max over ALL its rows)
-        self._clock = now + (float(lat.max()) if q else 0.0)
+        with span(ACCOUNT):
+            final = (np.stack(final_rows) if self.ltr is not None else None)
+            lat = t0 + t1 + t2
+            stage_latency = {"stage0": t0, "stage1": t1, "stage2": t2}
+            # the batch advances the shared serving clock exactly like the
+            # direct path (the miss sub-serve's advance is overridden: the
+            # batch's occupancy is the max over ALL its rows)
+            self._clock = now + (float(lat.max()) if q else 0.0)
 
-        dense_info = None
-        if self.dense is not None:
-            theta_all = np.zeros(q, bool)
-            fb_all = np.zeros(q, bool)
-            if sub is not None:
-                theta_all[miss_rows] = sub.dense["theta_skip"]
-                fb_all[miss_rows] = sub.dense["fallback"]
-            if skip_flags is not None:
-                theta_all[rows2] = skip_flags
-            # L1 rows keep False flags: their final list already baked in
-            # whatever shortcut the fill-time serve took
-            dense_info = {"modality": modality, "theta_skip": theta_all,
-                          "fallback": fb_all}
-        stats = self._build_stats(
-            lat, stage_latency, trimmed, skipped, faulted, coverage, now,
-            dense_info=dense_info, cache_stats=cache.stats())
-        if self.telemetry is not None:
-            self._record_hit_traces(l1_hit, l2_hit, lat, t0, t2, hit_us,
-                                    now)
+            dense_info = None
+            if self.dense is not None:
+                theta_all = np.zeros(q, bool)
+                fb_all = np.zeros(q, bool)
+                if sub is not None:
+                    theta_all[miss_rows] = sub.dense["theta_skip"]
+                    fb_all[miss_rows] = sub.dense["fallback"]
+                if skip_flags is not None:
+                    theta_all[rows2] = skip_flags
+                # L1 rows keep False flags: their final list already baked in
+                # whatever shortcut the fill-time serve took
+                dense_info = {"modality": modality, "theta_skip": theta_all,
+                              "fallback": fb_all}
+            stats = self._build_stats(
+                lat, stage_latency, trimmed, skipped, faulted, coverage, now,
+                dense_info=dense_info, cache_stats=cache.stats())
+            if self.telemetry is not None:
+                self._record_hit_traces(l1_hit, l2_hit, lat, t0, t2, hit_us,
+                                        now)
         return PipelineResult(topk=topk, final=final, candidates_used=used,
                               latency=lat, stage_latency=stage_latency,
                               stats=stats, coverage=coverage,

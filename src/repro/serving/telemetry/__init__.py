@@ -5,6 +5,11 @@ The :class:`Telemetry` facade owns one :class:`MetricsRegistry`, one
 allocated by ``SearchSystem`` only when ``TelemetrySpec.enabled`` — a
 disabled spec is provably inert: no registry exists and every hook in
 the serving path is guarded on ``system.telemetry is None``.
+
+Its wall-clock counterpart is :mod:`.spans`: ``cascade.*`` spans that
+``SearchSystem.serve`` writes into the JAX profiler trace, on the device
+ops' clock.  They record nothing outside a profiler trace and are not
+deterministic.
 """
 
 from __future__ import annotations

@@ -11,6 +11,7 @@ a kernel's lane axis is no longer split into fixed-width chunks.
 """
 
 import os
+import re
 from pathlib import Path
 
 import jax
@@ -51,11 +52,15 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _compile(fn, one_chip, *shapes):
+def _compile(fn, kernel, one_chip, *shapes):
+    """Compile ``fn`` and check that the kernel's custom call carries the
+    stable name ``kernel`` (the device trace and the benchmark's kernel
+    metrics match ops by it)."""
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
             for s, dt in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
-    assert "tpu_custom_call" in text
+    assert re.search(rf"%{kernel}(\.\d+)? = [^\n]*"
+                     r'custom_call_target="tpu_custom_call"', text), kernel
 
 
 def test_impact_accumulate_compiles(one_chip):
@@ -63,8 +68,8 @@ def test_impact_accumulate_compiles(one_chip):
         return impact_accumulate_tiles(td, tt, ti, qt, lstar, tile_d=TILE_D,
                                        interpret=False)
     mirror = ((N_TILES, TILE_CAP), jnp.int32)
-    _compile(fn, one_chip, mirror, mirror, mirror, ((Q, L), jnp.int32),
-             ((Q,), jnp.int32))
+    _compile(fn, "impact_accumulate_batched", one_chip, mirror, mirror,
+             mirror, ((Q, L), jnp.int32), ((Q,), jnp.int32))
 
 
 def test_blockmax_score_compiles(one_chip):
@@ -74,7 +79,8 @@ def test_blockmax_score_compiles(one_chip):
         return blockmax_score_tiles(td, tt, ts, qt, survive, tile_d=TILE_D,
                                     block_size=BLOCK, n_blocks=n_blocks,
                                     interpret=False)
-    _compile(fn, one_chip, ((N_TILES, TILE_CAP), jnp.int32),
+    _compile(fn, "blockmax_score_batched", one_chip,
+             ((N_TILES, TILE_CAP), jnp.int32),
              ((N_TILES, TILE_CAP), jnp.int32),
              ((N_TILES, TILE_CAP), jnp.float32), ((Q, L), jnp.int32),
              ((Q, n_blocks), jnp.bool_))
@@ -83,15 +89,15 @@ def test_blockmax_score_compiles(one_chip):
 def test_qd_feature_gather_compiles(one_chip):
     def fn(docs, scores, cand):
         return qd_feature_gather(docs, scores, cand, interpret=False)
-    _compile(fn, one_chip, ((Q, QCAP), jnp.int32), ((Q, QCAP), jnp.float32),
-             ((Q, C), jnp.int32))
+    _compile(fn, "qd_feature_gather_lanes", one_chip, ((Q, QCAP), jnp.int32),
+             ((Q, QCAP), jnp.float32), ((Q, C), jnp.int32))
 
 
 def test_dense_topk_compiles(one_chip):
     def fn(q_emb, doc_emb):
         return dense_topk(q_emb, doc_emb, C, tile_d=DENSE_TILE,
                           backend="pallas")
-    _compile(fn, one_chip, ((Q, EMB_D), jnp.float32),
+    _compile(fn, "dense_score_tiles", one_chip, ((Q, EMB_D), jnp.float32),
              ((N_DOCS, EMB_D), jnp.float32))
 
 
