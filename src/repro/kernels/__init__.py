@@ -2,7 +2,8 @@
 
 Each package holds ``kernel.py`` (the Pallas program), ``ops.py`` (jit'd
 layout/dispatch wrappers — what the engines import), and ``ref.py`` (a
-pure-jnp oracle the tests hold the kernel to).
+pure-jnp oracle the tests hold the kernel to; ``qd_feature_gather`` is held
+to the numpy Stage-2 feature loop instead).
 
 Serving kernels share one **bucketed postings layout**: at index-build
 time every posting of a shard is tiled into the ``(n_tiles, tile_cap)``
@@ -25,10 +26,13 @@ lane chunk keeps VMEM and compile time independent of ``tile_cap``.
   the per-query impact-level cut ``lstar``; compiled cost is a
   deterministic function of the layout (the structural 200 ms guarantee).
 * ``qd_feature_gather`` — Stage-2 LTR featurization: per-(query,
-  candidate) term-score aggregates {Σ score, max, match count} over the
-  batch's compacted posting lanes, reduced with the same one-hot MXU
-  matmul idiom (grid (query blocks, lane tiles), accumulating output
-  block).
+  candidate) term-score aggregates {Σ score, max, match count}, read
+  straight from the doc-ordered CSR kept as ``(rows, 128)`` tables: a
+  (queries, steps) grid whose scalar-prefetched step tables name the
+  1,024-posting block of each query term's range that a step reads (the
+  ragged-block pattern of paged attention), accumulating into the query's
+  output column.  Its oracle is the numpy ``repro.ltr.ranker.qd_features``
+  loop, which it matches bit for bit.
 * ``dense_topk`` — dense Stage-1: tiled query×doc scores on the MXU, then
   the tiled top-k merge (``repro.kernels.topk.topk_from_tiles``).
 * ``topk`` — the tiled top-k merge over the kernels' accumulator tiles.

@@ -1,29 +1,34 @@
-"""Stage-2 query-document feature gather as a lane-match MXU reduction.
+"""Stage-2 query-document feature gather straight from the doc-ordered CSR.
 
 The LTR re-ranker needs, for every (query, candidate) pair, the per-term
 exact-score aggregates {Σ score, max score, #matching terms} over the
-query's postings.  A scalar per-term binary search is hostile to the TPU's
-vector units, so the batched serving path compacts each query's ragged
-per-term posting ranges into dense ``(Q, P)`` lanes (the same
-``compact_lanes`` layout the DAAT engine uses) and this kernel reduces them
-against the candidate grid:
+query's postings.  Each query term's postings already lie contiguous and
+doc-sorted in the CSR, so the kernel reads them in place: the CSR's
+``docs``/``score`` arrays are kept as ``(rows, 128)`` tables
+(``csr_blocks``), and a *block* is one ``(8, 128)`` tile of them — 1,024
+consecutive postings.
 
-    match = lanes_doc[p] == cand[c]            (P × C in-register compare)
-    bm25  = scoresᵀ (1 × P) @ match (P × C)     — one-hot MXU matmul
-    cnt   = 1ᵀ @ match
-    mx    = column-max of score·match           — VPU reduce
+The grid is (queries, steps).  Step ``s`` of query ``q`` covers one block
+of one term's posting range; a query's steps walk its term slots in order
+and each slot's blocks in order (``ops.csr_steps`` builds the tables).  The
+block row of every step is scalar-prefetched into SMEM and the posting
+``BlockSpec``'s ``index_map`` reads it — the ragged-block pattern of paged
+attention — so the kernel DMAs only the blocks the batch's terms touch.
+Steps past a query's last block repeat its last block index (no new DMA)
+and have an empty posting range, so they skip compute under ``pl.when``.
 
-Postings are unique (term, doc) pairs, so a candidate matches at most one
-lane per query term — ``cnt`` is exactly the number of matching terms and
-``mx`` the max per-term score, i.e. the aggregates ``qd_features`` needs.
+A live step masks the tile to its term's ``[lo, hi)`` postings and matches
+them against the query's candidates, held as a ``(C, 1)`` column::
 
-The grid is (query blocks, lane tiles): each step takes ``QUERY_ROWS``
-queries (a sublane group — the TPU block rule, see
-``repro.kernels.blocks``), and lane tiles stream through VMEM and
-accumulate into the same ``(QUERY_ROWS, C)`` output block (sequential TPU
-grid ⇒ the revisited block is a safe accumulator), so VMEM per step is
-O(P_TILE · C) no matter how long the query's posting lanes are.  The sum
-runs at ``Precision.HIGHEST``, so scores are not rounded to bfloat16.
+    match[c, p] = doc[p] == cand[c]                  (C × 128 per tile row)
+    part[c]     = Σ_p score[p] · match[c, p]         — lane reduce
+    bm25 += part;  mx = max(mx, part);  cnt += any_p match[c, p]
+
+Postings are unique (term, doc) pairs, so a step holds at most one posting
+per candidate: ``part`` is that posting's score or 0 exactly, in any
+reduction order.  Steps run in term order, so ``bm25`` accumulates left to
+right over the query's terms, as the numpy ``qd_features`` loop does, and
+the aggregates match it bit for bit.
 """
 
 from __future__ import annotations
@@ -33,86 +38,84 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.blocks import SUBLANES, pad_axis, round_up
+from repro.kernels.blocks import LANES, SUBLANES
 
-
-QUERY_ROWS = SUBLANES
-
-
-def _qd_gather_kernel(cand_ref, docs_ref, scores_ref, bm25_ref, mx_ref,
-                      cnt_ref):
-    """One (query block, lane-tile) grid step: reduce a lane tile into the
-    block's (QUERY_ROWS, C) aggregates, one query row at a time."""
-    pt = pl.program_id(1)
-    for i in range(QUERY_ROWS):
-        d = docs_ref[i, :]                      # (PT,) int32, -1 = dead lane
-        s = scores_ref[i, :]                    # (PT,) float32
-        c = cand_ref[i, :]                      # (C,) int32, -1 = pad
-        match = ((d[:, None] == c[None, :])
-                 & (d[:, None] >= 0) & (c[None, :] >= 0))   # (PT, C)
-        mf = match.astype(jnp.float32)
-        part_sum = jax.lax.dot_general(
-            s[None, :], mf, (((1,), (0,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)[0]
-        part_cnt = jax.lax.dot_general(
-            jnp.ones((1, d.shape[0]), jnp.bfloat16),
-            match.astype(jnp.bfloat16), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)[0]
-        part_mx = jnp.max(jnp.where(match, s[:, None], 0.0), axis=0)
-
-        @pl.when(pt == 0)
-        def _init(i=i, part_sum=part_sum, part_mx=part_mx,
-                  part_cnt=part_cnt):
-            bm25_ref[i, :] = part_sum
-            mx_ref[i, :] = part_mx
-            cnt_ref[i, :] = part_cnt.astype(jnp.int32)
-
-        @pl.when(pt > 0)
-        def _accumulate(i=i, part_sum=part_sum, part_mx=part_mx,
-                        part_cnt=part_cnt):
-            bm25_ref[i, :] += part_sum
-            mx_ref[i, :] = jnp.maximum(mx_ref[i, :], part_mx)
-            cnt_ref[i, :] += part_cnt.astype(jnp.int32)
+BLOCK = SUBLANES * LANES        # postings per step: one (8, 128) tile
 
 
-@functools.partial(jax.jit, static_argnames=("p_tile", "interpret"))
-def qd_feature_gather_lanes(lane_docs: jnp.ndarray, lane_scores: jnp.ndarray,
-                            cand: jnp.ndarray, *, p_tile: int = 512,
-                            interpret: bool):
-    """Per-(query, candidate) term-score aggregates over compacted lanes.
+def _qd_gather_kernel(blk_ref, lo_ref, hi_ref, cand_ref, docs_ref,
+                      score_ref, bm25_ref, mx_ref, cnt_ref, *, n_steps: int):
+    """One (query, step) grid step: fold one posting block of one query
+    term into the query's (C, 1) aggregates."""
+    s = pl.program_id(1)
+    i = pl.program_id(0) * n_steps + s
+    lo, hi = lo_ref[i], hi_ref[i]          # block-local posting bounds
+
+    @pl.when(s == 0)
+    def _init():
+        bm25_ref[...] = jnp.zeros(bm25_ref.shape, bm25_ref.dtype)
+        mx_ref[...] = jnp.zeros(mx_ref.shape, mx_ref.dtype)
+        cnt_ref[...] = jnp.zeros(cnt_ref.shape, cnt_ref.dtype)
+
+    @pl.when(lo < hi)
+    def _fold():
+        k = (jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 0) * LANES
+             + jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 1))
+        docs = jnp.where((k >= lo) & (k < hi), docs_ref[...], -1)
+        score = score_ref[...]
+        cand = cand_ref[...]                           # (C, 1), -1 = pad
+        cand = jnp.where(cand >= 0, cand, -2)          # never a posting
+        hit_sc = jnp.zeros((cand.shape[0], LANES), jnp.float32)
+        hit = jnp.zeros((cand.shape[0], LANES), jnp.bool_)
+        for r in range(SUBLANES):
+            m = cand == docs[r:r + 1, :]               # (C, 128)
+            hit_sc = jnp.where(m, score[r:r + 1, :], hit_sc)
+            hit = hit | m
+        part = jnp.sum(hit_sc, axis=1, keepdims=True)  # one live lane at most
+        bm25_ref[...] += part
+        mx_ref[...] = jnp.maximum(mx_ref[...], part)
+        cnt_ref[...] += jnp.max(hit.astype(jnp.int32), axis=1, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def qd_feature_gather_csr(blk_docs: jnp.ndarray, blk_score: jnp.ndarray,
+                          blk: jnp.ndarray, lo: jnp.ndarray, hi: jnp.ndarray,
+                          cand: jnp.ndarray, *, interpret: bool):
+    """Per-(query, candidate) term-score aggregates read from the CSR.
 
     Args:
-      lane_docs: (Q, P) int32 doc ids of the query's postings, -1 dead.
-      lane_scores: (Q, P) float32 exact scores, 0 in dead lanes.
-      cand: (Q, C) int32 candidate doc ids, -1 padding.
-      p_tile: posting lanes per grid step (P must be a multiple).
+      blk_docs: (rows, 128) int32 doc ids of the CSR, -1 past its end
+        (``csr_blocks``); read in place, block by block.
+      blk_score: (rows, 128) float32 exact scores, 0 past the end.
+      blk: (Q, S) int32 block of each step (``csr_steps``).
+      lo/hi: (Q, S) int32 block-local ``[lo, hi)`` posting bounds of each
+        step; ``lo == hi`` marks a dead step.
+      cand: (Q, C) int32 candidate doc ids, -1 padding; C a multiple of 8.
     Returns:
       (bm25, mx, cnt): (Q, C) float32/float32/int32 — Σ score, max score and
       match count per candidate.
     """
-    q, p = lane_docs.shape
+    q, n_steps = blk.shape
     c = cand.shape[1]
-    assert p % p_tile == 0, (p, p_tile)
-    qp = round_up(q, QUERY_ROWS)
-    # padded query rows have no live lanes and no candidates
-    lane_docs = pad_axis(lane_docs, 0, qp, -1)
-    lane_scores = pad_axis(lane_scores, 0, qp, 0.0)
-    cand = pad_axis(cand, 0, qp, -1)
-    rows = pl.BlockSpec((QUERY_ROWS, c), lambda qi, t: (qi, 0))
-    lanes = pl.BlockSpec((QUERY_ROWS, p_tile), lambda qi, t: (qi, t))
+    col = pl.BlockSpec((c, 1), lambda qi, s, *_: (qi, 0))
+    tile = pl.BlockSpec((SUBLANES, LANES),
+                        lambda qi, s, b, *_: (b[qi * n_steps + s], 0))
     bm25, mx, cnt = pl.pallas_call(
-        _qd_gather_kernel,
-        grid=(qp // QUERY_ROWS, p // p_tile),
-        in_specs=[rows, lanes, lanes],
-        out_specs=[rows, rows, rows],
+        functools.partial(_qd_gather_kernel, n_steps=n_steps),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(q, n_steps),
+            in_specs=[col, tile, tile],
+            out_specs=[col, col, col]),
         out_shape=[
-            jax.ShapeDtypeStruct((qp, c), jnp.float32),
-            jax.ShapeDtypeStruct((qp, c), jnp.float32),
-            jax.ShapeDtypeStruct((qp, c), jnp.int32),
+            jax.ShapeDtypeStruct((q * c, 1), jnp.float32),
+            jax.ShapeDtypeStruct((q * c, 1), jnp.float32),
+            jax.ShapeDtypeStruct((q * c, 1), jnp.int32),
         ],
         interpret=interpret,
         name="qd_feature_gather_lanes",
-    )(cand, lane_docs, lane_scores)
-    return bm25[:q], mx[:q], cnt[:q]
+    )(blk.reshape(-1), lo.reshape(-1), hi.reshape(-1),
+      cand.reshape(q * c, 1), blk_docs, blk_score)
+    return bm25.reshape(q, c), mx.reshape(q, c), cnt.reshape(q, c)

@@ -59,8 +59,7 @@ def rerank_loop(index, corpus, ql, rows, candidate_lists, k_per_query,
 def rerank_batched(arrs: Stage2Arrays, ltr: LTRModel, terms, mask, topics,
                    cand, k_per_query, *, t_final: int = 10, n_iter: int,
                    backend: str = "jnp", qcap: int | None = None,
-                   lane_need: int | None = None,
-                   p_tile: int = 512) -> CascadeResult:
+                   lane_need: int | None = None) -> CascadeResult:
     """Batched Stage-2: re-rank every query's candidate grid in one array
     program.
 
@@ -80,8 +79,9 @@ def rerank_batched(arrs: Stage2Arrays, ltr: LTRModel, terms, mask, topics,
     """
     q, c = np.shape(cand)
     if backend != "jnp":
-        # compact_lanes silently drops lanes past qcap — refuse rather than
-        # return wrong features (size qcap with query_lane_budget)
+        # the kernel's step budget covers qcap postings a query and drops
+        # blocks past it — refuse rather than return wrong features (size
+        # qcap with query_lane_budget)
         if lane_need is None:
             off = np.asarray(arrs.offsets)
             t_np = np.asarray(terms)
@@ -97,8 +97,7 @@ def rerank_batched(arrs: Stage2Arrays, ltr: LTRModel, terms, mask, topics,
     cand_j = jnp.asarray(cand, jnp.int32)
     feats = qd_features_batched(arrs, terms, mask,
                                 jnp.asarray(topics, jnp.int32), cand_j,
-                                n_iter=n_iter, backend=backend, qcap=qcap,
-                                p_tile=p_tile)
+                                n_iter=n_iter, backend=backend, qcap=qcap)
     sc = gbrt.predict(ltr.model, feats.reshape(q * c, -1)).reshape(q, c)
     valid = (cand_j >= 0) & (jnp.arange(c, dtype=jnp.int32)[None, :]
                              < jnp.asarray(k_per_query, jnp.int32)[:, None])

@@ -14,12 +14,13 @@ Two implementations of the feature extractor coexist:
 * ``qd_features_batched`` — the serving path: one array program over the
   whole ``(Q, C)`` candidate grid.  The per-term exact scores come from a
   branch-free CSR binary search over *all* query terms at once (``"jnp"``
-  backend — the portable CPU fast path, bit-identical to the loop) or from
-  the ``qd_feature_gather`` Pallas kernel over compacted posting lanes
-  (``"pallas"`` / ``"interpret"`` backends — the TPU path, same backend
-  switch as the Stage-1 engines).  Transcendentals are precomputed
-  host-side into gather tables (``Stage2Arrays.log1p_doclen``) so the
-  batched features match the numpy loop bit-for-bit on the jnp backend.
+  backend — the portable CPU fast path) or from the ``qd_feature_gather``
+  Pallas kernel, which reads each query term's posting range block by
+  block straight from the CSR (``"pallas"`` / ``"interpret"`` backends —
+  the TPU path, same backend switch as the Stage-1 engines).
+  Transcendentals are precomputed host-side into gather tables
+  (``Stage2Arrays.log1p_doclen``), so on either backend the batched
+  features match the numpy loop bit for bit.
 """
 
 from __future__ import annotations
@@ -33,8 +34,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import gbrt
-from repro.isn.backend import compact_lanes
-from repro.kernels.qd_feature_gather.ops import qd_feature_gather
+from repro.kernels.qd_feature_gather.ops import (csr_blocks,
+                                                 qd_feature_gather,
+                                                 step_budget)
 
 N_LTR_FEATURES = 8
 
@@ -80,6 +82,8 @@ class Stage2Arrays(NamedTuple):
     offsets: jnp.ndarray       # (V+1,) int32 — doc-ordered CSR
     docs: jnp.ndarray          # (P,) int32, doc-sorted within each term
     score: jnp.ndarray         # (P,) float32 exact BM25
+    blk_docs: jnp.ndarray      # (rows, 128) int32 — docs in kernel blocks
+    blk_score: jnp.ndarray     # (rows, 128) float32 — score, same blocks
     doclen: jnp.ndarray        # (N,) float32
     log1p_doclen: jnp.ndarray  # (N,) float32 — np.log1p table (exactness)
     doc_topics: jnp.ndarray    # (N, K) float32
@@ -89,10 +93,13 @@ class Stage2Arrays(NamedTuple):
 def stage2_arrays(index, corpus) -> Stage2Arrays:
     """Materialize the Stage-2 gather tables from the index + corpus."""
     dl32 = index.doclen.astype(np.float32)
+    blk_docs, blk_score = csr_blocks(index.docs, index.bm25_score)
     return Stage2Arrays(
         offsets=jnp.asarray(index.offsets, jnp.int32),
         docs=jnp.asarray(index.docs, jnp.int32),
         score=jnp.asarray(index.bm25_score, jnp.float32),
+        blk_docs=blk_docs,
+        blk_score=blk_score,
         doclen=jnp.asarray(dl32),
         log1p_doclen=jnp.asarray(np.log1p(dl32)),
         doc_topics=jnp.asarray(corpus.doc_topics, jnp.float32),
@@ -144,29 +151,12 @@ def _csr_term_stats(offsets, docs, score, terms, tmask, cand, cmask,
     return bm25, mx, nm
 
 
-def _lane_term_stats(offsets, docs, score, terms, tmask, cand, qcap: int,
-                     p_tile: int, interpret: bool):
-    """Kernel-backed aggregates: compact the batch's ragged per-term posting
-    ranges into (Q, qcap) dense lanes, then one ``qd_feature_gather``
-    launch over the candidate grid."""
-    base = offsets[terms]                              # (Q, L)
-    dfs = (offsets[terms + 1] - base) * tmask.astype(jnp.int32)
-    pos, live = compact_lanes(base, dfs.astype(jnp.int32), qcap)
-    pos = jnp.minimum(pos, docs.shape[0] - 1)
-    lane_docs = jnp.where(live, docs[pos], -1)
-    lane_scores = jnp.where(live, score[pos], 0.0)
-    bm25, mx, cnt = qd_feature_gather(lane_docs, lane_scores, cand,
-                                      p_tile=p_tile, interpret=interpret)
-    return bm25, mx, cnt.astype(jnp.float32)
-
-
-@functools.partial(jax.jit, static_argnames=("n_iter", "backend", "qcap",
-                                             "p_tile"))
+@functools.partial(jax.jit, static_argnames=("n_iter", "backend", "qcap"))
 def qd_features_batched(arrs: Stage2Arrays, terms: jnp.ndarray,
                         mask: jnp.ndarray, topics: jnp.ndarray,
                         cand: jnp.ndarray, *, n_iter: int,
-                        backend: str = "jnp", qcap: int | None = None,
-                        p_tile: int = 512) -> jnp.ndarray:
+                        backend: str = "jnp",
+                        qcap: int | None = None) -> jnp.ndarray:
     """LTR features for the whole (Q, C) candidate grid in one call.
 
     Args:
@@ -176,9 +166,12 @@ def qd_features_batched(arrs: Stage2Arrays, terms: jnp.ndarray,
       cand: (Q, C) candidate doc ids, -1 padding (padded rows yield garbage
         features — mask downstream, as ``rerank_batched`` does).
       n_iter: static bisection depth (``csr_search_iters(max_df)``).
-      backend: "jnp" (CSR binary search — bit-identical to the numpy loop)
-        or "interpret"/"pallas" (``qd_feature_gather`` kernel over compacted
-        lanes; ``qcap`` must then bound the batch's per-query postings).
+      backend: "jnp" (CSR binary search) or "interpret"/"pallas"
+        (``qd_feature_gather`` kernel over the CSR's posting blocks); both
+        are bit-identical to the numpy loop.
+      qcap: kernel backends only — a static bound on the batch's per-query
+        posting total (``query_lane_budget``); it fixes the kernel's
+        ``step_budget``, so the program compiles per (width, ``qcap``).
     Returns:
       (Q, C, 8) float32 feature grid.
     """
@@ -191,9 +184,13 @@ def qd_features_batched(arrs: Stage2Arrays, terms: jnp.ndarray,
     else:
         if qcap is None:
             raise ValueError("kernel backends need a static qcap lane budget")
-        bm25, mx, nm = _lane_term_stats(arrs.offsets, arrs.docs, arrs.score,
-                                        terms, tmask, cand, qcap, p_tile,
-                                        backend == "interpret")
+        lo = arrs.offsets[terms]
+        hi = jnp.where(tmask, arrs.offsets[terms + 1], lo)
+        bm25, mx, cnt = qd_feature_gather(
+            arrs.blk_docs, arrs.blk_score, lo, hi, cand,
+            n_steps=step_budget(qcap, terms.shape[1]),
+            interpret=backend == "interpret")
+        nm = cnt.astype(jnp.float32)
     dl = arrs.doclen[c_safe]                           # (Q, C)
     n_terms = jnp.sum(tmask.astype(jnp.float32), axis=1)
     feats = jnp.stack([

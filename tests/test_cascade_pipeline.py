@@ -99,30 +99,150 @@ def test_rerank_batched_empty_candidates(stage2, ltr_model):
 # the qd_feature_gather kernel (interpret mode = the kernel program on CPU)
 # ---------------------------------------------------------------------------
 
-def test_qd_feature_gather_kernel_matches_ref():
-    from repro.kernels.qd_feature_gather.ops import (qd_feature_gather,
-                                                     qd_feature_gather_ref)
-    rng = np.random.RandomState(0)
-    q, p, c = 5, 700, 37
-    lane_docs = rng.randint(-1, 60, (q, p)).astype(np.int32)
-    lane_scores = np.where(lane_docs >= 0,
-                           rng.random_sample((q, p)) * 6, 0).astype(np.float32)
-    cand = rng.randint(-1, 60, (q, c)).astype(np.int32)
-    bm, mx, cnt = qd_feature_gather(jnp.asarray(lane_docs),
-                                    jnp.asarray(lane_scores),
-                                    jnp.asarray(cand), p_tile=256,
-                                    interpret=True)
-    bm_r, mx_r, cnt_r = qd_feature_gather_ref(jnp.asarray(lane_docs),
-                                              jnp.asarray(lane_scores),
-                                              jnp.asarray(cand))
-    np.testing.assert_allclose(np.asarray(bm), np.asarray(bm_r), atol=1e-4)
-    np.testing.assert_array_equal(np.asarray(mx), np.asarray(mx_r))
-    np.testing.assert_array_equal(np.asarray(cnt), np.asarray(cnt_r))
+def _block_csr():
+    """A hand-made doc-ordered CSR whose term ranges sit on and across the
+    kernel's 1,024-posting block edges: (index, corpus) as ``qd_features``
+    and ``stage2_arrays`` read them."""
+    from types import SimpleNamespace
+    rng = np.random.RandomState(2)
+    n_docs = 4096
+    # t0 absent, t1 df 1, t2 ends on the 1,024 edge, t3 crosses 2,048,
+    # t4 and t5 share t3's last block, t6 spans three blocks, t7 df 3
+    offsets = np.array([0, 0, 1, 1024, 2100, 2110, 2130, 5000, 5003])
+    docs = np.concatenate([
+        np.sort(rng.choice(n_docs, hi - lo, replace=False))
+        for lo, hi in zip(offsets[:-1], offsets[1:])]).astype(np.int64)
+    index = SimpleNamespace(
+        offsets=offsets, docs=docs,
+        bm25_score=(rng.random_sample(len(docs)) * 4 + 0.1)
+        .astype(np.float32),
+        doclen=rng.randint(8, 400, n_docs).astype(np.int32),
+        df=np.diff(offsets).astype(np.int32))
+    corpus = SimpleNamespace(
+        doc_topics=rng.dirichlet(np.full(4, 0.3), n_docs).astype(np.float32))
+    return index, corpus
+
+
+# (Q, per-query term slots, whether each query's candidates carry -1 pads);
+# each slot list is padded to 4 slots with masked term 0
+_CSR_CASES = {
+    "absent_term": (4, [[0, 1], [0], [0, 7, 0], [2, 0]], False),
+    "df_one": (3, [[1], [1, 7], [7, 1, 2]], False),
+    "range_ends_on_block_edge": (2, [[2], [2, 3]], False),
+    "range_crosses_block_edge": (3, [[3], [1, 3], [3, 6]], False),
+    "terms_share_block": (3, [[4, 5], [3, 4, 5], [5, 4, 3, 6]], False),
+    "all_slots_masked": (3, [[], [4], []], False),
+    "candidate_padding": (4, [[3, 4], [6], [2, 5, 7], [1]], True),
+    "q_not_multiple_of_8": (11, [[t % 8, (3 * t + 1) % 8] for t in range(11)],
+                            False),
+}
+
+
+@pytest.mark.parametrize("case", list(_CSR_CASES))
+def test_qd_feature_gather_kernel_matches_loop(case):
+    """The CSR-block kernel (interpret mode = the kernel program on CPU)
+    gives the numpy ``qd_features`` loop's features bit for bit."""
+    from repro.isn.backend import query_lane_budget
+    index, corpus = _block_csr()
+    q, slots, pad = _CSR_CASES[case]
+    rng = np.random.RandomState(len(case))
+    terms = np.zeros((q, 4), np.int32)
+    mask = np.zeros((q, 4), np.float32)
+    for i, row in enumerate(slots):
+        terms[i, :len(row)] = row
+        mask[i, :len(row)] = 1.0
+    c = 20                                   # not a sublane multiple
+    cand = rng.randint(0, 4096, (q, c))
+    for i, row in enumerate(slots):          # plant hits in every range
+        for j, t in enumerate(row):
+            lo, hi = index.offsets[t], index.offsets[t + 1]
+            if hi > lo:
+                cand[i, 2 * j] = index.docs[rng.randint(lo, hi)]
+                cand[i, 2 * j + 1] = index.docs[hi - 1]
+    if pad:
+        cand[:, c - 5:] = -1
+        cand[0, :] = -1
+    topics = rng.randint(0, 4, q).astype(np.int32)
+    arrs = ranker.stage2_arrays(index, corpus)
+    feats = np.asarray(ranker.qd_features_batched(
+        arrs, jnp.asarray(terms), jnp.asarray(mask), jnp.asarray(topics),
+        jnp.asarray(cand, jnp.int32),
+        n_iter=ranker.csr_search_iters(int(index.df.max())),
+        backend="interpret",
+        qcap=query_lane_budget(index.df, terms, mask)))
+    hits = 0
+    for i in range(q):
+        sel = cand[i] >= 0
+        if not sel.any():
+            continue
+        ref = ranker.qd_features(index, corpus, terms[i], mask[i],
+                                 topics[i], cand[i][sel])
+        np.testing.assert_array_equal(feats[i][sel], ref)
+        hits += int((ref[:, 2] > 0).sum())
+    assert hits > 0 or case == "all_slots_masked"
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_step_budget_covers_lane_budget(seed):
+    """Every batch that ``query_lane_budget`` sizes needs at most
+    ``step_budget(qcap, L)`` steps, with ranges placed at block edges
+    (where a range spans the most blocks); the live steps read each
+    posting once, in term order, and dead steps re-read the last block."""
+    import jax
+
+    from repro.isn.backend import query_lane_budget
+    from repro.kernels.qd_feature_gather.kernel import BLOCK
+    from repro.kernels.qd_feature_gather.ops import csr_steps, step_budget
+    steps = jax.jit(csr_steps, static_argnums=2)
+    rng = np.random.RandomState(seed)
+    q = 8
+    for _ in range(150):
+        n_slots = int(rng.choice([1, 3, 8]))
+        df = np.where(rng.random_sample((q, n_slots)) < 0.2, 0,
+                      rng.choice([1, 2, 1023, 1024, 1025, 3000],
+                                 (q, n_slots)))
+        mask = rng.random_sample((q, n_slots)) < 0.8
+        lo = (rng.randint(1, 40, (q, n_slots)) * BLOCK
+              + rng.choice([-1, 0, 1, -1023], (q, n_slots)))
+        hi = np.where(mask, lo + df, lo)
+        # query_lane_budget reads df through term ids: one id per slot
+        qcap = query_lane_budget((hi - lo).reshape(-1),
+                                 np.arange(q * n_slots).reshape(q, n_slots),
+                                 np.ones((q, n_slots)))
+        blk, s_lo, s_hi = (np.asarray(a) for a in steps(
+            jnp.asarray(lo), jnp.asarray(hi), 64))
+        live = s_lo < s_hi
+        assert live.sum(axis=1).max() <= step_budget(qcap, n_slots) <= 64
+        for i in range(q):
+            n = int(live[i].sum())
+            assert live[i, :n].all()
+            got = np.concatenate([np.arange(b * BLOCK + a, b * BLOCK + z)
+                                  for b, a, z in zip(blk[i, :n], s_lo[i, :n],
+                                                     s_hi[i, :n])] + [[]])
+            want = np.concatenate([np.arange(a, z) for a, z
+                                   in zip(lo[i], hi[i])] + [[]])
+            np.testing.assert_array_equal(got, want)
+            if n:
+                assert np.all(blk[i, n:] == blk[i, n - 1])
+
+
+def test_rerank_batched_refuses_short_lane_budget(stage2, ltr_model):
+    """A kernel-backend call whose qcap is under the batch's per-query
+    posting total is refused, not served with dropped postings."""
+    from repro.isn.backend import query_lane_budget
+    corpus, index, ql, arrs, n_iter, cand = stage2
+    need = int((index.df[ql.terms[:8]] * (ql.mask[:8] > 0)).sum(axis=1).max())
+    assert query_lane_budget(index.df, ql.terms[:8], ql.mask[:8]) >= need
+    with pytest.raises(ValueError, match="does not cover"):
+        cascade.rerank_batched(arrs, ltr_model, ql.terms[:8], ql.mask[:8],
+                               ql.topic[:8], cand[:8], np.full(8, 10),
+                               n_iter=n_iter, backend="interpret",
+                               qcap=need - 1)
 
 
 def test_qd_features_interpret_backend_matches_jnp(stage2):
     """The kernel-backed featurizer agrees with the CSR binary-search path
-    (float sums to tolerance; counts and gathers exactly)."""
+    bit for bit, sums included."""
     corpus, index, ql, arrs, n_iter, cand = stage2
     q = 8
     terms = jnp.asarray(ql.terms[:q])
@@ -136,10 +256,7 @@ def test_qd_features_interpret_backend_matches_jnp(stage2):
                                               backend="interpret", qcap=qcap))
     b = np.asarray(ranker.qd_features_batched(arrs, terms, mask, topics, cd,
                                               n_iter=n_iter, backend="jnp"))
-    np.testing.assert_allclose(a, b, atol=1e-4)
-    # non-sum features are exact across backends
-    for col in (0, 2, 3, 5, 6, 7):
-        np.testing.assert_array_equal(a[..., col], b[..., col])
+    np.testing.assert_array_equal(a, b)
 
 
 # ---------------------------------------------------------------------------

@@ -4,10 +4,12 @@ Interpret-mode tests cannot see what the TPU compiler refuses (block
 shapes off the (8, 128) tiling, VMEM overflow, primitives Mosaic cannot
 lower).  These tests compile each serving kernel with ``interpret=False``
 against a described ``v5e:2x2`` topology — no chip attached — at the
-widths ``chip_smoke.py`` serves: a 2^20-doc shard's bucketed mirror,
-Stage-2 lane budget and candidate depth, the dense embedding width, and a
-64-query batch.  Each compile takes seconds; one that takes minutes means
-a kernel's lane axis is no longer split into fixed-width chunks.
+widths ``chip_smoke.py`` serves: a 2^20-doc shard's bucketed mirror, the
+dense embedding width, and a 64-query batch; Stage-2 at the benchmark
+cell's shapes (a 32-query batch at its largest lane budget over a
+ClueWeb09B shard's 10.4M postings).  Each compile takes seconds; one that
+takes minutes means a kernel's lane axis is no longer split into
+fixed-width chunks.
 """
 
 import os
@@ -22,15 +24,20 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.blockmax_score.ops import blockmax_score_tiles
 from repro.kernels.dense_topk.ops import dense_topk
 from repro.kernels.impact_accumulate.ops import impact_accumulate_tiles
-from repro.kernels.qd_feature_gather.ops import qd_feature_gather
+from repro.kernels.qd_feature_gather.ops import qd_feature_gather, step_budget
+from repro.ltr.ranker import Stage2Arrays, qd_features_batched
 
 Q, L = 64, 8                      # served batch, padded query width
 N_DOCS, TILE_D, BLOCK = 1 << 20, 128, 64
 N_TILES = N_DOCS // TILE_D
 TILE_CAP = 16384                  # lane capacity of that shard's tiles
-QCAP = 1 << 20                    # Stage-2 posting-lane budget (bound)
 C = 128                           # Stage-2 candidates (k_serve)
 EMB_D, DENSE_TILE = 32, 512       # dense embedding width, doc tile
+# Stage-2 in the benchmark cell: batch, largest lane budget, the shard's
+# postings, terms and docs
+S2_Q, S2_QCAP = 32, 15360
+S2_POSTINGS, S2_VOCAB, S2_DOCS = 10_368_464, 2_328_791, 32768
+S2_ROWS = -(-(S2_POSTINGS + 1) // 1024) * 8   # (rows, 128) posting tables
 
 
 @pytest.fixture(scope="module")
@@ -87,10 +94,40 @@ def test_blockmax_score_compiles(one_chip):
 
 
 def test_qd_feature_gather_compiles(one_chip):
-    def fn(docs, scores, cand):
-        return qd_feature_gather(docs, scores, cand, interpret=False)
-    _compile(fn, "qd_feature_gather_lanes", one_chip, ((Q, QCAP), jnp.int32),
-             ((Q, QCAP), jnp.float32), ((Q, C), jnp.int32))
+    def fn(docs, scores, lo, hi, cand):
+        return qd_feature_gather(docs, scores, lo, hi, cand,
+                                 n_steps=step_budget(S2_QCAP, L),
+                                 interpret=False)
+    _compile(fn, "qd_feature_gather_lanes", one_chip,
+             ((S2_ROWS, 128), jnp.int32), ((S2_ROWS, 128), jnp.float32),
+             ((S2_Q, L), jnp.int32), ((S2_Q, L), jnp.int32),
+             ((S2_Q, C), jnp.int32))
+
+
+def test_stage2_program_reads_postings_in_place(one_chip):
+    """The kernel-backend Stage-2 featurizer reads postings only through
+    the kernel: no lane-compaction loop and no gather of (Q, qcap) lanes
+    from the CSR's flat ``docs``/``score``."""
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    p, n = S2_POSTINGS, S2_DOCS
+    arrs = Stage2Arrays(
+        offsets=sds((S2_VOCAB + 1,), jnp.int32), docs=sds((p,), jnp.int32),
+        score=sds((p,), jnp.float32),
+        blk_docs=sds((S2_ROWS, 128), jnp.int32),
+        blk_score=sds((S2_ROWS, 128), jnp.float32),
+        doclen=sds((n,), jnp.float32), log1p_doclen=sds((n,), jnp.float32),
+        doc_topics=sds((n, 32), jnp.float32),
+        doc_topics_max=sds((n,), jnp.float32))
+    text = qd_features_batched.lower(
+        arrs, sds((S2_Q, L), jnp.int32), sds((S2_Q, L), jnp.float32),
+        sds((S2_Q,), jnp.int32), sds((S2_Q, C), jnp.int32), n_iter=20,
+        backend="pallas", qcap=S2_QCAP).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(r" while\(", text)
+    gathers = re.findall(r"= \w+\[([\d,]*)\][^\n]* gather\(", text)
+    assert gathers and all(S2_QCAP not in map(int, g.split(","))
+                           for g in gathers), gathers
 
 
 def test_dense_topk_compiles(one_chip):
