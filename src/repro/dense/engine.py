@@ -6,8 +6,8 @@ to slot into the existing deployment shape unchanged:
 * the embedding matrix is partitioned by the **same contiguous doc ranges**
   as the inverted index (``shard_ranges``), per-shard results carry global
   doc ids, and the multi-shard merge is the existing ``merge_shard_topk``
-  — ascending doc-range order + stable ``top_k`` preserve the lower-global-
-  doc-id tie-break, and ``drop`` masks (fault loss / partial coverage)
+  — its (score, global doc id) sort keeps the lower-global-doc-id
+  tie-break, and ``drop`` masks (fault loss / partial coverage)
   degrade a dense query exactly like a lexical one;
 * per-shard cost is **shape-static** — every query scores every doc tile,
   so ``CostModel.dense_time(n_tiles)`` is exact from the spec alone, which

@@ -21,8 +21,9 @@ never fall back to scatter-adds.
 Tiled top-k
 -----------
 ``topk_from_tiles`` (re-exported from ``repro.kernels.topk``) reduces the
-(Q, n_tiles, tile_d) accumulator tiles the kernels emit to an exact top-k
-with the lower-doc-id tie rule.
+(Q, n_tiles, tile_d) accumulator tiles the kernels emit to an exact top-k;
+integer accumulators rank on packed (score, doc) keys, so their ties go to
+the lower doc id.
 """
 
 from __future__ import annotations
@@ -112,12 +113,12 @@ def merge_shard_topk(scores: list, ids: list, k: int, drop=None):
     """Scatter-gather merge of per-shard top-k candidate lists.
 
     ``scores[s]`` / ``ids[s]`` are the (Q, k_s) ranked candidates of shard
-    ``s`` with ids already global to the collection.  Shards must be passed
-    in ascending doc-range order: ``lax.top_k`` keeps the earliest position
-    on score ties, and within a shard candidates are already (score desc,
-    doc id asc), so the merged tie-break is *lower global doc id first* —
-    exactly the tie-break of a single-shard top-k over the dense
-    accumulator.  Returns (ids, scores) of shape (Q, k).
+    ``s`` with ids already global to the collection.  The merge sorts the
+    small (Q, Σ k_s) grid on two keys, score desc then global doc id asc,
+    so equal scores go to the *lower global doc id* — the tie rule of a
+    single-shard top-k over the dense accumulator — for either score dtype
+    and whatever order ``lax.top_k`` would give equal keys.  Returns (ids,
+    scores) of shape (Q, k).
 
     ``drop`` (optional, (n_shards, Q) bool) masks out shards whose response
     was lost for a query (fault injection / partial coverage): a dropped
@@ -139,23 +140,19 @@ def merge_shard_topk(scores: list, ids: list, k: int, drop=None):
                 else jnp.iinfo(sc.dtype).min)
         sc = jnp.where(dead, fill, sc)
         di = jnp.where(dead, -1, di)
-    top_sc, pos = jax.lax.top_k(sc, min(k, sc.shape[1]))
-    top_id = jnp.take_along_axis(di, pos, axis=1)
-    return top_id, top_sc
+    # ascending (score, -id), reversed, is (score desc, id asc)
+    sc, neg = jax.lax.sort((sc, -di), dimension=1, num_keys=2)
+    kk = min(k, sc.shape[1])
+    return -neg[:, ::-1][:, :kk], sc[:, ::-1][:, :kk]
 
 
-def tiled_topk(acc: jnp.ndarray, k: int, tile_d: int = 128):
-    """Tiled top-k over a dense (Q, n_docs) accumulator.
-
-    Pads the ragged tail tile with the dtype minimum so padding can never
-    enter the top-k (the accumulators are non-negative).
-    """
+def tiled_topk(acc: jnp.ndarray, k: int, tile_d: int = 128,
+               max_score: int | None = None):
+    """Tiled top-k over a dense (Q, n_docs) accumulator (``max_score`` as
+    ``topk_from_tiles`` takes it, for an integer one).  The ragged tail
+    tile's padding lies past ``n_docs``, so it never enters the top-k."""
     q, n = acc.shape
     n_tiles = -(-n // tile_d)
-    pad = n_tiles * tile_d - n
-    if pad:
-        fill = (jnp.finfo(acc.dtype).min
-                if jnp.issubdtype(acc.dtype, jnp.floating)
-                else jnp.iinfo(acc.dtype).min)
-        acc = jnp.pad(acc, ((0, 0), (0, pad)), constant_values=fill)
-    return topk_from_tiles(acc.reshape(q, n_tiles, tile_d), k)
+    acc = jnp.pad(acc, ((0, 0), (0, n_tiles * tile_d - n)))
+    return topk_from_tiles(acc.reshape(q, n_tiles, tile_d), k, n_docs=n,
+                           max_score=max_score)

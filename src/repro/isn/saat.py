@@ -26,9 +26,11 @@ Queries are served as a batch through a backend switch
   prefixes plus one fused scatter; identical results on any host.
 
 Top-k is the tiled hierarchical merge from ``repro.isn.backend`` rather
-than a full-collection ``lax.top_k``.  ``saat_serve_laxmap`` preserves the
-original one-query-at-a-time pipeline as parity oracle and benchmark
-baseline.  Accumulation is integer, so all backends agree bit-exactly.
+than a full-collection ``lax.top_k``, ranked on packed (score, doc) keys so
+equal scores go to the lower doc id on every backend.
+``saat_serve_laxmap`` preserves the original one-query-at-a-time pipeline
+as parity oracle and benchmark baseline.  Accumulation is integer, so all
+backends agree bit-exactly.
 """
 
 from __future__ import annotations
@@ -115,18 +117,19 @@ def _accumulate_batched(shard: IndexShard, terms, prefix, n_docs: int,
 def _saat_batched(shard: IndexShard, terms, mask, rho, *, n_docs: int,
                   k: int, cap: int, tile_d: int, backend: str):
     prefix, work, lstar = _level_cut_batched(shard, terms, mask, rho)
+    # every accumulator entry is a sum of at most L impacts
+    max_score = terms.shape[1] * (shard.level_cum.shape[1] - 1)
     if backend == "jnp":
         prefix = jnp.minimum(prefix, cap)
         acc = _accumulate_batched(shard, terms, prefix, n_docs, cap)
-        # top-k in f32: exact for impact sums (< 2^24) and ~30x faster than
-        # XLA CPU's int32 top-k; ties keep identical float representations
-        sc, ids = jax.lax.top_k(acc.astype(jnp.float32), k)
+        sc, ids = topk_from_tiles(acc[:, None, :], k, max_score=max_score)
     else:
         qterms = jnp.where(mask > 0, terms, -1).astype(jnp.int32)
         acc_t = impact_accumulate_tiles(
             shard.tile_docs, shard.tile_terms, shard.tile_imps, qterms,
             lstar, tile_d=tile_d, interpret=backend == "interpret")
-        sc, ids = topk_from_tiles(acc_t, k, n_docs=n_docs)
+        sc, ids = topk_from_tiles(acc_t, k, n_docs=n_docs,
+                                  max_score=max_score)
     return ids.astype(jnp.int32), sc.astype(jnp.float32), work
 
 

@@ -138,8 +138,11 @@ class StageZeroScheduler:
     def __init__(self, cfg: SchedulerConfig, cost: CostModel | None = None):
         self.cfg = cfg
         self.cost = cost or CostModel.paper_scale()
+        # routed rows per engine and hedges; the serve path adds the inert
+        # pad rows each engine ran and the JASS postings it scored
         self.stats = {"jass": 0, "bmw": 0, "hedged": 0, "late_hedged": 0,
-                      "late_hedged_jass": 0}
+                      "late_hedged_jass": 0, "jass_pad_rows": 0,
+                      "bmw_pad_rows": 0, "jass_postings": 0}
 
     def route(self, pred_k: np.ndarray, pred_rho: np.ndarray,
               pred_t: np.ndarray) -> RoutedBatch:

@@ -15,8 +15,8 @@ Deployment shape (``DeploySpec``)
 The index is partitioned into ``n_shards`` contiguous **doc-range shards**
 (``shard_from_index`` over ``shard_ranges``); Stage-1 fans each routed
 sub-batch out across every shard's batched DAAT/SAAT engine and merges the
-per-shard top-k with ``merge_shard_topk`` — shards are merged in ascending
-doc-range order, so score ties break toward the **lower global doc id**,
+per-shard top-k with ``merge_shard_topk``, which ranks (score desc, global
+doc id asc), so score ties break toward the **lower global doc id**,
 exactly the tie-break of a single-shard run (a one-shard deployment is
 bit-identical to the historical ``CascadePipeline``).
 
@@ -41,6 +41,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass, replace
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -67,14 +68,15 @@ from repro.serving.faults import FaultInjector
 from repro.serving.latency import (CostModel, budget_attribution,
                                    over_budget, percentiles,
                                    resolve_level_cut, stage2_afford)
+from repro.serving.online.batcher import bucket_size
 from repro.serving.replicas import BMW, JASS, PoolConfig, ReplicaPool
 from repro.serving.scheduler import (RoutedBatch, SchedulerConfig,
                                      StageZeroScheduler)
 from repro.serving.spec import CascadeSpec, RoutingSpec
 from repro.serving.telemetry import QueryTrace, Span, Telemetry
 from repro.serving.telemetry.spans import (ACCOUNT, CACHE, REPLICAS, SERVE,
-                                           STAGE0, STAGE1, STAGE2, fetch,
-                                           span)
+                                           STAGE0, STAGE1, STAGE1_BMW,
+                                           STAGE1_JASS, STAGE2, fetch, span)
 from repro.serving.telemetry.export import (legacy_stats_view,
                                             render_json,
                                             render_prometheus)
@@ -576,24 +578,39 @@ class SearchSystem:
         t_bmw = np.zeros(q)
         t_shards = np.zeros((ns, q))
 
-        def gather(rows, sc_list, id_list, extra):
+        def padded(rows):
+            """The rows' terms and mask with inert rows (mask 0) after the
+            real ones, up to the batcher's bucket width, so each engine
+            compiles per width rather than per sub-batch size; and the
+            number of pad rows."""
+            n, on = len(rows), self.cascade_spec.online
+            w = (bucket_size(n, on.max_batch, on.bucket_q)
+                 if n <= on.max_batch else n)
+            t = np.zeros((w, terms.shape[1]), terms.dtype)
+            m = np.zeros((w, mask.shape[1]), mask.dtype)
+            t[:n], m[:n] = terms[rows], mask[rows]
+            return jnp.asarray(t), jnp.asarray(m), w - n
+
+        def gather(rows, pad, sc_list, id_list, extra):
             """Merge one engine's per-segment lists into ``topk``/
             ``topk_sc`` and read them back, with ``extra`` (the engines'
             work counts), in one device wait; returns ``extra`` on the
-            host."""
+            host, for the real rows."""
             merged = None
             if ns > 1 or self.delta is not None:
-                dr = None if drop is None else drop[:, rows]
+                dr = None if drop is None else np.pad(drop[:, rows],
+                                                      ((0, 0), (0, pad)))
                 if dr is not None and self.delta is not None:
                     # the delta segment is local to the merge host — never
                     # lost, never admission-dropped
-                    dr = np.concatenate(
-                        [dr, np.zeros((1, len(rows)), bool)])
+                    dr = np.concatenate([dr, np.zeros((1, dr.shape[1]),
+                                                      bool)])
                 merged = merge_shard_topk(sc_list, id_list, self.k_serve,
                                           drop=dr)
             debug = self._debug_shard_lists is not None
             lists = (sc_list, id_list) if debug or merged is None else None
-            lists, merged, extra = fetch(lists, merged, extra)
+            lists, merged, extra = jax.tree.map(
+                lambda a: a[:len(rows)], fetch(lists, merged, extra))
             if debug:
                 self._debug_shard_lists.append((rows, *lists))
             if merged is None:
@@ -608,89 +625,97 @@ class SearchSystem:
                 topk_sc[rows] = merged[1].astype(np.float32)
             return extra
 
+        stats = self.sched.stats
         if len(routed.jass_rows):
-            rows = routed.jass_rows
-            rho_rows = routed.rho[rows]
-            if ns > 1 or self.delta is not None:
-                # one global level cut → per-segment budgets that reproduce
-                # exactly the single-shard posting set (see module
-                # docstring); a live delta is one more segment of the cut
-                work_s, any_ok = self._jass_split(terms, mask, rows,
-                                                  rho_rows, cache)
-                rho_per_shard = [np.where(any_ok, w, -1.0).astype(np.float64)
-                                 for w in work_s]
-            else:
-                rho_per_shard = [rho_rows]
-            sc_list, id_list, work = [], [], []
-            for s in range(ns):
-                res = saat_serve(self.shards[s], jnp.asarray(terms[rows]),
-                                 jnp.asarray(mask[rows]),
-                                 jnp.asarray(rho_per_shard[s]),
-                                 n_docs=self.shard_specs[s].n_docs,
-                                 k=self.k_serve,
-                                 cap=int(self.sched.cfg.rho_max),
-                                 tile_d=self.shard_specs[s].tile_d,
-                                 backend=self.backend)
-                sc_list.append(res.topk_scores)
-                id_list.append(res.topk_docs + self.doc_lo[s])
-                work.append(res.work)
-            if self.delta is not None:
-                # the delta pseudo-shard scans its slice of the same global
-                # cut; appended LAST so merge ties keep breaking toward the
-                # lower global doc id (delta ids all sit above the sealed
-                # collection).  Its time is the static _delta_us term.
-                dsp = self.delta.shard_spec
-                res = saat_serve(self.delta.shard, jnp.asarray(terms[rows]),
-                                 jnp.asarray(mask[rows]),
-                                 jnp.asarray(rho_per_shard[ns]),
-                                 n_docs=dsp.n_docs, k=self.k_serve,
-                                 cap=int(self.sched.cfg.rho_max),
-                                 tile_d=dsp.tile_d, backend=self.backend)
-                sc_list.append(res.topk_scores)
-                id_list.append(res.topk_docs + self.delta.base_docs)
-            work = gather(rows, sc_list, id_list, work)
-            for s in range(ns):
-                t_shards[s, rows] = self.cost.saat_time(
-                    work[s].astype(np.float64))
+            with span(STAGE1_JASS):
+                rows = routed.jass_rows
+                rho_rows = routed.rho[rows]
+                if ns > 1 or self.delta is not None:
+                    # one global level cut → per-segment budgets that
+                    # reproduce exactly the single-shard posting set (see
+                    # module docstring); a live delta is one more segment
+                    # of the cut
+                    work_s, any_ok = self._jass_split(terms, mask, rows,
+                                                      rho_rows, cache)
+                    rho_per_shard = [np.where(any_ok, w, -1.0)
+                                     .astype(np.float64) for w in work_s]
+                else:
+                    rho_per_shard = [rho_rows]
+                t_q, m_q, pad = padded(rows)
+                # pad rows have no terms: any budget scores nothing
+                rho_per_shard = [jnp.asarray(np.pad(r, (0, pad)))
+                                 for r in rho_per_shard]
+                sc_list, id_list, work = [], [], []
+                for s in range(ns):
+                    res = saat_serve(self.shards[s], t_q, m_q,
+                                     rho_per_shard[s],
+                                     n_docs=self.shard_specs[s].n_docs,
+                                     k=self.k_serve,
+                                     cap=int(self.sched.cfg.rho_max),
+                                     tile_d=self.shard_specs[s].tile_d,
+                                     backend=self.backend)
+                    sc_list.append(res.topk_scores)
+                    id_list.append(res.topk_docs + self.doc_lo[s])
+                    work.append(res.work)
+                if self.delta is not None:
+                    # the delta pseudo-shard scans its slice of the same
+                    # global cut, appended last (its ids all sit above the
+                    # sealed collection).  Its time is the static _delta_us
+                    # term.
+                    dsp = self.delta.shard_spec
+                    res = saat_serve(self.delta.shard, t_q, m_q,
+                                     rho_per_shard[ns],
+                                     n_docs=dsp.n_docs, k=self.k_serve,
+                                     cap=int(self.sched.cfg.rho_max),
+                                     tile_d=dsp.tile_d, backend=self.backend)
+                    sc_list.append(res.topk_scores)
+                    id_list.append(res.topk_docs + self.delta.base_docs)
+                work = gather(rows, pad, sc_list, id_list, work)
+                stats["jass_pad_rows"] += pad
+                stats["jass_postings"] += int(sum(w.sum() for w in work))
+                for s in range(ns):
+                    t_shards[s, rows] = self.cost.saat_time(
+                        work[s].astype(np.float64))
 
         if len(routed.bmw_rows):
-            rows = routed.bmw_rows
-            sc_list, id_list, work = [], [], []
-            for s in range(ns):
-                spec_s = self.shard_specs[s]
-                qcap = query_lane_budget(self._df_host[s], terms[rows],
-                                         mask[rows])
-                res = daat_serve(self.shards[s], jnp.asarray(terms[rows]),
-                                 jnp.asarray(mask[rows]),
-                                 jnp.ones(len(rows), jnp.float32),
-                                 n_docs=spec_s.n_docs,
-                                 n_blocks=spec_s.n_blocks,
-                                 block_size=spec_s.block_size,
-                                 k=self.k_serve, cap=spec_s.max_df,
-                                 bcap=spec_s.max_blocks_per_term, qcap=qcap,
-                                 tile_d=spec_s.tile_d, backend=self.backend)
-                sc_list.append(res.topk_scores)
-                id_list.append(res.topk_docs + self.doc_lo[s])
-                work.append((res.work, res.blocks))
-            if self.delta is not None:
-                # rank-safe BMW over the capacity-padded delta segment: the
-                # qcap default (L * cap) is spec-static, so fill level never
-                # changes the jit signature
-                dsp = self.delta.shard_spec
-                res = daat_serve(self.delta.shard, jnp.asarray(terms[rows]),
-                                 jnp.asarray(mask[rows]),
-                                 jnp.ones(len(rows), jnp.float32),
-                                 n_docs=dsp.n_docs, n_blocks=dsp.n_blocks,
-                                 block_size=dsp.block_size, k=self.k_serve,
-                                 cap=dsp.max_df,
-                                 bcap=dsp.max_blocks_per_term,
-                                 tile_d=dsp.tile_d, backend=self.backend)
-                sc_list.append(res.topk_scores)
-                id_list.append(res.topk_docs + self.delta.base_docs)
-            work = gather(rows, sc_list, id_list, work)
-            for s in range(ns):
-                t_shards[s, rows] = self.cost.daat_time(*work[s])
-            t_bmw[rows] = self.cost.gather_time(t_shards[:, rows])
+            with span(STAGE1_BMW):
+                rows = routed.bmw_rows
+                t_q, m_q, pad = padded(rows)
+                theta = jnp.ones(len(t_q), jnp.float32)
+                sc_list, id_list, work = [], [], []
+                for s in range(ns):
+                    spec_s = self.shard_specs[s]
+                    qcap = query_lane_budget(self._df_host[s], terms[rows],
+                                             mask[rows])
+                    res = daat_serve(self.shards[s], t_q, m_q, theta,
+                                     n_docs=spec_s.n_docs,
+                                     n_blocks=spec_s.n_blocks,
+                                     block_size=spec_s.block_size,
+                                     k=self.k_serve, cap=spec_s.max_df,
+                                     bcap=spec_s.max_blocks_per_term,
+                                     qcap=qcap, tile_d=spec_s.tile_d,
+                                     backend=self.backend)
+                    sc_list.append(res.topk_scores)
+                    id_list.append(res.topk_docs + self.doc_lo[s])
+                    work.append((res.work, res.blocks))
+                if self.delta is not None:
+                    # rank-safe BMW over the capacity-padded delta segment:
+                    # the qcap default (L * cap) is spec-static, so fill
+                    # level never changes the jit signature
+                    dsp = self.delta.shard_spec
+                    res = daat_serve(self.delta.shard, t_q, m_q, theta,
+                                     n_docs=dsp.n_docs, n_blocks=dsp.n_blocks,
+                                     block_size=dsp.block_size,
+                                     k=self.k_serve, cap=dsp.max_df,
+                                     bcap=dsp.max_blocks_per_term,
+                                     tile_d=dsp.tile_d, backend=self.backend)
+                    sc_list.append(res.topk_scores)
+                    id_list.append(res.topk_docs + self.delta.base_docs)
+                work = gather(rows, pad, sc_list, id_list, work)
+                stats["bmw_pad_rows"] += pad
+                for s in range(ns):
+                    t_shards[s, rows] = self.cost.daat_time(*work[s])
+                t_bmw[rows] = self.cost.gather_time(t_shards[:, rows])
         return topk, topk_sc, t_bmw, t_shards
 
     def stage2(self, terms, mask, topics, cand, k_per_query) -> CascadeResult:

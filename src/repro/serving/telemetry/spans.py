@@ -9,9 +9,11 @@ Outside a profiler trace a span costs one annotation enter/exit (about a
 microsecond) and records nothing.
 
 ``cascade.serve`` is the root of one ``SearchSystem.serve`` call; the
-stage spans below it are disjoint, and ``cascade.sync`` nests inside
-whichever of them waits for the device (:func:`fetch`, the serve path's
-one blocking read per stage or engine branch).
+stage spans below it are disjoint.  Inside ``cascade.stage1`` each engine
+branch that serves rows is one ``cascade.jass`` or ``cascade.bmw``, and
+``cascade.sync`` nests inside whichever span waits for the device
+(:func:`fetch`, the serve path's one blocking read per stage or engine
+branch).
 """
 
 from __future__ import annotations
@@ -24,13 +26,16 @@ from jax.profiler import TraceAnnotation
 SERVE = "cascade.serve"        # one SearchSystem.serve call
 STAGE0 = "cascade.stage0"      # features, stacked forest, route, modality
 STAGE1 = "cascade.stage1"      # lane budgets, engines per shard, merge, dense
+STAGE1_JASS = "cascade.jass"   # JASS calls over every segment, its read-back
+STAGE1_BMW = "cascade.bmw"     # BMW calls over every segment, its read-back
 STAGE2 = "cascade.stage2"      # stage2_afford, lane budget, re-rank, skips
 REPLICAS = "cascade.replicas"  # replica picks, fault plan, pool feedback
 ACCOUNT = "cascade.account"    # virtual-clock latencies, stats, traces
 CACHE = "cascade.cache"        # serving-cache lookup and fill
 SYNC = "cascade.sync"          # one blocking device-to-host read
 GC = "python.gc"               # a Python garbage collection (gc_spans)
-NAMES = (SERVE, STAGE0, STAGE1, STAGE2, REPLICAS, ACCOUNT, CACHE, SYNC, GC)
+NAMES = (SERVE, STAGE0, STAGE1, STAGE1_JASS, STAGE1_BMW, STAGE2, REPLICAS,
+         ACCOUNT, CACHE, SYNC, GC)
 
 
 def span(name: str) -> TraceAnnotation:

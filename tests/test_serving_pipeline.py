@@ -302,7 +302,7 @@ def test_tiled_topk_matches_dense_topk_with_ties():
     acc_i = jnp.asarray(rng.randint(0, 7, (16, 1000)), jnp.int32)
     acc_f = acc_i.astype(jnp.float32)
     for acc in (acc_i, acc_f):
-        sc, ids = tiled_topk(acc, 25, tile_d=128)
+        sc, ids = tiled_topk(acc, 25, tile_d=128, max_score=6)
         sc_r, ids_r = jax.lax.top_k(acc, 25)
         np.testing.assert_array_equal(np.asarray(sc), np.asarray(sc_r))
         np.testing.assert_array_equal(np.asarray(ids), np.asarray(ids_r))

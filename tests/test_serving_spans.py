@@ -139,3 +139,29 @@ def test_fetch_reads_every_leaf_in_one_sync_span(tmp_path):
     names = [ev[0] for ev in hostspans.load(hostspans.newest(tmp_path))]
     assert names.count(S.SYNC) == 1
     assert set(S.NAMES) >= {S.SERVE, S.SYNC, S.GC}
+
+
+def test_engine_spans_and_pad_counters(tiny, tmp_path):
+    """Inside ``cascade.stage1`` each engine that served rows is one
+    ``cascade.jass`` or ``cascade.bmw`` holding its one read-back; the pad
+    rows each engine ran and the JASS postings scored are counted."""
+    from repro.serving.online.batcher import bucket_size
+
+    corpus, index, ql, system = tiny
+    before = dict(system.sched.stats)
+    res, spans = _traced(system, ql, tmp_path, [slice(8, 16)])
+    r, = res
+    stats = system.sched.stats
+    n = {e: stats[e] - before[e] for e in ("jass", "bmw")}
+    assert n["jass"] and n["bmw"]
+    for e in ("jass", "bmw"):
+        assert stats[f"{e}_pad_rows"] - before[f"{e}_pad_rows"] == \
+            bucket_size(n[e], system.cascade_spec.online.max_batch) - n[e]
+    assert stats["jass_postings"] > before["jass_postings"]
+    (s1, d1), = [(s, d) for name, s, d in spans if name == S.STAGE1]
+    for name in (S.STAGE1_JASS, S.STAGE1_BMW):
+        (s, d), = [(s, d) for n2, s, d in spans if n2 == name]
+        assert s1 <= s and s + d <= s1 + d1
+        syncs = [s2 for n2, s2, d2 in spans
+                 if n2 == S.SYNC and s <= s2 and s2 + d2 <= s + d]
+        assert len(syncs) == 1
