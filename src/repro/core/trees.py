@@ -2,9 +2,10 @@
 
 LightGBM-style histogram trees, built level-wise with fully vectorized
 ``segment_sum`` histograms so training jits end-to-end.  Trees are complete
-binary trees of fixed depth stored as dense arrays, so inference is a
-branch-free O(depth) gather chain — cheap enough to run *inside* the serving
-step (the paper's "Stage-0" predictions must add <1 ms per query).
+binary trees of fixed depth stored as dense arrays, so inference is
+branch- and gather-free array code over every (tree, node, row) — cheap
+enough to run *inside* the serving step (the paper's "Stage-0" predictions
+must add <1 ms per query) and for Stage-2 to score every candidate.
 
 Feature values are pre-binned (quantile binning) to uint8; split thresholds
 are bin indices.  The binner (``fit_bins``/``apply_bins``) is part of the
@@ -36,6 +37,15 @@ class Forest(NamedTuple):
     feat:   (T, depth, 2**(depth-1)) int32 — split feature per node
     thresh: (T, depth, 2**(depth-1)) int32 — split bin; go right if bin > thresh
     leaf:   (T, 2**depth) float32 — leaf scores
+
+    Level d's nodes are ``feat[:, d, :2**d]`` (the rest is padding); node j
+    at level d has children 2j and 2j + 1 at level d + 1, and the leaf index
+    is the node index below the last level.  Inference reads these tables
+    without a data-dependent gather: the split bins of all nodes and rows
+    come from a one-hot contraction over the features (0/1 and bins < 256
+    are exact in bfloat16 and each sum has one nonzero term, so it is exact
+    on any backend), the descent selects by ``node == iota``, and the leaf
+    value by halving the leaf table on each bit of the leaf index.
     """
     feat: jnp.ndarray
     thresh: jnp.ndarray
@@ -162,24 +172,45 @@ def leaf_quantiles(leaf_id, values, weight, n_leaves, tau):
 # Inference
 # ---------------------------------------------------------------------------
 
-def _descend(feat, thresh, xb_row, depth):
-    node = jnp.zeros((), jnp.int32)
-    for d in range(depth):
-        f = feat[d, node]
-        b = thresh[d, node]
-        node = node * 2 + (xb_row[f].astype(jnp.int32) > b).astype(jnp.int32)
-    return node
+def _heap_order(a: jnp.ndarray, depth: int) -> jnp.ndarray:
+    """(..., depth, 2**(depth-1)) per-level node table -> (..., 2**depth - 1),
+    the level-d nodes at [2**d - 1, 2**(d+1) - 1) (the padding dropped)."""
+    return jnp.concatenate([a[..., d, :2 ** d] for d in range(depth)],
+                           axis=-1)
 
 
 @functools.partial(jax.jit, static_argnames=("depth", "reduce"))
 def forest_predict_binned(forest: Forest, xb: jnp.ndarray, depth: int,
                           reduce: str = "sum") -> jnp.ndarray:
-    """Predict from pre-binned features. reduce: 'sum' (boosting) | 'mean' (bagging)."""
-    def per_row(row):
-        leaves = jax.vmap(lambda ft, th, lf: lf[_descend(ft, th, row, depth)])(
-            forest.feat, forest.thresh, forest.leaf)
-        return jnp.sum(leaves) if reduce == "sum" else jnp.mean(leaves)
-    return jax.vmap(per_row)(xb)
+    """Predict from (n, F) pre-binned features (bins < 256).
+    reduce: 'sum' (boosting) | 'mean' (bagging).
+
+    Gather-free (see ``Forest``): one exact one-hot contraction gives every
+    internal node's split bin for every row, laid out (T, node, n); level d
+    reads its bit with ``node == iota`` over its 2**d nodes, and the leaf
+    value comes from ``depth`` halving selects over the leaf table.  Then
+    one float32 sum (or mean) over the trees.
+    """
+    feat = _heap_order(forest.feat, depth)                  # (T, 2^D - 1)
+    thresh = _heap_order(forest.thresh, depth)
+    onehot = feat[..., None] == jnp.arange(xb.shape[-1])    # (T, 2^D - 1, F)
+    bins = jnp.einsum("tnf,rf->tnr", onehot.astype(jnp.bfloat16),
+                      xb.astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32)
+    right = bins.astype(jnp.int32) > thresh[..., None]      # (T, 2^D - 1, n)
+    node = jnp.zeros((feat.shape[0], xb.shape[0]), jnp.int32)
+    for d in range(depth):
+        level = right[:, 2 ** d - 1:2 ** (d + 1) - 1]       # (T, 2^d, n)
+        at = node[:, None] == jnp.arange(2 ** d)[:, None]
+        node = node * 2 + jnp.any(at & level, axis=1).astype(jnp.int32)
+    # leaf value: halve the leaf table on each bit of node, lowest first
+    leaves = forest.leaf[..., None]                         # (T, 2^D, 1)
+    for d in range(depth):
+        bit = ((node >> d) & 1)[:, None] == 1
+        leaves = jnp.where(bit, jax.lax.slice_in_dim(leaves, 1, None, 2, 1),
+                           jax.lax.slice_in_dim(leaves, 0, None, 2, 1))
+    leaves = leaves[:, 0].T                                 # (n, T)
+    return jnp.sum(leaves, -1) if reduce == "sum" else jnp.mean(leaves, -1)
 
 
 @functools.partial(jax.jit, static_argnames=("depth", "reduce"))
@@ -190,9 +221,9 @@ def forest_predict_stacked(forests: Forest, xb: jnp.ndarray, depth: int,
     ``forests`` is a Forest whose arrays carry a leading (M,) model axis
     (same tree count and depth per model — stack with ``jnp.stack``);
     ``xb`` is (M, n, F) pre-binned features, one binning per model.  The
-    per-model math is the exact gather chain of ``forest_predict_binned``
-    vmapped over the model axis, so the Stage-0 k/ρ/t predictors run as one
-    array program instead of three dispatches.  Returns (M, n).
+    per-model math is ``forest_predict_binned`` vmapped over the model
+    axis, so the Stage-0 k/ρ/t predictors run as one array program instead
+    of three dispatches.  Returns (M, n).
     """
     return jax.vmap(
         lambda f, b: forest_predict_binned(f, b, depth, reduce))(forests, xb)

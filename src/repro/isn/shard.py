@@ -25,6 +25,7 @@ from jax import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from repro.core import trees as T
 from repro.index.postings import IndexShard
 from repro.isn.daat import daat_serve
 from repro.isn.saat import saat_serve
@@ -54,19 +55,10 @@ def forest_specs(n_targets=3, n_trees=64, depth=5, n_feats=147, n_bins=64):
 
 
 def _forest_predict(fa: ForestArrays, x, target: int, depth: int):
-    """Vectorized fixed-depth descent; x: (Q, F) raw features -> (Q,)."""
-    xb = jnp.sum(x[:, :, None] > fa.bin_edges[None], axis=-1).astype(jnp.int32)
-
-    def per_row(row):
-        def per_tree(ft, th, lf):
-            node = jnp.zeros((), jnp.int32)
-            for d in range(depth):
-                f = ft[d, node]
-                node = node * 2 + (row[f] > th[d, node]).astype(jnp.int32)
-            return lf[node]
-        return jnp.sum(jax.vmap(per_tree)(fa.feat[target], fa.thresh[target],
-                                          fa.leaf[target]))
-    return fa.base[target] + jax.vmap(per_row)(xb)
+    """x: (Q, F) raw features -> (Q,) prediction of model ``target``."""
+    forest = T.Forest(fa.feat[target], fa.thresh[target], fa.leaf[target])
+    return fa.base[target] + T.forest_predict_binned(
+        forest, T.apply_bins(x, fa.bin_edges), depth)
 
 
 def _stage0(fa, term_stats, df, terms, mask, depth=5):

@@ -1,11 +1,13 @@
 """Multi-stage cascade driver: Stage-0 predict → Stage-1 candidates (hybrid
 ISN) → Stage-2 LTR re-rank → final top-t.
 
-``rerank_batched`` is the serving path: one array program over the whole
+``rerank_batched`` is the serving path: array programs over the whole
 (Q, C) candidate grid — batched featurization (``qd_features_batched``),
-one fused GBRT inference over all (query, candidate) rows, and a masked
-``top_k`` selection whose tie-breaking (lower candidate rank first)
-matches the stable argsort of the loop.  ``rerank_loop`` keeps the original
+one GBRT inference over all (query, candidate) rows (gather-free: every
+split bin of every row from one one-hot contraction, then selects by node
+index, see ``core.trees.forest_predict_binned``), and a masked ``top_k``
+selection whose tie-breaking (lower candidate rank first) matches the
+stable argsort of the loop.  ``rerank_loop`` keeps the original
 one-query-at-a-time driver as the parity oracle; on the ``"jnp"`` backend
 the batched path reproduces it bit-for-bit
 (``tests/test_cascade_pipeline.py``, ``benchmarks/bench_hybrid.py``).

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import gbrt
+from repro.core import trees as T
 from repro.kernels.blockmax_score.ops import blockmax_score_tiles
 from repro.kernels.dense_topk.ops import dense_topk
 from repro.kernels.impact_accumulate.ops import impact_accumulate_tiles
@@ -38,6 +40,10 @@ EMB_D, DENSE_TILE = 32, 512       # dense embedding width, doc tile
 S2_Q, S2_QCAP = 32, 15360
 S2_POSTINGS, S2_VOCAB, S2_DOCS = 10_368_464, 2_328_791, 32768
 S2_ROWS = -(-(S2_POSTINGS + 1) // 1024) * 8   # (rows, 128) posting tables
+# the forests: Stage-2 48 trees of depth 4 over 8 features, Stage-0 three
+# stacked ensembles of 48 trees of depth 5 over 147 features
+S2_TREES, S2_DEPTH, S2_FEATS = 48, 4, 8
+S0_MODELS, S0_TREES, S0_DEPTH, S0_FEATS, N_BINS = 3, 48, 5, 147, 64
 
 
 @pytest.fixture(scope="module")
@@ -128,6 +134,34 @@ def test_stage2_program_reads_postings_in_place(one_chip):
     gathers = re.findall(r"= \w+\[([\d,]*)\][^\n]* gather\(", text)
     assert gathers and all(S2_QCAP not in map(int, g.split(","))
                            for g in gathers), gathers
+
+
+@pytest.mark.parametrize("stage", ["stage2", "stage0"])
+def test_forest_program_has_no_gather(one_chip, stage):
+    """The forest is evaluated without a data-dependent gather at both
+    serving shapes: Stage-2's (Q x C) candidate rows and Stage-0's fused
+    k/ρ/t call over a Q-query batch."""
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def forest(*lead, depth):
+        nodes = (*lead, depth, 2 ** (depth - 1))
+        return T.Forest(sds(nodes, jnp.int32), sds(nodes, jnp.int32),
+                        sds((*lead, 2 ** depth), jnp.float32))
+    if stage == "stage2":
+        lowered = T.forest_predict_binned.lower(
+            forest(S2_TREES, depth=S2_DEPTH),
+            sds((S2_Q * C, S2_FEATS), jnp.uint8), depth=S2_DEPTH)
+    else:
+        stacked = gbrt.StackedGBRT(
+            forest(S0_MODELS, S0_TREES, depth=S0_DEPTH),
+            sds((S0_MODELS,), jnp.float32),
+            sds((S0_MODELS, S0_FEATS, N_BINS - 1), jnp.float32))
+        lowered = gbrt.predict_stacked.lower(
+            stacked, sds((S2_Q, S0_FEATS), jnp.float32), depth=S0_DEPTH)
+    text = lowered.compile().as_text()
+    assert re.search(r" (convolution|dot)\(", text)
+    assert " gather(" not in text
 
 
 def test_dense_topk_compiles(one_chip):
