@@ -28,9 +28,13 @@ the lower doc id.
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from repro.index.postings import IndexShard, IndexShardSpec
 from repro.kernels.topk import topk_from_tiles  # noqa: F401  (re-export)
 
 BACKENDS = ("pallas", "interpret", "jnp")
@@ -46,7 +50,6 @@ def query_lane_budget(df, terms, mask, round_to: int = 1024,
     Callers size qcap from the batch they are about to serve (like length
     bucketing in LM serving); rounding bounds jit recompiles.
     """
-    import numpy as np
     eff = np.asarray(df)[np.asarray(terms)] * (np.asarray(mask) > 0)
     need = int(eff.sum(axis=1).max()) if eff.size else 0
     return max(-(-max(need, 1) // round_to) * round_to, floor)
@@ -144,6 +147,40 @@ def merge_shard_topk(scores: list, ids: list, k: int, drop=None):
     sc, neg = jax.lax.sort((sc, -di), dimension=1, num_keys=2)
     kk = min(k, sc.shape[1])
     return -neg[:, ::-1][:, :kk], sc[:, ::-1][:, :kk]
+
+
+class Segment(NamedTuple):
+    """One doc-range segment of a Stage-1 fan-out: a sealed shard or the
+    live delta, with the host tables Stage-1 reads for it."""
+    shard: IndexShard              # device mirrors
+    spec: IndexShardSpec           # their static shapes
+    doc_lo: int                    # global id of the segment's doc 0
+    level_cum: np.ndarray | None = None   # (V, n_levels) impact-level
+                                          # table: the global JASS cut
+    df: np.ndarray | None = None   # (V,) df: the jnp lane budget (None:
+                                   # the spec-static L * max_df)
+
+
+class SegmentLists(NamedTuple):
+    """One engine's fan-out over a segment list: the per-segment top-k
+    (ids global), their merge ((ids, scores), or None for one segment and
+    no drop) and the per-segment work counts (and blocks, for BMW)."""
+    scores: list
+    ids: list
+    merged: tuple | None
+    work: list
+    blocks: list | None = None
+
+
+def merge_segments(segments, results, k: int, drop=None):
+    """(scores, ids, merged) of per-segment engine ``results``: ids offset
+    to global by each segment's ``doc_lo``, merged by ``merge_shard_topk``
+    (``drop`` rows follow segment order) unless one segment has no drop."""
+    scores = [r.topk_scores for r in results]
+    ids = [r.topk_docs + g.doc_lo for g, r in zip(segments, results)]
+    merged = (None if len(results) == 1 and drop is None
+              else merge_shard_topk(scores, ids, k, drop=drop))
+    return scores, ids, merged
 
 
 def tiled_topk(acc: jnp.ndarray, k: int, tile_d: int = 128,

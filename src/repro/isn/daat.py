@@ -62,8 +62,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.index.postings import IndexShard
-from repro.isn.backend import (compact_lanes, map_query_blocks,
-                               resolve_backend, topk_from_tiles)
+from repro.isn.backend import (SegmentLists, compact_lanes,
+                               map_query_blocks, merge_segments,
+                               query_lane_budget, resolve_backend,
+                               topk_from_tiles)
 from repro.kernels.blockmax_score.ops import blockmax_score_tiles
 
 
@@ -234,10 +236,12 @@ def daat_serve(shard: IndexShard, terms: jnp.ndarray, mask: jnp.ndarray,
 
     cap: static per-term postings bound (max df in shard).
     bcap: static per-term block-entry bound.
-    qcap: static per-QUERY posting-lane budget for the jnp backend's
-      compacted gather; must cover max_q Σ_t min(df_t, cap) over the batch
-      (size it with ``repro.isn.backend.query_lane_budget``).  None falls
-      back to the exact worst case L·cap.
+    qcap: static per-QUERY posting-lane budget, read only by the jnp
+      backend's compacted gather; must cover max_q Σ_t min(df_t, cap) over
+      the batch (size it with ``repro.isn.backend.query_lane_budget``).
+      None falls back to the exact worst case L·cap.  The kernel backends
+      never read it: leave it None there, where a value would only add a
+      compile key.
     tile_d: docs per accumulator tile (must match the shard's bucketed
       mirror when a kernel backend runs).
     q_block: queries scored concurrently; larger batches stream through in
@@ -293,37 +297,34 @@ def daat_serve_laxmap(shard: IndexShard, terms: jnp.ndarray,
     return DaatResult(ids, sc, work, blocks)
 
 
-def daat_serve_segments(segments, terms, mask, theta, *, k, qcaps=None,
-                        tile_d: int = 128, q_block: int = 64,
-                        backend: str | None = None, drop=None):
-    """Serve one batch over sealed + delta segments and merge the top-k.
+def daat_serve_segments(segments, terms, mask, theta, *, k: int,
+                        backend: str | None = None, drop=None,
+                        engine=None) -> SegmentLists:
+    """Serve one batch over an ordered segment list and merge the top-k.
 
-    ``segments`` is a list of ``(shard, spec, doc_lo)`` in ascending
-    global-doc order — sealed shards first, then (optionally) the live
-    delta pseudo-shard, whose ``doc_lo`` is the sealed collection size.
-    Each segment is scanned with its own static caps (a delta segment's
-    capacity padding is inert: padded lanes sit past every term's df and
-    are never gathered), and the candidates merge through
-    ``merge_shard_topk``'s lower-global-doc-id tie policy.
-
-    ``qcaps[i]``/``drop[i]`` (optional) are per-segment; ``drop`` rows
-    follow segment order. Returns ``(ids, scores, works, blocks)``: the
-    merged (Q, k) global result plus per-segment work/block counters.
+    ``segments`` are :class:`~repro.isn.backend.Segment` s in ascending
+    global-doc order: sealed shards, then the live delta, whose ``doc_lo``
+    is the sealed collection size.  Each segment is scanned with its own
+    static caps (a delta's capacity padding is inert: padded lanes sit past
+    every term's df), and the candidates merge with ``merge_shard_topk``'s
+    lower-global-doc-id tie rule; ``drop`` ((n_segments, Q) bool) masks
+    lost slots out of the merge.  Only the ``jnp`` backend reads a lane
+    budget: it is sized from the segment's host ``df`` (the delta has none
+    and keeps the spec-static ``L * max_df``).  ``engine`` replaces the
+    per-segment ``daat_serve`` (same signature).
     """
-    from repro.isn.backend import merge_shard_topk
-
-    sc_list, id_list, works, blocks = [], [], [], []
-    for i, (shard, spec, doc_lo) in enumerate(segments):
-        r = daat_serve(shard, terms, mask, theta, n_docs=spec.n_docs,
-                       n_blocks=spec.n_blocks, block_size=spec.block_size,
-                       k=k, cap=spec.max_df, bcap=spec.max_blocks_per_term,
-                       qcap=None if qcaps is None else qcaps[i],
-                       tile_d=tile_d, q_block=q_block, backend=backend)
-        sc_list.append(r.topk_scores)
-        id_list.append(r.topk_docs + doc_lo)
-        works.append(r.work)
-        blocks.append(r.blocks)
-    if len(segments) == 1 and drop is None:
-        return id_list[0], sc_list[0], works, blocks
-    ids, sc = merge_shard_topk(sc_list, id_list, k, drop=drop)
-    return ids, sc, works, blocks
+    backend = resolve_backend(backend)
+    engine = engine or daat_serve
+    host = terms, mask
+    terms, mask = jnp.asarray(terms), jnp.asarray(mask)
+    res = []
+    for g in segments:
+        sp = g.spec
+        qcap = (query_lane_budget(g.df, *host)
+                if backend == "jnp" and g.df is not None else None)
+        res.append(engine(g.shard, terms, mask, theta, n_docs=sp.n_docs,
+                          n_blocks=sp.n_blocks, block_size=sp.block_size,
+                          k=k, cap=sp.max_df, bcap=sp.max_blocks_per_term,
+                          qcap=qcap, tile_d=sp.tile_d, backend=backend))
+    return SegmentLists(*merge_segments(segments, res, k, drop),
+                        [r.work for r in res], [r.blocks for r in res])

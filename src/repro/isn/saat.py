@@ -42,7 +42,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.index.postings import IndexShard
-from repro.isn.backend import (compact_lanes, map_query_blocks,
+from repro.isn.backend import (SegmentLists, compact_lanes,
+                               map_query_blocks, merge_segments,
                                resolve_backend, topk_from_tiles)
 from repro.kernels.impact_accumulate.ops import impact_accumulate_tiles
 
@@ -180,33 +181,25 @@ def saat_serve_laxmap(shard: IndexShard, terms: jnp.ndarray,
     return SaatResult(ids, sc, work)
 
 
-def saat_serve_segments(segments, terms, mask, rhos, *, k, cap,
-                        tile_d: int = 128, q_block: int = 64,
-                        backend: str | None = None, drop=None):
-    """Serve one batch over sealed + delta segments and merge the top-k.
+def saat_serve_segments(segments, terms, mask, rhos, *, k: int, cap: int,
+                        backend: str | None = None, drop=None,
+                        engine=None) -> SegmentLists:
+    """Serve one batch over an ordered segment list and merge the top-k.
 
-    ``segments`` is a list of ``(shard, spec, doc_lo)`` in ascending
-    global-doc order (delta pseudo-shard last); ``rhos[i]`` is segment
-    ``i``'s per-query postings budget — the caller resolves the global
-    ρ → level-cut split across *all* segments (delta included) so the
-    combined scanned prefix is exactly the budgeted work. Integer impact
-    accumulation keeps the merge bit-exact across backends; a delta
-    segment's capacity padding contributes zero impact and is outranked
-    by the sealed segments' real candidates.
-
-    Returns ``(ids, scores, works)`` with per-segment work counters.
+    ``segments`` are :class:`~repro.isn.backend.Segment` s in ascending
+    global-doc order: sealed shards, then the live delta.  ``rhos[i]`` is
+    segment ``i``'s per-query postings budget: the caller splits one global
+    level cut over *all* segments, so the union of the scanned prefixes is
+    exactly the budgeted work.  Integer accumulation keeps the merge
+    bit-exact across backends; a delta's capacity padding contributes zero
+    impact and is outranked by real candidates.  ``drop`` ((n_segments, Q)
+    bool) masks lost slots out of the merge.  ``engine`` replaces the
+    per-segment ``saat_serve`` (same signature).
     """
-    from repro.isn.backend import merge_shard_topk
-
-    sc_list, id_list, works = [], [], []
-    for i, (shard, spec, doc_lo) in enumerate(segments):
-        r = saat_serve(shard, terms, mask, rhos[i], n_docs=spec.n_docs,
-                       k=k, cap=cap, tile_d=tile_d, q_block=q_block,
-                       backend=backend)
-        sc_list.append(r.topk_scores)
-        id_list.append(r.topk_docs + doc_lo)
-        works.append(r.work)
-    if len(segments) == 1 and drop is None:
-        return id_list[0], sc_list[0], works
-    ids, sc = merge_shard_topk(sc_list, id_list, k, drop=drop)
-    return ids, sc, works
+    engine = engine or saat_serve
+    terms, mask = jnp.asarray(terms), jnp.asarray(mask)
+    res = [engine(g.shard, terms, mask, rho, n_docs=g.spec.n_docs, k=k,
+                  cap=cap, tile_d=g.spec.tile_d, backend=backend)
+           for g, rho in zip(segments, rhos)]
+    return SegmentLists(*merge_segments(segments, res, k, drop),
+                        [r.work for r in res])

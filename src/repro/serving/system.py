@@ -13,12 +13,13 @@ This is the unified serving facade the paper's framework implies: a
 Deployment shape (``DeploySpec``)
 ---------------------------------
 The index is partitioned into ``n_shards`` contiguous **doc-range shards**
-(``shard_from_index`` over ``shard_ranges``); Stage-1 fans each routed
-sub-batch out across every shard's batched DAAT/SAAT engine and merges the
-per-shard top-k with ``merge_shard_topk``, which ranks (score desc, global
-doc id asc), so score ties break toward the **lower global doc id**,
-exactly the tie-break of a single-shard run (a one-shard deployment is
-bit-identical to the historical ``CascadePipeline``).
+(``shard_from_index`` over ``shard_ranges``).  Stage-1 serves an ordered
+list of segments, the sealed shards then (ingest on) the live delta:
+``stage1`` makes one ``saat_serve_segments`` / ``daat_serve_segments``
+call per engine branch, which runs the batched SAAT/DAAT engine on every
+segment and merges the per-segment top-k with ``merge_shard_topk``.  The
+merge ranks (score desc, global doc id asc), so score ties break toward
+the **lower global doc id**, exactly the tie-break of a single-shard run.
 
 Multi-shard exactness: DAAT is rank-safe per shard, so the merged top-k is
 the exact global top-k.  For SAAT, the ρ budget resolves to a **global**
@@ -55,10 +56,11 @@ from repro.index.builder import InvertedIndex, build_index
 from repro.index.corpus import Corpus, FeedDocs
 from repro.index.delta import DeltaStore
 from repro.index.postings import shard_from_index, shard_ranges
-from repro.isn.backend import (merge_shard_topk, query_lane_budget,
-                               resolve_backend)
-from repro.isn.daat import daat_serve
-from repro.isn.saat import saat_serve
+from repro.isn.backend import Segment, query_lane_budget, resolve_backend
+# the per-segment engines ``stage1`` hands its fan-outs, bound here so that
+# a test can replace them on the serve path
+from repro.isn.daat import daat_serve, daat_serve_segments
+from repro.isn.saat import saat_serve, saat_serve_segments
 from repro.ltr.cascade import CascadeResult, rerank_batched
 from repro.ltr.ranker import (LTRModel, csr_search_iters, ltr_training_set,
                               qd_features, stage2_arrays, train_ltr)
@@ -113,7 +115,7 @@ def scheduler_config(routing: RoutingSpec) -> SchedulerConfig:
 
 
 def routing_spec(cfg: SchedulerConfig) -> RoutingSpec:
-    """The RoutingSpec describing a runtime SchedulerConfig (shim path)."""
+    """The RoutingSpec describing a runtime SchedulerConfig."""
     return RoutingSpec(
         algorithm=cfg.algorithm, t_k=cfg.t_k, t_time=cfg.t_time,
         rho_max=cfg.rho_max, rho_min=cfg.rho_min, budget=cfg.budget,
@@ -268,7 +270,32 @@ class SearchSystem:
 
     @property
     def n_shards(self) -> int:
-        return len(self.shards)
+        return len(self._sealed)
+
+    @property
+    def segments(self) -> list[Segment]:
+        """Stage-1's segments in global doc order: the sealed doc-range
+        shards, then (ingest on) the live delta, read from the delta as it
+        stands.  The delta carries no df, so its ``jnp`` lane budget stays
+        the spec-static ``L * max_df`` and its fill never changes a
+        signature."""
+        if self.delta is None:
+            return self._sealed
+        d = self.delta
+        return self._sealed + [Segment(d.shard, d.shard_spec, d.base_docs,
+                                       d.level_cum)]
+
+    @property
+    def shards(self) -> list:
+        return [g.shard for g in self._sealed]
+
+    @property
+    def shard_specs(self) -> list:
+        return [g.spec for g in self._sealed]
+
+    @property
+    def _df_host(self) -> list:
+        return [g.df for g in self._sealed]
 
     def _attach_index(self, index: InvertedIndex) -> None:
         """(Re)build every index-derived serving structure — doc-range
@@ -280,25 +307,23 @@ class SearchSystem:
         spec = self.cascade_spec
         self.index = index
         ranges = shard_ranges(index.n_docs, spec.deploy.n_shards)
-        self.doc_lo = [lo for lo, _ in ranges]
         built = [shard_from_index(index, lo, hi, tile_d=spec.index.tile_d)
                  for lo, hi in ranges]
-        self.shards = [s for s, _ in built]
-        self.shard_specs = [sp for _, sp in built]
-        min_docs = min(sp.n_docs for sp in self.shard_specs)
+        min_docs = min(sp.n_docs for _, sp in built)
         if min_docs < self.k_serve:
             raise ValueError(
                 f"k_serve={self.k_serve} exceeds the smallest shard "
                 f"({min_docs} docs at n_shards={spec.deploy.n_shards}); "
                 f"use fewer shards or a smaller k_serve")
-        self._df_host = [np.asarray(s.df) for s in self.shards]
         # host-side impact-level tables: the global SAAT level cut (and the
         # deterministic JASS cost) are resolved against the full collection,
         # then split per shard — see module docstring for why this keeps
         # multi-shard SAAT bit-identical to the single-shard traversal
-        self._level_cum_host = ([index.level_cum] if len(self.shards) == 1
-                                else [np.asarray(s.level_cum)
-                                      for s in self.shards])
+        level_cum = ([index.level_cum] if len(built) == 1
+                     else [np.asarray(s.level_cum) for s, _ in built])
+        self._sealed = [Segment(s, sp, lo, lc, np.asarray(s.df))
+                        for (s, sp), (lo, _), lc
+                        in zip(built, ranges, level_cum)]
 
         self.term_stats = jnp.asarray(index.term_stats)
         self.df = jnp.asarray(index.df)
@@ -501,13 +526,8 @@ class SearchSystem:
         """Resolve the ρ budget to the global impact-level cut and split the
         cut's work per segment.  Returns (per-segment work list, any_ok).
 
-        With a live delta attached the list carries one extra trailing
-        entry — the delta segment's slice of the same global cut (its
-        level table participates in the cut resolution, so ρ budgets the
-        *whole* collection including undigested feed docs).  Timing/pool
-        consumers slice ``work_s[:n_shards]``: the delta's scan cost is
-        charged as the shape-static ``_delta_us`` term, never from its
-        per-query work.
+        Every segment's level table joins the cut, a live delta's too, so ρ
+        budgets the *whole* collection including undigested feed docs.
 
         ``cache`` memoizes on (rows, rho) for the duration of one served
         batch — stage-1 budgeting, hedging resolution, and pool feedback
@@ -521,11 +541,8 @@ class SearchSystem:
             if key in cache:
                 return cache[key]
         m = (mask[rows] > 0)[:, :, None]
-        totals = [(lc[terms[rows]] * m).sum(axis=1)       # (R, n_levels)
-                  for lc in self._level_cum_host]
-        if self.delta is not None:
-            totals.append((self.delta.level_cum[terms[rows]] * m)
-                          .sum(axis=1))
+        totals = [(g.level_cum[terms[rows]] * m).sum(axis=1)  # (R, n_levels)
+                  for g in self.segments]
         total_g = totals[0] if len(totals) == 1 else np.sum(totals, axis=0)
         lstar, any_ok = resolve_level_cut(total_g, rho)
         rr = np.arange(len(rows))
@@ -534,28 +551,32 @@ class SearchSystem:
             cache[key] = (work_s, any_ok)
         return work_s, any_ok
 
+    def _shard_times(self, work, blocks=None):
+        """(n_shards, R) engine times from per-segment work counts: JASS's
+        postings, or BMW's postings and ``blocks``.  Only the sealed shards
+        are timed: a live delta (the last segment) is charged as the
+        shape-static ``_delta_us`` term, never from its per-query work."""
+        ns = self.n_shards
+        if blocks is None:
+            return np.stack([self.cost.saat_time(w.astype(np.float64))
+                             for w in work[:ns]])
+        return np.stack([self.cost.daat_time(w, b)
+                         for w, b in zip(work[:ns], blocks[:ns])])
+
     def _jass_time(self, terms, mask, cache: dict | None = None):
         """Deterministic JASS time under scatter-gather: the ρ budget
         resolves to a global level cut, each shard's slice of the cut costs
         its own work, and the query waits for the slowest shard."""
         def fn(rows, rho):
             work_s, _ = self._jass_split(terms, mask, rows, rho, cache)
-            t = np.stack([self.cost.saat_time(w.astype(np.float64))
-                          for w in work_s[:self.n_shards]])
-            return self.cost.gather_time(t)
+            return self.cost.gather_time(self._shard_times(work_s))
         return fn
 
-    def stage1(self, terms: np.ndarray, mask: np.ndarray, routed):
-        """Public alias of :meth:`_stage1_full` (shims may narrow the
-        return signature; ``serve`` always uses the full form).  Threads a
-        fresh per-call split memo so same-batch duplicate queries share
-        their SAAT level-cut resolution instead of recomputing it."""
-        return self._stage1_full(terms, mask, routed, {})
-
-    def _stage1_full(self, terms: np.ndarray, mask: np.ndarray, routed,
-                     cache: dict | None = None, drop=None):
-        """Fan the routed sub-batches out across every shard's batched
-        engine and merge the per-shard top-k.
+    def stage1(self, terms: np.ndarray, mask: np.ndarray, routed,
+               split_cache: dict | None = None, drop=None):
+        """Fan the routed sub-batches out over every segment (sealed shards,
+        then the live delta) with one engine call per branch, and merge the
+        per-segment top-k.
 
         Returns (topk, topk_sc, t_bmw, t_shards): merged global candidates
         and their merged scores (engine-native units; ``SCORE_FILL`` marks
@@ -564,157 +585,94 @@ class SearchSystem:
         per query, and the (n_shards, Q) per-shard engine-time matrix that
         feeds the replica pool's EWMA estimates.
 
-        ``drop`` ((n_shards, Q) bool, optional) marks (shard, query) slots
-        whose response was lost (fault injection) or never requested
-        (partial-coverage admission): their candidates are excluded from
-        the merge (padded with ``-1`` ids when fewer than ``k_serve``
-        survive), so a degraded query's list is exactly the merge over its
-        surviving partitions.
+        ``split_cache`` memoizes the JASS level-cut splits for one served
+        batch (a fresh one per call when omitted).  ``drop`` ((n_shards, Q)
+        bool, optional) marks (shard, query) slots whose response was lost
+        (fault injection) or never requested (partial-coverage admission):
+        their candidates are excluded from the merge (padded with ``-1``
+        ids when fewer than ``k_serve`` survive), so a degraded query's list
+        is exactly the merge over its surviving partitions.  The delta is
+        local to the merge host: never lost, never admission-dropped.
         """
+        split_cache = {} if split_cache is None else split_cache
         q = terms.shape[0]
-        ns = self.n_shards
+        segs = self.segments
         topk = np.zeros((q, self.k_serve), np.int64)
         topk_sc = np.full((q, self.k_serve), SCORE_FILL, np.float32)
         t_bmw = np.zeros(q)
-        t_shards = np.zeros((ns, q))
+        t_shards = np.zeros((self.n_shards, q))
 
         def padded(rows):
             """The rows' terms and mask with inert rows (mask 0) after the
             real ones, up to the batcher's bucket width, so each engine
-            compiles per width rather than per sub-batch size; and the
-            number of pad rows."""
+            compiles per width rather than per sub-batch size; the
+            segment-ordered drop mask over them; and the number of pad
+            rows."""
             n, on = len(rows), self.cascade_spec.online
             w = (bucket_size(n, on.max_batch, on.bucket_q)
                  if n <= on.max_batch else n)
             t = np.zeros((w, terms.shape[1]), terms.dtype)
             m = np.zeros((w, mask.shape[1]), mask.dtype)
             t[:n], m[:n] = terms[rows], mask[rows]
-            return jnp.asarray(t), jnp.asarray(m), w - n
+            dr = None if drop is None else np.pad(
+                drop[:, rows], ((0, len(segs) - len(drop)), (0, w - n)))
+            return t, m, dr, w - n
 
-        def gather(rows, pad, sc_list, id_list, extra):
-            """Merge one engine's per-segment lists into ``topk``/
-            ``topk_sc`` and read them back, with ``extra`` (the engines'
-            work counts), in one device wait; returns ``extra`` on the
-            host, for the real rows."""
-            merged = None
-            if ns > 1 or self.delta is not None:
-                dr = None if drop is None else np.pad(drop[:, rows],
-                                                      ((0, 0), (0, pad)))
-                if dr is not None and self.delta is not None:
-                    # the delta segment is local to the merge host — never
-                    # lost, never admission-dropped
-                    dr = np.concatenate([dr, np.zeros((1, dr.shape[1]),
-                                                      bool)])
-                merged = merge_shard_topk(sc_list, id_list, self.k_serve,
-                                          drop=dr)
+        def gather(rows, out):
+            """Write one engine's merged list into ``topk``/``topk_sc`` and
+            read it back, with the engines' work counts, in one device
+            wait; returns (work, blocks) on the host, for the real rows."""
             debug = self._debug_shard_lists is not None
-            lists = (sc_list, id_list) if debug or merged is None else None
-            lists, merged, extra = jax.tree.map(
-                lambda a: a[:len(rows)], fetch(lists, merged, extra))
+            lists = (out.scores, out.ids) if debug or out.merged is None \
+                else None
+            lists, merged, work, blocks = jax.tree.map(
+                lambda a: a[:len(rows)],
+                fetch(lists, out.merged, out.work, out.blocks))
             if debug:
                 self._debug_shard_lists.append((rows, *lists))
-            if merged is None:
-                topk[rows] = lists[1][0]
-                topk_sc[rows] = lists[0][0].astype(np.float32)
-                if drop is not None and drop[0, rows].any():
-                    dead = rows[drop[0, rows]]
-                    topk[dead] = -1
-                    topk_sc[dead] = SCORE_FILL
-            else:
-                topk[rows] = merged[0]
-                topk_sc[rows] = merged[1].astype(np.float32)
-            return extra
+            ids, sc = ((lists[1][0], lists[0][0]) if merged is None
+                       else merged)
+            topk[rows] = ids
+            # SCORE_FILL marks a dropped slot whatever the score dtype (the
+            # merge fills one with the dtype's minimum)
+            topk_sc[rows] = np.where(ids < 0, SCORE_FILL, sc)
+            return work, blocks
 
         stats = self.sched.stats
         if len(routed.jass_rows):
             with span(STAGE1_JASS):
                 rows = routed.jass_rows
-                rho_rows = routed.rho[rows]
-                if ns > 1 or self.delta is not None:
+                rhos = [routed.rho[rows]]
+                if len(segs) > 1:
                     # one global level cut → per-segment budgets that
                     # reproduce exactly the single-shard posting set (see
-                    # module docstring); a live delta is one more segment
-                    # of the cut
+                    # module docstring)
                     work_s, any_ok = self._jass_split(terms, mask, rows,
-                                                      rho_rows, cache)
-                    rho_per_shard = [np.where(any_ok, w, -1.0)
-                                     .astype(np.float64) for w in work_s]
-                else:
-                    rho_per_shard = [rho_rows]
-                t_q, m_q, pad = padded(rows)
+                                                      rhos[0], split_cache)
+                    rhos = [np.where(any_ok, w, -1.0).astype(np.float64)
+                            for w in work_s]
+                t_q, m_q, dr, pad = padded(rows)
                 # pad rows have no terms: any budget scores nothing
-                rho_per_shard = [jnp.asarray(np.pad(r, (0, pad)))
-                                 for r in rho_per_shard]
-                sc_list, id_list, work = [], [], []
-                for s in range(ns):
-                    res = saat_serve(self.shards[s], t_q, m_q,
-                                     rho_per_shard[s],
-                                     n_docs=self.shard_specs[s].n_docs,
-                                     k=self.k_serve,
-                                     cap=int(self.sched.cfg.rho_max),
-                                     tile_d=self.shard_specs[s].tile_d,
-                                     backend=self.backend)
-                    sc_list.append(res.topk_scores)
-                    id_list.append(res.topk_docs + self.doc_lo[s])
-                    work.append(res.work)
-                if self.delta is not None:
-                    # the delta pseudo-shard scans its slice of the same
-                    # global cut, appended last (its ids all sit above the
-                    # sealed collection).  Its time is the static _delta_us
-                    # term.
-                    dsp = self.delta.shard_spec
-                    res = saat_serve(self.delta.shard, t_q, m_q,
-                                     rho_per_shard[ns],
-                                     n_docs=dsp.n_docs, k=self.k_serve,
-                                     cap=int(self.sched.cfg.rho_max),
-                                     tile_d=dsp.tile_d, backend=self.backend)
-                    sc_list.append(res.topk_scores)
-                    id_list.append(res.topk_docs + self.delta.base_docs)
-                work = gather(rows, pad, sc_list, id_list, work)
+                out = saat_serve_segments(
+                    segs, t_q, m_q, [np.pad(r, (0, pad)) for r in rhos],
+                    k=self.k_serve, cap=int(self.sched.cfg.rho_max),
+                    backend=self.backend, drop=dr, engine=saat_serve)
+                work, _ = gather(rows, out)
                 stats["jass_pad_rows"] += pad
                 stats["jass_postings"] += int(sum(w.sum() for w in work))
-                for s in range(ns):
-                    t_shards[s, rows] = self.cost.saat_time(
-                        work[s].astype(np.float64))
+                t_shards[:, rows] = self._shard_times(work)
 
         if len(routed.bmw_rows):
             with span(STAGE1_BMW):
                 rows = routed.bmw_rows
-                t_q, m_q, pad = padded(rows)
-                theta = jnp.ones(len(t_q), jnp.float32)
-                sc_list, id_list, work = [], [], []
-                for s in range(ns):
-                    spec_s = self.shard_specs[s]
-                    qcap = query_lane_budget(self._df_host[s], terms[rows],
-                                             mask[rows])
-                    res = daat_serve(self.shards[s], t_q, m_q, theta,
-                                     n_docs=spec_s.n_docs,
-                                     n_blocks=spec_s.n_blocks,
-                                     block_size=spec_s.block_size,
-                                     k=self.k_serve, cap=spec_s.max_df,
-                                     bcap=spec_s.max_blocks_per_term,
-                                     qcap=qcap, tile_d=spec_s.tile_d,
-                                     backend=self.backend)
-                    sc_list.append(res.topk_scores)
-                    id_list.append(res.topk_docs + self.doc_lo[s])
-                    work.append((res.work, res.blocks))
-                if self.delta is not None:
-                    # rank-safe BMW over the capacity-padded delta segment:
-                    # the qcap default (L * cap) is spec-static, so fill
-                    # level never changes the jit signature
-                    dsp = self.delta.shard_spec
-                    res = daat_serve(self.delta.shard, t_q, m_q, theta,
-                                     n_docs=dsp.n_docs, n_blocks=dsp.n_blocks,
-                                     block_size=dsp.block_size,
-                                     k=self.k_serve, cap=dsp.max_df,
-                                     bcap=dsp.max_blocks_per_term,
-                                     tile_d=dsp.tile_d, backend=self.backend)
-                    sc_list.append(res.topk_scores)
-                    id_list.append(res.topk_docs + self.delta.base_docs)
-                work = gather(rows, pad, sc_list, id_list, work)
+                t_q, m_q, dr, pad = padded(rows)
+                out = daat_serve_segments(
+                    segs, t_q, m_q, jnp.ones(len(t_q), jnp.float32),
+                    k=self.k_serve, backend=self.backend, drop=dr,
+                    engine=daat_serve)
+                work, blocks = gather(rows, out)
                 stats["bmw_pad_rows"] += pad
-                for s in range(ns):
-                    t_shards[s, rows] = self.cost.daat_time(*work[s])
+                t_shards[:, rows] = self._shard_times(work, blocks)
                 t_bmw[rows] = self.cost.gather_time(t_shards[:, rows])
         return topk, topk_sc, t_bmw, t_shards
 
@@ -826,8 +784,7 @@ class SearchSystem:
             rows = np.fromiter(hedge_picks, dtype=np.int64)
             work_s, _ = self._jass_split(terms, mask, rows,
                                          routed.rho[rows], cache)
-            t_h = np.stack([self.cost.saat_time(w.astype(np.float64))
-                            for w in work_s[:self.n_shards]])
+            t_h = self._shard_times(work_s)
             for j, i in enumerate(rows):
                 reps = hedge_picks[int(i)]
                 if reps is None:
@@ -944,7 +901,7 @@ class SearchSystem:
 
         with span(STAGE1):
             split_cache: dict = {}
-            topk, topk_sc, t_bmw, t_shards = self._stage1_full(
+            topk, topk_sc, t_bmw, t_shards = self.stage1(
                 terms, mask, routed, split_cache, drop=drop)
 
             theta_skip = np.zeros(q, bool)
@@ -999,7 +956,7 @@ class SearchSystem:
                             rho=np.minimum(
                                 routed.rho,
                                 float(self.sched.cfg.resolved_late_rho())))
-                        fb_topk, fb_sc, _, fb_tsh = self._stage1_full(
+                        fb_topk, fb_sc, _, fb_tsh = self.stage1(
                             terms, mask, fb_routed, split_cache)
                         topk[fb_rows] = fb_topk[fb_rows]
                         topk_sc[fb_rows] = fb_sc[fb_rows]
@@ -1033,8 +990,7 @@ class SearchSystem:
                 def jass_fault_fn(rows, rho):
                     work_s, _ = self._jass_split(terms, mask, rows, rho,
                                                  split_cache)
-                    t = np.stack([self.cost.saat_time(w.astype(np.float64))
-                                  for w in work_s[:ns]])
+                    t = self._shard_times(work_s)
                     tf = np.where(dropped[:, rows], 0.0,
                                   delay[:, rows]
                                   + np.where(lost[:, rows], 0.0,

@@ -25,7 +25,7 @@ from jax.profiler import TraceAnnotation
 
 SERVE = "cascade.serve"        # one SearchSystem.serve call
 STAGE0 = "cascade.stage0"      # features, stacked forest, route, modality
-STAGE1 = "cascade.stage1"      # lane budgets, engines per shard, merge, dense
+STAGE1 = "cascade.stage1"      # level-cut split, engines per segment, merge, dense
 STAGE1_JASS = "cascade.jass"   # JASS calls over every segment, its read-back
 STAGE1_BMW = "cascade.bmw"     # BMW calls over every segment, its read-back
 STAGE2 = "cascade.stage2"      # stage2_afford, lane budget, re-rank, skips

@@ -2,8 +2,8 @@
 
 The unified array-program cascade (fused Stage-0, batched Stage-2 LTR
 re-rank, per-stage latency accounting) must reproduce the per-query
-reference paths: the numpy ``qd_features`` loop, the ``rerank_loop``
-cascade driver, and the pre-refactor ``HybridServer`` serving loop.
+reference paths: the numpy ``qd_features`` loop and the ``rerank_loop``
+cascade driver.
 """
 
 import jax.numpy as jnp
@@ -12,10 +12,7 @@ import pytest
 
 from repro.core import gbrt
 from repro.ltr import cascade, ranker
-from repro.serving.latency import CostModel
-from repro.serving.pipeline import CascadePipeline
 from repro.serving.scheduler import SchedulerConfig
-from repro.serving.server import HybridServer
 
 
 @pytest.fixture(scope="module")
@@ -260,7 +257,7 @@ def test_qd_features_interpret_backend_matches_jnp(stage2):
 
 
 # ---------------------------------------------------------------------------
-# end-to-end pipeline vs the HybridServer serving loop
+# end-to-end: a one-shard system against the per-query references
 # ---------------------------------------------------------------------------
 
 @pytest.fixture(scope="module")
@@ -284,11 +281,12 @@ def stage0_models(small_collection):
     return x, models
 
 
-def test_pipeline_stage0_matches_per_model(small_collection, stage0_models):
+def test_pipeline_stage0_matches_per_model(small_collection, stage0_models,
+                                           one_shard_system):
     corpus, index, ql = small_collection
     x, models = stage0_models
     cfg = SchedulerConfig(budget=100.0)
-    pipe = CascadePipeline(index, models, cfg)
+    pipe = one_shard_system(index, models, cfg)
     assert pipe._stacked is not None, "same-shaped ensembles must stack"
     pk, pr, pt = pipe.stage0(ql.terms, ql.mask)
     for name, got in (("k", pk), ("rho", pr), ("t", pt)):
@@ -297,34 +295,16 @@ def test_pipeline_stage0_matches_per_model(small_collection, stage0_models):
         np.testing.assert_array_equal(got, want)
 
 
-def test_pipeline_matches_hybrid_server(small_collection, stage0_models):
-    """Stage-1-only pipeline == HybridServer: same top-k, same latency."""
-    corpus, index, ql = small_collection
-    x, models = stage0_models
-    cfg = SchedulerConfig(budget=100.0, rho_max=1 << 14)
-    cost = CostModel.paper_scale()
-    pipe = CascadePipeline(index, models, cfg, cost=cost)
-    server = HybridServer(index, models,
-                          SchedulerConfig(budget=100.0, rho_max=1 << 14),
-                          cost=cost)
-    a = pipe.serve(ql.terms, ql.mask)
-    b = server.serve(ql.terms, ql.mask)
-    np.testing.assert_array_equal(a.topk, b.topk)
-    np.testing.assert_allclose(a.latency, b.latency)
-    for key in ("jass", "bmw", "p50", "p99", "over_budget"):
-        assert a.stats[key] == b.stats[key]
-
-
 def test_pipeline_full_cascade_matches_loop(small_collection, stage0_models,
-                                            ltr_model):
+                                            ltr_model, one_shard_system):
     """End-to-end: the pipeline's Stage-2 output equals running rerank_loop
     over the served Stage-1 candidates, and the cascade latency decomposes
     into the per-stage accounts."""
     corpus, index, ql = small_collection
     x, models = stage0_models
     cfg = SchedulerConfig(budget=100.0, rho_max=1 << 14)
-    pipe = CascadePipeline(index, models, cfg, corpus=corpus, ltr=ltr_model,
-                           k_serve=64, t_final=10)
+    pipe = one_shard_system(index, models, cfg, corpus=corpus, ltr=ltr_model,
+                            k_serve=64, t_final=10)
     res = pipe.serve(ql.terms, ql.mask, ql.topic)
     assert res.final is not None and res.final.shape == (96, 10)
 
@@ -346,7 +326,7 @@ def test_pipeline_full_cascade_matches_loop(small_collection, stage0_models,
 
 
 def test_cascade_budget_reserves_stage2(small_collection, stage0_models,
-                                        ltr_model):
+                                        ltr_model, one_shard_system):
     """With an LTR model attached, the scheduler enforces Stage-1 against
     budget - Stage-0 prediction cost - worst-case Stage-2 cost, so the
     late-hedge guarantee covers the cascade; without an LTR model only the
@@ -354,12 +334,12 @@ def test_cascade_budget_reserves_stage2(small_collection, stage0_models,
     corpus, index, ql = small_collection
     x, models = stage0_models
     cfg = SchedulerConfig(budget=30.0, rho_max=1 << 14)
-    pipe = CascadePipeline(index, models, cfg, corpus=corpus, ltr=ltr_model,
-                           k_serve=64)
+    pipe = one_shard_system(index, models, cfg, corpus=corpus, ltr=ltr_model,
+                            k_serve=64)
     reserve = float(pipe.cost.ltr_time(np.asarray(64)))
     assert pipe.sched.cfg.budget == pytest.approx(
         30.0 - pipe.cost.predict_us - reserve)
     assert pipe.budget == 30.0                 # reporting uses the full budget
-    plain = CascadePipeline(index, models, cfg)
+    plain = one_shard_system(index, models, cfg)
     assert plain.sched.cfg.budget == pytest.approx(
         30.0 - plain.cost.predict_us)

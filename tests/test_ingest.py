@@ -24,10 +24,12 @@ from repro.index.corpus import (FeedDocs, extend_corpus, slice_feed,
 from repro.index.delta import DeltaStore
 from repro.index.postings import shard_from_index
 from repro.isn import oracle
+from repro.isn.backend import Segment
 from repro.isn.daat import daat_serve, daat_serve_segments
 from repro.isn.saat import saat_serve, saat_serve_segments
 from repro.serving.online.simulator import INGEST_EVENT, MERGE_EVENT
 from repro.serving.online.traffic import feed_arrival_times
+from repro.serving.scheduler import RoutedBatch
 from repro.serving.spec import (BackendSpec, CacheSpec, CascadeSpec,
                                 DeploySpec, IngestSpec, OnlineSpec,
                                 RoutingSpec, Stage2Spec, TrafficSpec)
@@ -188,9 +190,11 @@ def test_saat_delta_scan_parity(small_collection, trial):
                      k=32, cap=cap)
 
     dshard, dspec = delta.segment()
-    segments = [(*shard_from_index(index), 0), (dshard, dspec, index.n_docs)]
-    ids, sc, works = saat_serve_segments(segments, terms, mask,
-                                         [rho, rho], k=32, cap=cap)
+    segments = [Segment(*shard_from_index(index), 0),
+                Segment(dshard, dspec, index.n_docs)]
+    out = saat_serve_segments(segments, terms, mask, [rho, rho], k=32,
+                              cap=cap)
+    ids, sc = out.merged
     np.testing.assert_array_equal(np.asarray(ids), np.asarray(ref.topk_docs))
     np.testing.assert_array_equal(np.asarray(sc), np.asarray(ref.topk_scores))
     # ghost capacity rows never surface
@@ -215,14 +219,14 @@ def test_saat_delta_multishard_and_drop(small_collection):
     cap = int(np.asarray(oidx.df).max())
     rho = jnp.full(len(rows), BIG)
     dshard, dspec = delta.segment()
-    segments = [(*shard_from_index(index, 0, half), 0),
-                (*shard_from_index(index, half, index.n_docs), half),
-                (dshard, dspec, index.n_docs)]
+    segments = [Segment(*shard_from_index(index, 0, half), 0),
+                Segment(*shard_from_index(index, half, index.n_docs), half),
+                Segment(dshard, dspec, index.n_docs)]
     drop = np.zeros((3, len(rows)), bool)
     drop[0, ::2] = True
-    ids, sc, works = saat_serve_segments(segments, terms, mask,
-                                         [rho, rho, rho], k=24, cap=cap,
-                                         drop=drop)
+    out = saat_serve_segments(segments, terms, mask, [rho, rho, rho], k=24,
+                              cap=cap, drop=drop)
+    ids, sc = out.merged
     acc, _ = oracle.jass_scores(oidx, ql.terms, ql.mask, rows, BIG)
     acc = np.asarray(acc, np.float64)
     acc[::2, :half] = -np.inf           # dropped shard's doc range
@@ -256,10 +260,10 @@ def test_daat_delta_scan_parity(small_collection):
                      n_blocks=ospec.n_blocks, block_size=ospec.block_size,
                      k=k, cap=ospec.max_df, bcap=ospec.max_blocks_per_term)
     dshard, dspec = delta.segment()
-    segments = [(*shard_from_index(index), 0), (dshard, dspec, index.n_docs)]
-    ids, sc, works, blocks = daat_serve_segments(segments, terms, mask,
-                                                 theta, k=k)
-    ids = np.asarray(ids)
+    segments = [Segment(*shard_from_index(index), 0),
+                Segment(dshard, dspec, index.n_docs)]
+    ids = np.asarray(daat_serve_segments(segments, terms, mask, theta,
+                                         k=k).merged[0])
     ref_ids = np.asarray(ref.topk_docs)
     overlap = np.mean([len(np.intersect1d(ids[i], ref_ids[i])) / k
                        for i in range(len(rows))])
@@ -270,10 +274,69 @@ def test_daat_delta_scan_parity(small_collection):
     # drop the sealed shard: only delta-range ids (or -1 padding) remain
     drop = np.zeros((2, len(rows)), bool)
     drop[0] = True
-    dids, _, _, _ = daat_serve_segments(segments, terms, mask, theta, k=k,
-                                        drop=drop)
-    dids = np.asarray(dids)
+    dids = np.asarray(daat_serve_segments(segments, terms, mask, theta, k=k,
+                                          drop=drop).merged[0])
     assert ((dids >= index.n_docs) | (dids == -1)).all()
+
+
+@pytest.mark.parametrize("engine", ["jass", "bmw"])
+@pytest.mark.parametrize("ingest", [False, True], ids=["sealed", "delta"])
+@pytest.mark.parametrize("n_shards", [1, 2])
+def test_stage1_serves_the_segment_list(small_collection, n_shards, ingest,
+                                        engine):
+    """``SearchSystem.stage1`` over its segment list (sealed shards, then
+    the live delta) against the monolithic collection: JASS id for id with
+    the numpy oracle at the same budget, BMW at the repo's rank-safety bar
+    (exact on the sealed index, the sealed + delta bar with a delta)."""
+    corpus, index, ql = small_collection
+    k, rows = 32, np.arange(24)
+    spec = CascadeSpec(
+        routing=RoutingSpec(rho_max=1 << 14),
+        stage2=Stage2Spec(enabled=False, k_serve=k),
+        backend=BackendSpec(backend="jnp"),
+        deploy=DeploySpec(n_shards=n_shards, replicas=2),
+        ingest=IngestSpec(enabled=ingest, delta_docs=64,
+                          delta_postings=1 << 14), name="segments")
+    system = build_system(spec, index, corpus=corpus)
+    ref_index = index
+    if ingest:
+        feed = synthesize_feed_docs(corpus, 48, seed=7)
+        assert system.add_documents(feed) == 48
+        ref_index = _frozen_oracle(index, extend_corpus(corpus, feed))
+    assert len(system.segments) == n_shards + ingest
+    none = np.zeros(0, np.int64)
+    rho = np.full(len(rows), 1500, np.int64)
+    routed = RoutedBatch(jass_rows=rows if engine == "jass" else none,
+                         bmw_rows=rows if engine == "bmw" else none,
+                         hedged_rows=none, k=np.full(len(rows), k, np.int64),
+                         rho=rho)
+    before = system.sched.stats["jass_postings"]
+    topk, topk_sc, _, t_shards = system.stage1(ql.terms[rows],
+                                               ql.mask[rows], routed)
+    assert t_shards.shape == (n_shards, len(rows))
+    # the delta's docs are served: someone's list holds one
+    assert (topk >= index.n_docs).any() == ingest
+    if engine == "jass":
+        acc, work = oracle.jass_scores(ref_index, ql.terms, ql.mask, rows,
+                                       rho)
+        o_ids, o_sc = _topk_tie(np.asarray(acc), k)
+        np.testing.assert_array_equal(topk, o_ids)
+        np.testing.assert_array_equal(topk_sc, o_sc.astype(np.float32))
+        assert system.sched.stats["jass_postings"] - before == work.sum()
+        return
+    oshard, ospec = shard_from_index(ref_index)
+    ref = np.asarray(daat_serve(
+        oshard, jnp.asarray(ql.terms[rows]), jnp.asarray(ql.mask[rows]),
+        jnp.ones(len(rows), jnp.float32), n_docs=ospec.n_docs,
+        n_blocks=ospec.n_blocks, block_size=ospec.block_size, k=k,
+        cap=ospec.max_df, bcap=ospec.max_blocks_per_term).topk_docs)
+    if not ingest:
+        np.testing.assert_array_equal(topk, ref)
+        return
+    overlap = np.mean([len(np.intersect1d(topk[i], ref[i])) / k
+                       for i in range(len(rows))])
+    assert overlap > 0.97
+    assert int(topk.max()) < ref_index.n_docs   # no ghost capacity rows
 
 
 # ---------------------------------------------------------------------------

@@ -1,6 +1,6 @@
 """SearchSystem / CascadeSpec suite: spec JSON round-trip, the preset
 registry, multi-shard scatter-gather parity vs the single-shard pipeline,
-compat-shim parity, and replica-pool integration.
+and replica-pool integration.
 """
 
 import dataclasses
@@ -10,13 +10,10 @@ import numpy as np
 import pytest
 
 from repro.configs.cascade_presets import PRESETS, get_preset
-from repro.serving.pipeline import CascadePipeline
-from repro.serving.scheduler import SchedulerConfig
-from repro.serving.server import HybridServer
 from repro.serving.spec import (BackendSpec, CascadeSpec, DeploySpec,
                                 IndexSpec, RoutingSpec, Stage0Spec,
                                 Stage2Spec)
-from repro.serving.system import SearchSystem, build_system
+from repro.serving.system import build_system
 
 
 # ---------------------------------------------------------------------------
@@ -175,39 +172,6 @@ def test_k_serve_must_fit_smallest_shard(small_collection):
         _spec(64), stage2=Stage2Spec(enabled=True, k_serve=128))
     with pytest.raises(ValueError, match="smallest shard"):
         build_system(spec, index, corpus=corpus)
-
-
-# ---------------------------------------------------------------------------
-# compat shims
-# ---------------------------------------------------------------------------
-
-def test_compat_shims_match_spec_system(fitted):
-    """CascadePipeline/HybridServer old signatures == a one-shard spec
-    system, bit for bit."""
-    corpus, index, ql, system, (tk, tt) = fitted
-    cfg = SchedulerConfig(budget=100.0, rho_max=1 << 14, t_k=tk,
-                          t_time=tt)
-    pipe = CascadePipeline(index, system.models, cfg, corpus=corpus,
-                           ltr=system.ltr, k_serve=64, t_final=10,
-                           backend="jnp")
-    assert isinstance(pipe, SearchSystem)
-    assert pipe.n_shards == 1
-    assert pipe.spec.n_docs == index.n_docs          # historical IndexShardSpec
-    a = system.serve(ql.terms, ql.mask, ql.topic)
-    b = pipe.serve(ql.terms, ql.mask, ql.topic)
-    np.testing.assert_array_equal(a.topk, b.topk)
-    np.testing.assert_array_equal(a.final, b.final)
-    np.testing.assert_allclose(a.latency, b.latency)
-
-    server = HybridServer(index, system.models, cfg, k_serve=64)
-    stage1 = build_system(
-        dataclasses.replace(_spec(1, tk, tt),
-                            stage2=Stage2Spec(enabled=False, k_serve=64)),
-        index, models=system.models)
-    c = server.serve(ql.terms, ql.mask)
-    d = stage1.serve(ql.terms, ql.mask)
-    np.testing.assert_array_equal(c.topk, d.topk)
-    np.testing.assert_allclose(c.latency, d.latency)
 
 
 # ---------------------------------------------------------------------------

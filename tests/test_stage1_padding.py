@@ -1,10 +1,12 @@
 """Mixed routing pads each engine's sub-batch to the batcher's bucket width.
 
 Algorithm 2 splits a batch between JASS and BMW, so each engine sees a
-sub-batch of any size from 1 to ``max_batch``.  ``_stage1_full`` pads it
-with inert rows after the real ones, so each engine builds one program per
-bucket width (and per lane budget ``qcap`` for BMW), not one per size, and
-the real rows' answers are those of the unpadded call.
+sub-batch of any size from 1 to ``max_batch``.  ``SearchSystem.stage1``
+pads it with inert rows after the real ones, so each engine builds one
+program per bucket width (and, on the ``jnp`` backend only, per BMW lane
+budget ``qcap``), not one per size, and the real rows' answers are those
+of the unpadded call.  Also here: the per-engine capture the benchmark
+harness reads (``_debug_shard_lists``).
 """
 
 import dataclasses
@@ -14,6 +16,8 @@ import pytest
 
 from repro.index.builder import build_index
 from repro.index.corpus import CorpusParams, build_corpus, build_queries
+from repro.isn.backend import query_lane_budget
+from repro.isn.daat import daat_serve
 from repro.serving import system as system_mod
 from repro.serving.online.batcher import bucket_size
 from repro.serving.scheduler import RoutedBatch
@@ -46,7 +50,9 @@ def systems():
 
 
 def _record(monkeypatch):
-    """(engine, width, qcap) of every engine call."""
+    """(engine, width, qcap) of every engine call: ``stage1`` hands the
+    engines bound in ``repro.serving.system`` to the ``isn`` fan-outs,
+    which call them once per segment."""
     calls = []
     for name, eng in (("saat_serve", "jass"), ("daat_serve", "bmw")):
         real = getattr(system_mod, name)
@@ -114,3 +120,75 @@ def test_jass_postings_counter_sums_the_engine_work(systems):
     from repro.isn import oracle
     _, work = oracle.jass_scores(padded.index, terms, mask, rows, rho)
     assert padded.sched.stats["jass_postings"] - before == int(work.sum())
+
+
+@pytest.mark.parametrize("n_shards", [1, 2])
+def test_debug_shard_lists_contract(systems, n_shards):
+    """The capture the benchmark harness reads: one (rows, score lists, id
+    lists) entry per engine branch, JASS before BMW, every list cut to the
+    real rows, segment 0 first; with one segment its lists are the served
+    ones."""
+    ql, (padded, _) = systems
+    sysm = padded if n_shards == 1 else build_system(
+        dataclasses.replace(padded.cascade_spec,
+                            deploy=DeploySpec(n_shards=2, replicas=2)),
+        padded.index, corpus=padded.corpus, models=padded.models,
+        ltr=padded.ltr)
+    none = np.zeros(0, np.int64)
+    jass, bmw = np.array([0, 3, 5]), np.array([1, 2, 4, 6, 7])
+    sysm._debug_shard_lists = []
+    topk, topk_sc, _, _ = sysm.stage1(ql.terms[:MB], ql.mask[:MB], RoutedBatch(
+        jass_rows=jass, bmw_rows=bmw, hedged_rows=none,
+        k=np.full(MB, sysm.k_serve, np.int64), rho=np.full(MB, 1500)))
+    lists, sysm._debug_shard_lists = sysm._debug_shard_lists, None
+    assert len(lists) == 2
+    hi = sysm.shard_specs[0].n_docs              # segment 0's doc range
+    for (rows, scs, ids), want in zip(lists, (jass, bmw)):
+        np.testing.assert_array_equal(rows, want)
+        assert len(scs) == len(ids) == n_shards
+        for sc, d in zip(scs, ids):
+            assert sc.shape == d.shape == (len(rows), sysm.k_serve)
+        assert ((ids[0] >= 0) & (ids[0] < hi)).all()
+        if n_shards == 1:
+            np.testing.assert_array_equal(ids[0], topk[rows])
+            np.testing.assert_array_equal(np.asarray(scs[0], np.float32),
+                                          topk_sc[rows])
+        else:
+            assert (ids[1] >= hi).all()
+
+
+def test_bmw_kernel_programs_are_keyed_by_width_alone(systems, monkeypatch):
+    """On a kernel backend (the interpreter here) BMW reads no lane budget:
+    batches whose ``jnp`` lane budgets differ share one program per batch
+    width."""
+    ql, (padded, _) = systems
+    sysm = build_system(
+        dataclasses.replace(padded.cascade_spec,
+                            backend=BackendSpec(backend="interpret")),
+        padded.index, corpus=padded.corpus)
+    # queries of the 1, 4 and all L most frequent terms: three lane budgets
+    df = sysm._df_host[0]
+    terms = np.zeros((3, ql.terms.shape[1]), ql.terms.dtype)
+    mask = np.zeros((3, ql.mask.shape[1]), ql.mask.dtype)
+    for i, n in enumerate((1, 4, terms.shape[1])):
+        terms[i, :n] = np.argsort(-df, kind="stable")[:n]
+        mask[i, :n] = 1
+    assert len({query_lane_budget(df, terms[i:i + 1], mask[i:i + 1])
+                for i in range(3)}) == 3
+    calls = _record(monkeypatch)
+    none = np.zeros(0, np.int64)
+    widths = sorted({bucket_size(n, MB) for n in range(1, MB + 1)})
+
+    def serve(i, w):
+        sysm.stage1(terms[[i] * w], mask[[i] * w], RoutedBatch(
+            jass_rows=none, bmw_rows=np.arange(w), hedged_rows=none,
+            k=np.full(w, sysm.k_serve, np.int64), rho=np.full(w, 1500)))
+    for w in widths:
+        serve(0, w)
+    programs = daat_serve._cache_size()
+    for i in (1, 2):
+        for w in widths:
+            serve(i, w)
+    assert daat_serve._cache_size() == programs
+    assert {q for _, _, q in calls} == {None}
+    assert {w for _, w, _ in calls} == set(widths)

@@ -13,7 +13,6 @@ from repro.core.reference import rbp_weights
 from repro.isn import oracle
 from repro.serving.latency import CostModel
 from repro.serving.scheduler import SchedulerConfig
-from repro.serving.server import HybridServer
 
 
 @pytest.fixture(scope="module")
@@ -55,7 +54,7 @@ def test_oracle_k_achieves_eps(pipeline):
         assert med <= cfg.eps + 1e-9
 
 
-def test_end_to_end_budget_guarantee(pipeline):
+def test_end_to_end_budget_guarantee(pipeline, one_shard_system):
     """The hybrid system must keep (almost) every query under budget while a
     fixed exhaustive BMW system does not — the paper's headline claim."""
     corpus, index, ql, labels, x = pipeline
@@ -72,7 +71,7 @@ def test_end_to_end_budget_guarantee(pipeline):
     cfg = SchedulerConfig(algorithm=2, budget=budget, rho_max=1 << 14,
                           t_time=budget * 0.6, t_k=float(
                               np.median(labels.oracle_k[keep])))
-    server = HybridServer(index, models, cfg, cost=cost)
+    server = one_shard_system(index, models, cfg, cost=cost)
     res = server.serve(ql.terms, ql.mask)
     frac_over_hybrid = np.mean(res.latency > budget)
     frac_over_bmw = np.mean(labels.t_bmw > budget)
