@@ -1,20 +1,23 @@
 """Multi-stage cascade driver: Stage-0 predict → Stage-1 candidates (hybrid
 ISN) → Stage-2 LTR re-rank → final top-t.
 
-``rerank_batched`` is the serving path: array programs over the whole
-(Q, C) candidate grid — batched featurization (``qd_features_batched``),
-one GBRT inference over all (query, candidate) rows (gather-free: every
-split bin of every row from one one-hot contraction, then selects by node
-index, see ``core.trees.forest_predict_binned``), and a masked ``top_k``
-selection whose tie-breaking (lower candidate rank first) matches the
-stable argsort of the loop.  ``rerank_loop`` keeps the original
-one-query-at-a-time driver as the parity oracle; on the ``"jnp"`` backend
-the batched path reproduces it bit-for-bit
-(``tests/test_cascade_pipeline.py``, ``benchmarks/bench_hybrid.py``).
+``rerank_batched`` is the serving path: one compiled program per (Q, C,
+qcap, t_final) — featurize, bin, forest, mask, top-k — over the whole (Q, C)
+candidate grid, launched once with the host inputs and read back once.  Inside
+it: batched featurization (``qd_features_batched``), one GBRT inference over
+all (query, candidate) rows (gather-free: every split bin of every row from
+one one-hot contraction, then selects by node index, see
+``core.trees.forest_predict_binned``), and a masked ``top_k`` selection
+whose tie-breaking (lower candidate rank first) matches the stable argsort
+of the loop.  ``rerank_loop`` keeps the original one-query-at-a-time cascade
+as the parity oracle; on the ``"jnp"`` backend the batched path reproduces
+it bit-for-bit (``tests/test_cascade_pipeline.py``,
+``benchmarks/bench_hybrid.py``).
 """
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
 import jax
@@ -58,12 +61,39 @@ def rerank_loop(index, corpus, ql, rows, candidate_lists, k_per_query,
     return CascadeResult(final=out, candidates_used=used)
 
 
+@functools.partial(jax.jit, static_argnames=("params", "t_final", "n_iter",
+                                             "backend", "qcap"))
+def _rerank_program(arrs: Stage2Arrays, forest, base, bin_edges, terms, mask,
+                    topics, cand, k_per_query, *, params: gbrt.GBRTParams,
+                    t_final: int, n_iter: int, backend: str,
+                    qcap: int | None):
+    """Featurize, bin, forest, mask and top-k over the (Q, C) grid, traced
+    as one program; returns the (Q, t_final) final ids and (Q,) used."""
+    q, c = cand.shape
+    feats = qd_features_batched(arrs, terms, mask, topics, cand,
+                                n_iter=n_iter, backend=backend, qcap=qcap)
+    model = gbrt.GBRTModel(forest, base, bin_edges, params)
+    sc = gbrt.predict(model, feats.reshape(q * c, -1)).reshape(q, c)
+    valid = (cand >= 0) & (jnp.arange(c, dtype=jnp.int32)[None, :]
+                           < k_per_query[:, None])
+    sc = jnp.where(valid, sc, -jnp.inf)
+    kk = min(t_final, c)
+    top_sc, order = jax.lax.top_k(sc, kk)
+    picks = jnp.take_along_axis(cand, order, axis=1)
+    picks = jnp.where(jnp.isfinite(top_sc), picks, -1)
+    if kk < t_final:
+        picks = jnp.pad(picks, ((0, 0), (0, t_final - kk)),
+                        constant_values=-1)
+    used = jnp.sum(valid, axis=1)
+    return jnp.where(used[:, None] > 0, picks, 0), used
+
+
 def rerank_batched(arrs: Stage2Arrays, ltr: LTRModel, terms, mask, topics,
                    cand, k_per_query, *, t_final: int = 10, n_iter: int,
                    backend: str = "jnp", qcap: int | None = None,
                    lane_need: int | None = None) -> CascadeResult:
-    """Batched Stage-2: re-rank every query's candidate grid in one array
-    program.
+    """Batched Stage-2: re-rank every query's candidate grid in one
+    compiled program and one read-back.
 
     Args:
       arrs: ``stage2_arrays`` gather tables.
@@ -78,8 +108,11 @@ def rerank_batched(arrs: Stage2Arrays, ltr: LTRModel, terms, mask, topics,
         omitted it is re-derived from ``arrs.offsets``, which costs a
         device-to-host copy of the offsets table per call.
       n_iter / backend / qcap: see ``qd_features_batched``.
+
+    Every input is cast to one fixed dtype first (int32 ids and budgets,
+    float32 mask), so callers holding int64 or int32 arrays share one
+    program per (Q, C, ``qcap``, ``t_final``).
     """
-    q, c = np.shape(cand)
     if backend != "jnp":
         # the kernel's step budget covers qcap postings a query and drops
         # blocks past it — refuse rather than return wrong features (size
@@ -94,26 +127,12 @@ def rerank_batched(arrs: Stage2Arrays, ltr: LTRModel, terms, mask, topics,
                 f"qcap={qcap} does not cover the batch's per-query posting "
                 f"total ({lane_need}); size it with "
                 f"repro.isn.backend.query_lane_budget")
-    terms = jnp.asarray(terms)
-    mask = jnp.asarray(mask)
-    cand_j = jnp.asarray(cand, jnp.int32)
-    feats = qd_features_batched(arrs, terms, mask,
-                                jnp.asarray(topics, jnp.int32), cand_j,
-                                n_iter=n_iter, backend=backend, qcap=qcap)
-    sc = gbrt.predict(ltr.model, feats.reshape(q * c, -1)).reshape(q, c)
-    valid = (cand_j >= 0) & (jnp.arange(c, dtype=jnp.int32)[None, :]
-                             < jnp.asarray(k_per_query, jnp.int32)[:, None])
-    sc = jnp.where(valid, sc, -jnp.inf)
-    kk = min(t_final, c)
-    top_sc, order = jax.lax.top_k(sc, kk)
-    picks = jnp.take_along_axis(cand_j, order, axis=1)
-    picks = jnp.where(jnp.isfinite(top_sc), picks, -1)
-    used = jnp.sum(valid, axis=1)
-    final = jnp.where(used[:, None] > 0, picks, 0)
-    if kk < t_final:
-        final = jnp.pad(final, ((0, 0), (0, t_final - kk)),
-                        constant_values=-1)
-        final = jnp.where(used[:, None] > 0, final, 0)
-    final, used = fetch(final, used)
+    model = ltr.model
+    final, used = fetch(*_rerank_program(
+        arrs, model.forest, model.base, model.bin_edges,
+        np.asarray(terms, np.int32), np.asarray(mask, np.float32),
+        np.asarray(topics, np.int32), np.asarray(cand, np.int32),
+        np.asarray(k_per_query, np.int32), params=model.params,
+        t_final=t_final, n_iter=n_iter, backend=backend, qcap=qcap))
     return CascadeResult(final=final.astype(np.int64),
                          candidates_used=used.astype(np.int64))

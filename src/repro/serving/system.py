@@ -678,7 +678,10 @@ class SearchSystem:
 
     def stage2(self, terms, mask, topics, cand, k_per_query) -> CascadeResult:
         """Batched LTR re-rank of the merged Stage-1 candidate grid (the
-        re-ranker sees global doc ids, so it is shard-agnostic)."""
+        re-ranker sees global doc ids, so it is shard-agnostic): one
+        compiled program per (batch width, grid width, lane budget
+        ``qcap``) featurizes, bins, scores, masks and takes the top
+        ``t_final``, with one read-back (``rerank_batched``)."""
         backend = resolve_backend(self.backend)
         qcap = None
         if backend != "jnp":

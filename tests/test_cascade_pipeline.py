@@ -62,17 +62,65 @@ def test_qd_features_batched_matches_loop_exactly(stage2):
         np.testing.assert_array_equal(feats[q][sel], ref)
 
 
-def test_rerank_batched_matches_loop_exactly(stage2, ltr_model):
+# (batch width Q, grid width C, t_final): the served bucket widths' range,
+# and a t_final past the grid, whose tail is -1 padding
+_RERANK_SHAPES = {
+    "q96_c48": (96, 48, 10),
+    "q7_c48": (7, 48, 10),
+    "q2_c48": (2, 48, 10),
+    "t_final_over_grid": (13, 8, 12),
+}
+
+
+@pytest.mark.parametrize("shape", list(_RERANK_SHAPES))
+def test_rerank_batched_matches_loop_exactly(stage2, ltr_model, shape):
     corpus, index, ql, arrs, n_iter, cand = stage2
+    q, c, t_final = _RERANK_SHAPES[shape]
+    cand = cand[:q, :c].copy()
+    cand[-1, c // 2:] = -1                  # ragged padding in every case
     rng = np.random.RandomState(3)
-    k_per_query = rng.randint(0, cand.shape[1] + 16, 96)
-    k_per_query[5] = 0                      # k = 0 edge case
-    a = cascade.rerank_batched(arrs, ltr_model, ql.terms, ql.mask, ql.topic,
-                               cand, k_per_query, t_final=10, n_iter=n_iter)
-    b = cascade.rerank_loop(index, corpus, ql, np.arange(96), cand,
-                            k_per_query, ltr_model, t_final=10)
+    k_per_query = rng.randint(0, c + 16, q)
+    k_per_query[5 % q] = 0                  # k = 0 edge case
+    a = cascade.rerank_batched(arrs, ltr_model, ql.terms[:q], ql.mask[:q],
+                               ql.topic[:q], cand, k_per_query,
+                               t_final=t_final, n_iter=n_iter)
+    b = cascade.rerank_loop(index, corpus, ql, np.arange(q), cand,
+                            k_per_query, ltr_model, t_final=t_final)
+    assert a.final.shape == (q, t_final)
     np.testing.assert_array_equal(a.final, b.final)
     np.testing.assert_array_equal(a.candidates_used, b.candidates_used)
+
+
+def test_rerank_batched_is_one_program(stage2, ltr_model):
+    """A fresh (Q, C) shape builds exactly one Stage-2 program, and a second
+    call at that shape, with budgets of another dtype and other values,
+    builds none: featurize, bin, forest, mask and top-k are one launch."""
+    import jax
+
+    corpus, index, ql, arrs, n_iter, cand = stage2
+    q, c = 5, 24                            # a shape no other test uses
+    built = []
+
+    def count(event, secs, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            built.append(secs)
+    jax.monitoring.register_event_duration_secs_listener(count)
+    try:
+        first = cascade.rerank_batched(
+            arrs, ltr_model, ql.terms[:q], ql.mask[:q], ql.topic[:q],
+            cand[:q, :c].astype(np.int32), np.full(q, c, np.int32),
+            n_iter=n_iter)
+        assert len(built) == 1
+        second = cascade.rerank_batched(
+            arrs, ltr_model, ql.terms[q:2 * q], ql.mask[q:2 * q],
+            ql.topic[q:2 * q], cand[q:2 * q, :c],
+            np.arange(q, dtype=np.int64) * 3, n_iter=n_iter)
+        assert len(built) == 1
+    finally:
+        jax.monitoring.unregister_event_duration_listener(count)
+    np.testing.assert_array_equal(first.candidates_used,
+                                  (cand[:q, :c] >= 0).sum(axis=1))
+    np.testing.assert_array_equal(second.candidates_used, [0, 3, 6, 9, 12])
 
 
 def test_rerank_batched_empty_candidates(stage2, ltr_model):

@@ -27,7 +27,8 @@ from repro.kernels.blockmax_score.ops import blockmax_score_tiles
 from repro.kernels.dense_topk.ops import dense_topk
 from repro.kernels.impact_accumulate.ops import impact_accumulate_tiles
 from repro.kernels.qd_feature_gather.ops import qd_feature_gather, step_budget
-from repro.ltr.ranker import Stage2Arrays, qd_features_batched
+from repro.ltr.cascade import _rerank_program
+from repro.ltr.ranker import Stage2Arrays
 
 Q, L = 64, 8                      # served batch, padded query width
 N_DOCS, TILE_D, BLOCK = 1 << 20, 128, 64
@@ -111,9 +112,11 @@ def test_qd_feature_gather_compiles(one_chip):
 
 
 def test_stage2_program_reads_postings_in_place(one_chip):
-    """The kernel-backend Stage-2 featurizer reads postings only through
-    the kernel: no lane-compaction loop and no gather of (Q, qcap) lanes
-    from the CSR's flat ``docs``/``score``."""
+    """The served Stage-2 program (featurize, bin, forest, mask and top-k
+    in one launch) still carries the ``qd_feature_gather_lanes`` kernel
+    under its stable name, and reads postings only through it: no
+    lane-compaction loop and no gather of (Q, qcap) lanes from the CSR's
+    flat ``docs``/``score``."""
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     p, n = S2_POSTINGS, S2_DOCS
@@ -125,11 +128,21 @@ def test_stage2_program_reads_postings_in_place(one_chip):
         doclen=sds((n,), jnp.float32), log1p_doclen=sds((n,), jnp.float32),
         doc_topics=sds((n, 32), jnp.float32),
         doc_topics_max=sds((n,), jnp.float32))
-    text = qd_features_batched.lower(
-        arrs, sds((S2_Q, L), jnp.int32), sds((S2_Q, L), jnp.float32),
-        sds((S2_Q,), jnp.int32), sds((S2_Q, C), jnp.int32), n_iter=20,
-        backend="pallas", qcap=S2_QCAP).compile().as_text()
-    assert "tpu_custom_call" in text
+    nodes = (S2_TREES, S2_DEPTH, 2 ** (S2_DEPTH - 1))
+    forest = T.Forest(sds(nodes, jnp.int32), sds(nodes, jnp.int32),
+                      sds((S2_TREES, 2 ** S2_DEPTH), jnp.float32))
+    text = _rerank_program.lower(
+        arrs, forest, sds((), jnp.float32),
+        sds((S2_FEATS, N_BINS - 1), jnp.float32),
+        sds((S2_Q, L), jnp.int32), sds((S2_Q, L), jnp.float32),
+        sds((S2_Q,), jnp.int32), sds((S2_Q, C), jnp.int32),
+        sds((S2_Q,), jnp.int32),
+        params=gbrt.GBRTParams(n_trees=S2_TREES, depth=S2_DEPTH,
+                               n_bins=N_BINS, loss="l2", learning_rate=0.2),
+        t_final=10, n_iter=20, backend="pallas",
+        qcap=S2_QCAP).compile().as_text()
+    assert re.search(r"%qd_feature_gather_lanes(\.\d+)? = [^\n]*"
+                     r'custom_call_target="tpu_custom_call"', text)
     assert not re.search(r" while\(", text)
     gathers = re.findall(r"= \w+\[([\d,]*)\][^\n]* gather\(", text)
     assert gathers and all(S2_QCAP not in map(int, g.split(","))
