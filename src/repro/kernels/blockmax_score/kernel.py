@@ -11,9 +11,18 @@ mirror's blocks are still fetched for skipped tiles; only their compute is
 saved.  The SAAT kernel's grid, by contrast, is budget-bounded.
 
 All lanes of one doc share the doc's pruning block, so masking a doc's
-accumulated score equals masking its lanes.  Scores are float32; the
-matmul runs at ``Precision.HIGHEST``, so scores are not rounded to
-bfloat16.
+accumulated score equals masking its lanes.
+
+Scores are float32 and stay so, yet the matmul is one bfloat16 MXU pass.
+Each tile's score row is split into three bfloat16 parts, ``hi`` (the
+score rounded to bfloat16), ``mid`` (the remainder rounded) and ``lo``
+(what is left, at most 8 significant bits), whose sum is the score bit for
+bit (``split_bf16``).  The one-hot is 0/1, which bfloat16 holds exactly,
+so every product is exact and the MXU sums them in float32: the three
+parts, stacked as ``3·QB`` rows, go through one contraction against one
+one-hot, and ``(hi + mid) + lo`` per doc is the float32 sum of its
+postings up to float32 rounding.  ``Precision.HIGHEST`` would compute the
+same sum in several MXU passes, loading the one-hot for each.
 """
 
 from __future__ import annotations
@@ -26,6 +35,17 @@ from jax.experimental import pallas as pl
 
 from repro.kernels.blocks import (TILES_PER_STEP, check_mirror, lane_chunk,
                                   pad_axis, query_rows, round_up)
+
+
+def split_bf16(x: jnp.ndarray):
+    """Split float32 ``x`` into three float32 arrays of bfloat16 values
+    with ``(hi + mid) + lo == x`` exactly: each part rounds the remainder
+    of the ones before it to bfloat16's 8 significant bits, and the
+    remainders (at most 16, then 8 significant bits) are exact in float32."""
+    hi = x.astype(jnp.bfloat16).astype(jnp.float32)
+    r = x - hi
+    mid = r.astype(jnp.bfloat16).astype(jnp.float32)
+    return hi, mid, r - mid
 
 
 def _score_kernel(qterms_ref, keep_ref, docs_ref, terms_ref, scores_ref,
@@ -45,17 +65,20 @@ def _score_kernel(qterms_ref, keep_ref, docs_ref, terms_ref, scores_ref,
         def _score(i=i, cols=cols, keep=keep):
             local = docs_ref[i:i + 1, :]          # (1, CH) tile-local, -1 pad
             tterm = terms_ref[i:i + 1, :]
-            sc = scores_ref[i:i + 1, :]
+            hi, mid, lo = split_bf16(scores_ref[i:i + 1, :])
             match = tterm == qt[:, 0:1]
             for l in range(1, qt.shape[1]):
                 match = match | (tterm == qt[:, l:l + 1])
-            v = jnp.where(match & (local >= 0), sc, 0.0)     # (QB, CH)
+            live = match & (local >= 0)                      # (QB, CH)
+            v = jnp.concatenate([jnp.where(live, x, 0.0)
+                                 for x in (hi, mid, lo)]).astype(jnp.bfloat16)
             onehot = (jax.lax.broadcasted_iota(jnp.int32, (tile_d, ch), 0)
-                      == local).astype(jnp.float32)
-            part = jax.lax.dot_general(
+                      == local).astype(jnp.bfloat16)
+            parts = jax.lax.dot_general(
                 v, onehot, (((1,), (1,)), ((), ())),
-                precision=jax.lax.Precision.HIGHEST,
-                preferred_element_type=jnp.float32)
+                preferred_element_type=jnp.float32)          # (3·QB, TILE_D)
+            qb = qt.shape[0]
+            part = (parts[:qb] + parts[qb:2 * qb]) + parts[2 * qb:]
             acc_ref[:, cols] += part * keep
 
 

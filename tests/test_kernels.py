@@ -90,6 +90,110 @@ def test_blockmax_score_matches_ref(n_docs, p, bs, survive_frac):
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=1e-4)
 
 
+def _full_mantissa(rng, n, lo_exp, hi_exp):
+    """float32 values in [2^lo_exp, 2^hi_exp) with all 24 mantissa bits
+    random (the low bit set), so no shorter float rounds them exactly."""
+    m = rng.randint(0, 1 << 23, n) | 1
+    e = rng.randint(lo_exp, hi_exp, n)
+    return ((1.0 + m / 2.0 ** 23) * 2.0 ** e).astype(np.float32)
+
+
+@pytest.mark.parametrize("lo_exp,hi_exp", [
+    (-20, -10),       # 1e-6 .. 1e-3
+    (-10, 0),
+    (0, 6),           # BM25's usual range, up to 64
+    (-20, 6),         # all of it
+])
+def test_score_split_is_exact(lo_exp, hi_exp):
+    """The kernel's three bfloat16 parts sum back to each float32 score bit
+    for bit, and each part is a bfloat16 value."""
+    from repro.kernels.blockmax_score.kernel import split_bf16
+    rng = np.random.RandomState(100 * (lo_exp + 30) + hi_exp)
+    x = _full_mantissa(rng, 1 << 16, lo_exp, hi_exp)
+    x = np.concatenate([x, np.float32([2.0 ** -20, 64.0 - 2.0 ** -18,
+                                       np.nextafter(np.float32(1), 2)])])
+    parts = split_bf16(jnp.asarray(x))
+    for p in parts:
+        np.testing.assert_array_equal(
+            np.asarray(p.astype(jnp.bfloat16).astype(jnp.float32)),
+            np.asarray(p))
+    hi, mid, lo = (np.asarray(p) for p in parts)
+    np.testing.assert_array_equal((hi + mid) + lo, x)
+    assert not np.array_equal(hi, x)      # the split is needed at all
+
+
+@pytest.mark.parametrize("q", [3, 12])
+def test_blockmax_tiles_float32_exact(q):
+    """Scores that need all 24 mantissa bits come out as the float32 sum
+    of the surviving postings: no bfloat16 rounding reaches the
+    accumulator."""
+    from repro.index.builder import pack_tiles
+    from repro.kernels.blockmax_score.ops import blockmax_score_tiles
+    rng = np.random.RandomState(q)
+    n_docs, vocab, p, tile_d, bs, L = 700, 24, 6000, 128, 64, 8
+    pairs = rng.permutation(n_docs * vocab)[:p]          # unique (term, doc)
+    terms = (pairs // n_docs).astype(np.int32)
+    docs = (pairs % n_docs).astype(np.int32)
+    scores = _full_mantissa(rng, p, -12, 5)
+    td, tt, (ts,), _ = pack_tiles(docs, terms, [(scores, 0.0, np.float32)],
+                                  n_docs, tile_d)
+    qterms = np.full((q, L), -1, np.int32)
+    for i in range(q):
+        qterms[i, :6] = rng.choice(vocab, 6, replace=False)
+    n_blocks = -(-n_docs // bs)
+    survive = rng.random_sample((q, n_blocks)) < 0.6
+    acc = np.asarray(blockmax_score_tiles(
+        jnp.asarray(td), jnp.asarray(tt), jnp.asarray(ts),
+        jnp.asarray(qterms), jnp.asarray(survive), tile_d=tile_d,
+        block_size=bs, n_blocks=n_blocks, interpret=True)
+    ).reshape(q, -1)[:, :n_docs]
+    for i in range(q):
+        keep = np.isin(terms, qterms[i][qterms[i] >= 0]) \
+            & survive[i][docs // bs]
+        ref = np.zeros(n_docs, np.float32)
+        np.add.at(ref, docs[keep], scores[keep])
+        assert (np.bincount(docs[keep], minlength=n_docs) > 2).any()
+        np.testing.assert_allclose(acc[i], ref, rtol=1e-6)
+
+
+def _walk_eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, ClosedJaxpr):
+                    yield from _walk_eqns(sub.jaxpr)
+                elif isinstance(sub, Jaxpr):
+                    yield from _walk_eqns(sub)
+
+
+def test_blockmax_kernel_contracts_in_one_bf16_pass():
+    """The scoring kernel's contractions take bfloat16 operands into
+    float32 results at default precision: no ``Precision.HIGHEST``
+    (several MXU passes a contraction) comes back unnoticed."""
+    from repro.kernels.blockmax_score.ops import blockmax_score_tiles
+    nt, cap, q, L, nb = 8, 256, 5, 8, 16
+    args = (jnp.zeros((nt, cap), jnp.int32), jnp.zeros((nt, cap), jnp.int32),
+            jnp.zeros((nt, cap), jnp.float32), jnp.zeros((q, L), jnp.int32),
+            jnp.zeros((q, nb), bool))
+    jaxpr = jax.make_jaxpr(lambda *a: blockmax_score_tiles(
+        *a, tile_d=128, block_size=64, n_blocks=nb, interpret=True))(*args)
+    calls = [e for e in _walk_eqns(jaxpr.jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert [e.params["name"] for e in calls] == ["blockmax_score_batched"]
+    dots = [e for e in _walk_eqns(calls[0].params["jaxpr"])
+            if e.primitive.name == "dot_general"]
+    assert dots
+    for e in dots:
+        assert [v.aval.dtype for v in e.invars] == [jnp.bfloat16] * 2
+        assert [v.aval.dtype for v in e.outvars] == [jnp.float32]
+        prec = e.params["precision"]
+        assert jax.lax.Precision.HIGHEST not in (
+            prec if isinstance(prec, tuple) else (prec,)), prec
+
+
 # ---------------------------------------------------------------------------
 # flash attention
 # ---------------------------------------------------------------------------

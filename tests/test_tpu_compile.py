@@ -7,7 +7,8 @@ against a described ``v5e:2x2`` topology — no chip attached — at the
 widths ``chip_smoke.py`` serves: a 2^20-doc shard's bucketed mirror, the
 dense embedding width, and a 64-query batch; Stage-2 at the benchmark
 cell's shapes (a 32-query batch at its largest lane budget over a
-ClueWeb09B shard's 10.4M postings).  Each compile takes seconds; one that
+ClueWeb09B shard's 10.4M postings), and BMW's kernel also at that cell's
+8-query batch over the shard's mirror.  Each compile takes seconds; one that
 takes minutes means a kernel's lane axis is no longer split into
 fixed-width chunks.
 """
@@ -41,6 +42,7 @@ EMB_D, DENSE_TILE = 32, 512       # dense embedding width, doc tile
 S2_Q, S2_QCAP = 32, 15360
 S2_POSTINGS, S2_VOCAB, S2_DOCS = 10_368_464, 2_328_791, 32768
 S2_ROWS = -(-(S2_POSTINGS + 1) // 1024) * 8   # (rows, 128) posting tables
+CELL_TILE_CAP = 45056             # lane capacity of that shard's tiles
 # the forests: Stage-2 48 trees of depth 4 over 8 features, Stage-0 three
 # stacked ensembles of 48 trees of depth 5 over 147 features
 S2_TREES, S2_DEPTH, S2_FEATS = 48, 4, 8
@@ -98,6 +100,22 @@ def test_blockmax_score_compiles(one_chip):
              ((N_TILES, TILE_CAP), jnp.int32),
              ((N_TILES, TILE_CAP), jnp.float32), ((Q, L), jnp.int32),
              ((Q, n_blocks), jnp.bool_))
+
+
+def test_blockmax_score_compiles_one_sublane_group(one_chip):
+    """An 8-query batch at the benchmark cell's mirror: one sublane group of
+    query rows, so the kernel's 24 stacked bfloat16 rows (three score parts)
+    are not a whole (16, 128) bfloat16 tile."""
+    q, n_tiles, n_blocks = 8, S2_DOCS // TILE_D, S2_DOCS // BLOCK
+
+    def fn(td, tt, ts, qt, survive):
+        return blockmax_score_tiles(td, tt, ts, qt, survive, tile_d=TILE_D,
+                                    block_size=BLOCK, n_blocks=n_blocks,
+                                    interpret=False)
+    mirror = (n_tiles, CELL_TILE_CAP)
+    _compile(fn, "blockmax_score_batched", one_chip, (mirror, jnp.int32),
+             (mirror, jnp.int32), (mirror, jnp.float32), ((q, L), jnp.int32),
+             ((q, n_blocks), jnp.bool_))
 
 
 def test_qd_feature_gather_compiles(one_chip):
