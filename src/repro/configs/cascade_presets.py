@@ -41,6 +41,11 @@ live_ingest    The paper point serving while the collection mutates: a
                is full — and the feed throttles strictly before queries
                degrade.  Adaptation frozen like ``cached``: ingest bumps
                the cache epoch, stable routes keep replay deterministic.
+msmarco_passage
+               Passage re-ranking at the MS MARCO task's depth: the
+               paper point's routing and 200 ms budget with Stage-1
+               retrieving 1,000 candidates and Stage-2 re-ranking them
+               to a top 10 (the task scores MRR@10).
 hybrid_fusion  The paper point with the dense Stage-1 modality enabled:
                Stage-0 dispatches each query lexical / dense / both+fused
                from its predicted traversal time, both-routed lists merge
@@ -117,6 +122,21 @@ def _quality() -> CascadeSpec:
         # effectiveness-first: never degrade the grid — shed instead
         online=OnlineSpec(max_batch=16, batch_deadline_us=2.0,
                           admission=True, degrade=False),
+    )
+
+
+def _msmarco_passage() -> CascadeSpec:
+    # k_serve above the serving tile (128 docs) takes Stage-1 through the
+    # deep top-k select (repro.kernels.topk)
+    return CascadeSpec(
+        name="msmarco_passage",
+        routing=RoutingSpec(algorithm=2, budget=200.0, rho_max=1 << 18,
+                            hedge_deadline=0.5, late_rho=4096,
+                            adapt_every=1, calibrate=True),
+        stage2=Stage2Spec(enabled=True, k_serve=1000, t_final=10),
+        deploy=DeploySpec(n_shards=1, replicas=2),
+        online=OnlineSpec(max_batch=32, batch_deadline_us=5.0,
+                          admission=True, degrade=True),
     )
 
 
@@ -216,6 +236,7 @@ PRESETS = {
     "paper_200ms": _paper_200ms,
     "throughput": _throughput,
     "quality": _quality,
+    "msmarco_passage": _msmarco_passage,
     "stage1_only": _stage1_only,
     "fault_tolerant": _fault_tolerant,
     "cached": _cached,

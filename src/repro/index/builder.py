@@ -45,11 +45,14 @@ class InvertedIndex:
     impact: np.ndarray             # (P,) uint8 quantized bm25
     quant_scale: float             # impact -> score scale (score≈imp/255*scale)
 
-    # block-max structure (document-ordered)
+    # block-max structure (document-ordered): a term-major CSR over the
+    # (term, block) pairs that hold postings, blocks ascending in a term
     block_size: int
     n_blocks: int
-    block_max: np.ndarray          # (V, n_blocks) uint8, 0 = term absent
-    block_count: np.ndarray        # (V, n_blocks) uint16 postings per block
+    bm_offsets: np.ndarray         # (V+1,) int64 into the block arrays
+    bm_block: np.ndarray           # (E,) int32 doc-block id
+    bm_max: np.ndarray             # (E,) uint8 largest impact in the block
+    bm_count: np.ndarray           # (E,) int32 postings in the block
 
     # impact-ordered layout (per-term descending impact)
     docs_imp: np.ndarray           # (P,)
@@ -227,6 +230,23 @@ def impact_order_layout(term: np.ndarray, doc: np.ndarray,
     return order, level_cum
 
 
+def block_csr(term: np.ndarray, doc: np.ndarray, block_size: int,
+              n_terms: int):
+    """Term-major CSR over the (term, doc-block) pairs that hold postings,
+    for (term, doc)-sorted postings (so each pair's postings are
+    contiguous): ``(offsets (V+1,) int64, block ids (E,) int32, postings per
+    pair (E,) int32, first posting of each pair (E,))``; a per-posting
+    value's block maxima are ``np.maximum.reduceat(value, first)``."""
+    p = len(term)
+    blk = (doc // block_size).astype(np.int64)
+    first = np.flatnonzero(np.r_[p > 0, (term[1:] != term[:-1])
+                                 | (blk[1:] != blk[:-1])])
+    offsets = np.zeros(n_terms + 1, np.int64)
+    np.cumsum(np.bincount(term[first], minlength=n_terms), out=offsets[1:])
+    return (offsets, blk[first].astype(np.int32),
+            np.diff(np.r_[first, p]).astype(np.int32), first)
+
+
 def assemble_index(term: np.ndarray, doc: np.ndarray, tf: np.ndarray,
                    doclen: np.ndarray, vocab: int, *,
                    block_size: int = 64, n_levels: int = 255,
@@ -271,21 +291,13 @@ def assemble_index(term: np.ndarray, doc: np.ndarray, tf: np.ndarray,
     bm25_sc = sims[:, 1].astype(np.float32)
     impact, qmax = scoring.quantize_impacts(bm25_sc, n_levels, smax=smax)
 
-    # ---- block-max structure ----
+    # ---- block-max structure: CSR over the (term, block) pairs ----
     n_blocks = (n + block_size - 1) // block_size
-    block_max = np.zeros((v, n_blocks), np.uint8)
-    block_count = np.zeros((v, n_blocks), np.uint16)
-    if p:
-        blk = (doc // block_size).astype(np.int64)
-        key = term.astype(np.int64) * n_blocks + blk
-        # postings are (term, doc)-sorted => (term, block) groups contiguous
-        group_start = np.flatnonzero(np.r_[True, key[1:] != key[:-1]])
-        gmax = np.maximum.reduceat(impact.astype(np.int32), group_start)
-        gcount = np.diff(np.r_[group_start, len(key)])
-        gkey = key[group_start]
-        block_max.reshape(-1)[gkey] = gmax.astype(np.uint8)
-        block_count.reshape(-1)[gkey] = \
-            np.minimum(gcount, 65535).astype(np.uint16)
+    bm_offsets, bm_block, bm_count, start = block_csr(term, doc, block_size,
+                                                      v)
+    bm_max = (np.maximum.reduceat(impact, start) if p
+              else np.zeros(0, np.uint8))
+    del start
 
     # ---- impact-ordered layout ----
     order, level_cum = impact_order_layout(term, doc, impact, v)
@@ -313,7 +325,8 @@ def assemble_index(term: np.ndarray, doc: np.ndarray, tf: np.ndarray,
         offsets=offsets, docs=doc, tf=tf.astype(np.int32),
         bm25_score=bm25_sc, impact=impact, quant_scale=qmax,
         block_size=block_size, n_blocks=n_blocks,
-        block_max=block_max, block_count=block_count,
+        bm_offsets=bm_offsets, bm_block=bm_block, bm_max=bm_max,
+        bm_count=bm_count,
         docs_imp=docs_imp, imp_sorted=imp_sorted, level_cum=level_cum,
         term_stats=term_stats,
         stoplist=np.asarray(stoplist, np.int64),

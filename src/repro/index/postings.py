@@ -31,7 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.index.builder import InvertedIndex, impact_order_layout, pack_tiles
+from repro.index.builder import (InvertedIndex, block_csr,
+                                 impact_order_layout, pack_tiles)
 
 
 class IndexShardSpec(NamedTuple):
@@ -157,23 +158,18 @@ def shard_from_index(index: InvertedIndex, doc_lo: int = 0,
         docs_imp = d[order]
         imp = im[order]
 
-    # sparse block-max
-    if len(d):
-        blk = (d // bs).astype(np.int64)
-        key = t.astype(np.int64) * (1 << 32) + blk
-        start = np.flatnonzero(np.r_[True, key[1:] != key[:-1]])
-        b_term = t[start]
-        b_id = blk[start].astype(np.int32)
-        b_max = np.maximum.reduceat(s, start).astype(np.float32)
-        b_cnt = np.diff(np.r_[start, len(key)]).astype(np.int32)
+    # sparse block-max: a shard over the whole index takes the index's own
+    # CSR, a doc-range shard groups its postings; the block maxima are the
+    # exact float scores either way
+    if n_local == index.n_docs:
+        bm_offsets, b_id, b_cnt = index.bm_offsets, index.bm_block, \
+            index.bm_count
+        start = np.r_[0, np.cumsum(b_cnt)[:-1]].astype(np.int64)
     else:
-        b_term = np.zeros(0, np.int64)
-        b_id = np.zeros(0, np.int32)
-        b_max = np.zeros(0, np.float32)
-        b_cnt = np.zeros(0, np.int32)
-    bm_df = np.bincount(b_term, minlength=v)
-    bm_offsets = np.zeros(v + 1, np.int64)
-    np.cumsum(bm_df, out=bm_offsets[1:])
+        bm_offsets, b_id, b_cnt, start = block_csr(t, d, bs, v)
+    b_max = (np.maximum.reduceat(s, start).astype(np.float32) if len(d)
+             else np.zeros(0, np.float32))
+    bm_df = np.diff(bm_offsets)
 
     if pad_postings is not None:
         docs = _pad_to(docs, pad_postings, 0)

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.index.postings import IndexShard, IndexShardSpec
+from repro.kernels.topk import takes_deep_select
 from repro.kernels.topk import topk_from_tiles  # noqa: F401  (re-export)
 
 BACKENDS = ("pallas", "interpret", "jnp")
@@ -48,11 +49,19 @@ def query_lane_budget(df, terms, mask, round_to: int = 1024,
     into a dense (Q, qcap) lane buffer before the fused scatter, so its cost
     tracks the *actual* postings of the batch instead of L x max_df padding.
     Callers size qcap from the batch they are about to serve (like length
-    bucketing in LM serving); rounding bounds jit recompiles.
+    bucketing in LM serving); rounding bounds jit recompiles.  The budget
+    is a whole number of ``round_to`` blocks, rounded up to the geometric
+    series 1, 2, 3, 4, 6, 8, 12, ... (powers of two and three halves of
+    them): at most 1.5 times what the batch needs, and a few dozen budgets
+    cover every batch of a collection.
     """
     eff = np.asarray(df)[np.asarray(terms)] * (np.asarray(mask) > 0)
     need = int(eff.sum(axis=1).max()) if eff.size else 0
-    return max(-(-max(need, 1) // round_to) * round_to, floor)
+    blocks = -(-max(need, 1) // round_to)
+    step = 1 << (blocks - 1).bit_length()          # next power of two
+    if step >= 4 and blocks <= 3 * step // 4:
+        step = 3 * step // 4
+    return max(step * round_to, floor)
 
 
 def resolve_backend(backend: str | None) -> str:
@@ -164,12 +173,23 @@ class Segment(NamedTuple):
 class SegmentLists(NamedTuple):
     """One engine's fan-out over a segment list: the per-segment top-k
     (ids global), their merge ((ids, scores), or None for one segment and
-    no drop) and the per-segment work counts (and blocks, for BMW)."""
+    no drop), the per-segment work counts (and blocks, for BMW) and whether
+    a segment's top-k took the deep select."""
     scores: list
     ids: list
     merged: tuple | None
     work: list
     blocks: list | None = None
+    deep_select: bool = False
+
+
+def deep_select_ran(segments, k: int, backend: str) -> bool:
+    """Whether an engine's fan-out at depth ``k`` ranks some segment with
+    the deep select: the kernel backends take ``topk_from_tiles`` over the
+    segment's tiles (the ``jnp`` backends rank the whole row at once)."""
+    return backend != "jnp" and any(
+        takes_deep_select(k, g.spec.tile_d, g.spec.n_tiles * g.spec.tile_d)
+        for g in segments)
 
 
 def merge_segments(segments, results, k: int, drop=None):

@@ -37,9 +37,10 @@ posting ranges into a (Q, qcap) lane buffer before its fused scatter, so
 scatter traffic tracks the batch's actual postings rather than L·max_df
 padding.  On the kernel backends top-k is the tiled hierarchical merge
 (per-tile top-k over the (Q, n_tiles, TILE_D) accumulator tiles, then a
-merge over per-tile candidates) — per-query traffic is O(surviving tiles ·
-TILE_D), not O(n_docs); the dense jnp path keeps XLA's native batched
-top-k, which is faster on CPU.
+merge over per-tile candidates), or past one tile's depth (k > TILE_D) the
+deep select (``repro.kernels.topk``), which sorts the n_docs/8 maxima of
+doc groups and the 8·k members of the k best groups; the dense jnp path
+keeps XLA's native batched top-k, which is faster on CPU.
 
 ``daat_serve_laxmap`` preserves the original one-query-at-a-time
 ``lax.map`` + dense scatter-add reference; the parity tests and the
@@ -64,8 +65,8 @@ import jax.numpy as jnp
 from repro.index.postings import IndexShard
 from repro.isn.backend import (SegmentLists, compact_lanes,
                                map_query_blocks, merge_segments,
-                               query_lane_budget, resolve_backend,
-                               topk_from_tiles)
+                               deep_select_ran, query_lane_budget,
+                               resolve_backend, topk_from_tiles)
 from repro.kernels.blockmax_score.ops import blockmax_score_tiles
 
 
@@ -327,4 +328,5 @@ def daat_serve_segments(segments, terms, mask, theta, *, k: int,
                           k=k, cap=sp.max_df, bcap=sp.max_blocks_per_term,
                           qcap=qcap, tile_d=sp.tile_d, backend=backend))
     return SegmentLists(*merge_segments(segments, res, k, drop),
-                        [r.work for r in res], [r.blocks for r in res])
+                        [r.work for r in res], [r.blocks for r in res],
+                        deep_select_ran(segments, k, backend))

@@ -68,6 +68,21 @@ def _batch_accumulate(index, terms, mask, rows, impact_ordered=False,
     return acc.reshape(b, n), int(keys.shape[0])
 
 
+def _block_bounds(index, terms):
+    """Per-block summed impact upper bound (float32, in score units) and
+    posting count of a query's terms, read from the block-max CSR."""
+    lo, hi = index.bm_offsets[terms], index.bm_offsets[terms + 1]
+    sel = np.concatenate([np.arange(a, b) for a, b in zip(lo, hi)] + [[]])
+    sel = sel.astype(np.int64)
+    blk = index.bm_block[sel]
+    # integer sums below 2^24: exact in either float width
+    ub = np.bincount(blk, weights=index.bm_max[sel],
+                     minlength=index.n_blocks).astype(np.float32)
+    cnt = np.bincount(blk, weights=index.bm_count[sel],
+                      minlength=index.n_blocks).astype(np.int64)
+    return ub * (index.quant_scale / 255.0), cnt
+
+
 def _topk_ids(acc: np.ndarray, k: int):
     """Row-wise top-k (ids desc by score). acc: (B, N)."""
     k = min(k, acc.shape[1])
@@ -136,20 +151,18 @@ def bmw_scores(index, terms, mask, rows, k, theta: float = 1.0):
     Returns (scores (B,N), work postings, surviving blocks per query).
     """
     n, bs, nb = index.n_docs, index.block_size, index.n_blocks
-    scale = index.quant_scale / 255.0
     k_arr = np.broadcast_to(np.asarray(k), (len(rows),))
 
     accs, works, blocks_touched = [], [], []
     for qi, q in enumerate(rows):
         k = int(k_arr[qi])
         t = terms[q][mask[q] > 0]
-        ub = index.block_max[t].astype(np.float32).sum(axis=0) * scale  # (nb,)
-        cnt = index.block_count[t].astype(np.int64)                     # (L, nb)
+        ub, cnt = _block_bounds(index, t)                         # (nb,)
         # phase 1: walk blocks in descending upper-bound order until the
         # heap can plausibly be full (>= 2k candidate docs seen), so τ is a
         # genuine k-th-best lower bound rather than 0
         order = np.argsort(-ub, kind="stable")
-        cand_docs = np.minimum(cnt.sum(axis=0), bs)[order]
+        cand_docs = np.minimum(cnt, bs)[order]
         need = int(np.searchsorted(np.cumsum(cand_docs), 2 * k)) + 1
         phase1 = order[:min(max(need, 4), nb)]
         in_p1 = np.zeros(nb, bool)
@@ -163,7 +176,7 @@ def bmw_scores(index, terms, mask, rows, k, theta: float = 1.0):
 
         survive = (ub > theta * tau) | in_p1
         acc = np.bincount(d, weights=np.where(survive[blk], w, 0.0), minlength=n)
-        works.append(int(cnt[:, survive].sum()))
+        works.append(int(cnt[survive].sum()))
         blocks_touched.append(int(survive.sum()))
         accs.append(acc)
     return np.stack(accs), np.asarray(works), np.asarray(blocks_touched)

@@ -25,9 +25,10 @@ Queries are served as a batch through a backend switch
 * ``"jnp"`` — vectorized batched gather of the per-term impact-ordered
   prefixes plus one fused scatter; identical results on any host.
 
-Top-k is the tiled hierarchical merge from ``repro.isn.backend`` rather
-than a full-collection ``lax.top_k``, ranked on packed (score, doc) keys so
-equal scores go to the lower doc id on every backend.
+Top-k is the tiled hierarchical merge from ``repro.isn.backend`` (the deep
+select past one tile's depth) rather than a full-collection ``lax.top_k``,
+ranked on packed (score, doc) keys so equal scores go to the lower doc id
+on every backend.
 ``saat_serve_laxmap`` preserves the original one-query-at-a-time pipeline
 as parity oracle and benchmark baseline.  Accumulation is integer, so all
 backends agree bit-exactly.
@@ -43,8 +44,9 @@ import jax.numpy as jnp
 
 from repro.index.postings import IndexShard
 from repro.isn.backend import (SegmentLists, compact_lanes,
-                               map_query_blocks, merge_segments,
-                               resolve_backend, topk_from_tiles)
+                               deep_select_ran, map_query_blocks,
+                               merge_segments, resolve_backend,
+                               topk_from_tiles)
 from repro.kernels.impact_accumulate.ops import impact_accumulate_tiles
 
 
@@ -196,10 +198,12 @@ def saat_serve_segments(segments, terms, mask, rhos, *, k: int, cap: int,
     bool) masks lost slots out of the merge.  ``engine`` replaces the
     per-segment ``saat_serve`` (same signature).
     """
+    backend = resolve_backend(backend)
     engine = engine or saat_serve
     terms, mask = jnp.asarray(terms), jnp.asarray(mask)
     res = [engine(g.shard, terms, mask, rho, n_docs=g.spec.n_docs, k=k,
                   cap=cap, tile_d=g.spec.tile_d, backend=backend)
            for g, rho in zip(segments, rhos)]
     return SegmentLists(*merge_segments(segments, res, k, drop),
-                        [r.work for r in res])
+                        [r.work for r in res], None,
+                        deep_select_ran(segments, k, backend))

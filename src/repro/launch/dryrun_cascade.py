@@ -29,8 +29,8 @@ Post-build (``index=`` given — strictly more accurate, same schema):
   table** (``level_cum``) to the same global level cut the serving system
   uses — the exact posting count the traversal would touch, instead of
   the ``min(ρ, mass)`` ceiling;
-* BMW blocks come from the real block-max structure (``block_count > 0``
-  per term) instead of the perfectly-packed ``mass / block_size``
+* BMW blocks come from the real block-max structure (the blocks each
+  term's CSR row holds) instead of the perfectly-packed ``mass / block_size``
   estimate (a lower bound — the real spread is wider).
 
 Either way, scatter-gather splits work uniformly across ``n_shards``
@@ -104,8 +104,7 @@ class WorkProxies:
     def from_index(cls, index, spec: CascadeSpec) -> "WorkProxies":
         return cls(index.df, index.block_size,
                    level_cum=np.asarray(index.level_cum),
-                   blocks_per_term=(np.asarray(index.block_count) > 0)
-                   .sum(axis=1))
+                   blocks_per_term=np.diff(index.bm_offsets))
 
     @property
     def post_build(self) -> bool:

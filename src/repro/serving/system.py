@@ -250,6 +250,9 @@ class SearchSystem:
         }
         self._debug_shard_lists = None   # tests: set to [] to capture the
                                          # per-shard candidate lists
+        # Stage-1 rows served through the deep top-k select (the engines
+        # report it: SegmentLists.deep_select); 0 wherever k_serve <= tile_d
+        self._stage1_counters = {"deep_select_rows": 0}
         self._batches = 0
         self._last_stats: dict = {}
         self._budget_reserve = self._attribute_budget(self.budget, None)
@@ -658,6 +661,8 @@ class SearchSystem:
                     k=self.k_serve, cap=int(self.sched.cfg.rho_max),
                     backend=self.backend, drop=dr, engine=saat_serve)
                 work, _ = gather(rows, out)
+                self._stage1_counters["deep_select_rows"] += \
+                    len(rows) * out.deep_select
                 stats["jass_pad_rows"] += pad
                 stats["jass_postings"] += int(sum(w.sum() for w in work))
                 t_shards[:, rows] = self._shard_times(work)
@@ -671,6 +676,8 @@ class SearchSystem:
                     k=self.k_serve, backend=self.backend, drop=dr,
                     engine=daat_serve)
                 work, blocks = gather(rows, out)
+                self._stage1_counters["deep_select_rows"] += \
+                    len(rows) * out.deep_select
                 stats["bmw_pad_rows"] += pad
                 t_shards[:, rows] = self._shard_times(work, blocks)
                 t_bmw[rows] = self.cost.gather_time(t_shards[:, rows])
@@ -1699,6 +1706,8 @@ class SearchSystem:
             reg.counter("scheduler", key=k).set_total(v)
         for k, v in self._fault_counters.items():
             reg.counter("faults", key=k).set_total(v)
+        for k, v in self._stage1_counters.items():
+            reg.counter("stage1", key=k).set_total(v)
         reg.gauge("faults", key="clock").set(self._clock)
         for k, v in self._ingest_counters.items():
             reg.counter("ingest", key=k).set_total(v)
@@ -1954,10 +1963,12 @@ class SearchSystem:
             self._export_metrics()
             snap = tel.registry.snapshot()
             scheduler = legacy_stats_view(snap, "scheduler")
+            stage1 = legacy_stats_view(snap, "stage1")
             fault_ctr = legacy_stats_view(snap, "faults")
             ingest = legacy_stats_view(snap, "ingest")
         else:
             scheduler = dict(self.sched.stats)
+            stage1 = dict(self._stage1_counters)
             fault_ctr = dict(self._fault_counters)
             fault_ctr["clock"] = self._clock
             ingest = None
@@ -1968,6 +1979,7 @@ class SearchSystem:
             "replicas": self.cascade_spec.deploy.replicas,
             "batches": self._batches,
             "scheduler": scheduler,
+            "stage1": stage1,
             "budget": {"total": self.budget,
                        "reserve": dict(self._budget_reserve),
                        "enforce": self.sched.cfg.enforce_budget,

@@ -54,7 +54,8 @@ def test_spec_validation_rejects_bad_values():
 def test_preset_registry_complete():
     assert set(PRESETS) == {"paper_200ms", "throughput", "quality",
                             "stage1_only", "fault_tolerant", "cached",
-                            "live_ingest", "hybrid_fusion"}
+                            "live_ingest", "hybrid_fusion",
+                            "msmarco_passage"}
     for name in PRESETS:
         spec = get_preset(name)
         assert spec.name == name
@@ -63,6 +64,10 @@ def test_preset_registry_complete():
     assert get_preset("throughput").routing.enable_hedging is False
     assert (get_preset("quality").stage2.k_serve
             > get_preset("throughput").stage2.k_serve)
+    marco = get_preset("msmarco_passage")
+    assert (marco.stage2.k_serve, marco.stage2.t_final) == (1000, 10)
+    assert marco.routing.algorithm == 2 and marco.routing.budget == 200.0
+    assert marco.online.max_batch == 32
     with pytest.raises(ValueError):
         get_preset("no_such_preset")
     # overrides replace whole nodes and re-validate

@@ -264,6 +264,31 @@ def test_stats_compat_view_matches_legacy(fitted):
     assert s_on["scheduler"] and s_on["n_shards"] == s_off["n_shards"]
 
 
+def test_stage1_deep_select_counter_in_snapshot(fitted):
+    """``stage1{key="deep_select_rows"}`` counts the Stage-1 rows a kernel
+    backend serves through the deep top-k select: 0 at a depth within one
+    accumulator tile, every routed row past it; ``stats()`` reports the
+    same section with telemetry on or off."""
+    corpus, index, ql, _, _ = fitted
+    key = 'stage1{key="deep_select_rows"}'
+    shallow = _system(fitted, telemetry=TEL)
+    shallow.serve(ql.terms[:8], ql.mask[:8], ql.topic[:8])
+    assert shallow.snapshot()["counters"][key] == 0
+    assert shallow.stats()["stage1"] == {"deep_select_rows": 0}
+    spec = dataclasses.replace(
+        shallow.cascade_spec, backend=BackendSpec(backend="interpret"),
+        stage2=dataclasses.replace(shallow.cascade_spec.stage2,
+                                   k_serve=200))
+    deep = [build_system(dataclasses.replace(spec, telemetry=tel), index,
+                         corpus=corpus, models=shallow.models,
+                         ltr=shallow.ltr)
+            for tel in (TEL, TelemetrySpec())]
+    for sysm in deep:
+        sysm.serve(ql.terms[:8], ql.mask[:8], ql.topic[:8])
+        assert sysm.stats()["stage1"] == {"deep_select_rows": 8}
+    assert deep[0].snapshot()["counters"][key] == 8
+
+
 def test_offline_snapshot_contents_and_determinism(fitted):
     """The snapshot exports per-stage quantiles + counters, and two
     same-seed runs render byte-identical JSON."""

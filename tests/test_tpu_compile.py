@@ -1,4 +1,4 @@
-"""AOT compiles of the four serving kernels for a described TPU v5e chip.
+"""AOT compiles of the serving kernels for a described TPU v5e chip.
 
 Interpret-mode tests cannot see what the TPU compiler refuses (block
 shapes off the (8, 128) tiling, VMEM overflow, primitives Mosaic cannot
@@ -8,7 +8,8 @@ widths ``chip_smoke.py`` serves: a 2^20-doc shard's bucketed mirror, the
 dense embedding width, and a 64-query batch; Stage-2 at the benchmark
 cell's shapes (a 32-query batch at its largest lane budget over a
 ClueWeb09B shard's 10.4M postings), and BMW's kernel also at that cell's
-8-query batch over the shard's mirror.  Each compile takes seconds; one that
+8-query batch over the shard's mirror; the deep top-k select at the MS
+MARCO cell's depth and shard.  Each compile takes seconds; one that
 takes minutes means a kernel's lane axis is no longer split into
 fixed-width chunks.
 """
@@ -28,6 +29,7 @@ from repro.kernels.blockmax_score.ops import blockmax_score_tiles
 from repro.kernels.dense_topk.ops import dense_topk
 from repro.kernels.impact_accumulate.ops import impact_accumulate_tiles
 from repro.kernels.qd_feature_gather.ops import qd_feature_gather, step_budget
+from repro.kernels.topk import topk_from_tiles
 from repro.ltr.cascade import _rerank_program
 from repro.ltr.ranker import Stage2Arrays
 
@@ -116,6 +118,28 @@ def test_blockmax_score_compiles_one_sublane_group(one_chip):
     _compile(fn, "blockmax_score_batched", one_chip, (mirror, jnp.int32),
              (mirror, jnp.int32), (mirror, jnp.float32), ((q, L), jnp.int32),
              ((q, n_blocks), jnp.bool_))
+
+
+@pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32])
+def test_deep_topk_select_compiles(one_chip, dtype):
+    """Stage-1's top 1,000 over the MS MARCO cell's 2^18-doc accumulator
+    (2,048 tiles) for a 32-query batch, on JASS's packed int32 keys and
+    BMW's float32 scores: every sort in the program is the deep select's
+    (its ops carry ``deep_topk_select`` in their op names, which the
+    benchmark's select metrics read), and none sorts a whole row."""
+    n_tiles, k = (1 << 18) // TILE_D, 1000
+
+    def fn(acc):
+        return topk_from_tiles(acc, k, max_score=L * 255
+                               if dtype == jnp.int32 else None)
+    arg = jax.ShapeDtypeStruct((S2_Q, n_tiles, TILE_D), dtype,
+                               sharding=one_chip)
+    text = jax.jit(fn).lower(arg).compile().as_text()
+    sorts = re.findall(r"%sort\S* = \(?(\w+)\[(\d+),(\d+)\][^\n]*", text)
+    assert sorts
+    for line in re.findall(r"%sort\S* = [^\n]*", text):
+        assert 'op_name="jit(fn)/jit(deep_topk_select)/' in line, line
+    assert all(int(n) < n_tiles * TILE_D for _, _, n in sorts), sorts
 
 
 def test_qd_feature_gather_compiles(one_chip):
