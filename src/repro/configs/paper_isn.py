@@ -29,7 +29,7 @@ CONFIG = ISNConfig()
 
 REDUCED = ISNConfig(
     name="paper-isn-reduced", n_docs=8192, vocab=4096,
-    postings_per_shard=750_000, block_entries_per_shard=350_000,
+    postings_per_shard=750_000, block_entries_per_shard=350_208,
     n_levels=256, block_size=64, k_max=128, rho_max=4096, query_len=8,
     queries_per_step=32, tile_d=128, tile_cap=16_384,
 )

@@ -7,7 +7,8 @@ An ISN holds one *document shard* of the corpus in HBM, in three mirrors:
   the ρ budget resolves to per-term prefixes in O(levels);
 * document-ordered arrays for DAAT (BMW) — per-term postings sorted by
   docid with exact scores, plus a *sparse* per-term block-max structure
-  (term-major CSR of (block_id, block_max, block_count)) — dense
+  (term-major CSR of (block_id, block_max, block_count), padded to whole
+  1,024-entry tiles that the block-bounds kernel reads in place) — dense
   (V × n_blocks) does not scale to 2M-term vocabularies;
 * a **bucketed (doc-tile-major) mirror** feeding the batched Pallas serving
   kernels — every posting pre-tiled at index-build time into the
@@ -33,6 +34,7 @@ import numpy as np
 
 from repro.index.builder import (InvertedIndex, block_csr,
                                  impact_order_layout, pack_tiles)
+from repro.kernels.blocks import CSR_BLOCK, round_up
 
 
 class IndexShardSpec(NamedTuple):
@@ -40,7 +42,7 @@ class IndexShardSpec(NamedTuple):
     vocab: int
     n_postings: int        # padded postings count
     n_blocks: int          # doc blocks in this shard
-    n_block_entries: int   # padded (term, block) entries
+    n_block_entries: int   # (term, block) entries, whole 1,024-entry tiles
     n_levels: int
     block_size: int
     max_df: int            # static cap for per-term gathers
@@ -179,6 +181,12 @@ def shard_from_index(index: InvertedIndex, doc_lo: int = 0,
         b_id = _pad_to(b_id, pad_postings, 0)
         b_max = _pad_to(b_max, pad_postings, 0.0)
         b_cnt = _pad_to(b_cnt, pad_postings, 0)
+    # whole 1,024-entry tiles: the BMW block-bounds kernel views the CSR's
+    # columns as (rows, 128) tables in place
+    n_bm = round_up(max(len(b_id), 1), CSR_BLOCK)
+    b_id = _pad_to(b_id, n_bm, 0)
+    b_max = _pad_to(b_max, n_bm, 0.0)
+    b_cnt = _pad_to(b_cnt, n_bm, 0)
 
     # bucketed doc-tile-major mirror for the batched serving kernels
     tile_docs, tile_terms, (tile_scores, tile_imps), tcap = \

@@ -12,7 +12,10 @@ Queries are served as a batch, not one at a time: block bounds and the
 phase-1 selection are vmapped, and the scoring hot loop dispatches through
 a backend switch (see ``repro.isn.backend``):
 
-* ``"pallas"`` / ``"interpret"`` — the exact pass runs on
+* ``"pallas"`` / ``"interpret"`` — the block bounds come from
+  ``repro.kernels.block_bounds``, which walks each query term's own
+  block-max entries in place (one 1,024-entry tile a grid step) instead of
+  gathering ``L × bcap`` padded lanes; the exact pass runs on
   ``repro.kernels.blockmax_score`` over the shard's **build-time bucketed
   postings mirror** (``IndexShard.tile_*``): a (query blocks, tile groups,
   lane chunks) grid where each step term-matches doc-tile buckets against a
@@ -27,7 +30,9 @@ a backend switch (see ``repro.isn.backend``):
   runs the identical kernel program under the Pallas interpreter on CPU
   (tests).
 * ``"jnp"`` — vectorized batched gather + one fused scatter over the CSR
-  mirror; identical results, the portable fast path on CPU hosts.
+  mirror (the block bounds too: ``_block_bounds_batched``, the reference
+  the bounds kernel is held to); identical results, the portable fast
+  path on CPU hosts.
 
 Exactly **one exact-scoring pass** runs per query: the phase-1 accumulator
 is kept and the exact pass only scores blocks in ``survive \\ phase1``
@@ -67,6 +72,7 @@ from repro.isn.backend import (SegmentLists, compact_lanes,
                                map_query_blocks, merge_segments,
                                deep_select_ran, query_lane_budget,
                                resolve_backend, topk_from_tiles)
+from repro.kernels.block_bounds.ops import block_bounds
 from repro.kernels.blockmax_score.ops import blockmax_score_tiles
 
 
@@ -193,7 +199,14 @@ def _kth_score(topk_out, k: int):
 def _daat_batched(shard: IndexShard, terms, mask, theta, *, n_docs: int,
                   n_blocks: int, block_size: int, k: int, cap: int,
                   bcap: int, qcap: int, tile_d: int, backend: str):
-    ub, ccnt = _block_bounds_batched(shard, terms, mask, n_blocks, bcap)
+    interpret = backend == "interpret"
+    if backend == "jnp":
+        ub, ccnt = _block_bounds_batched(shard, terms, mask, n_blocks, bcap)
+    else:
+        ub, ccnt = block_bounds(shard.bm_offsets, shard.bm_block_id,
+                                shard.bm_block_max, shard.bm_block_cnt,
+                                terms, mask, n_blocks=n_blocks, bcap=bcap,
+                                interpret=interpret)
     in_p1 = _phase1_blocks(ub, ccnt, block_size, k, n_blocks)
 
     if backend == "jnp":
@@ -204,7 +217,6 @@ def _daat_batched(shard: IndexShard, terms, mask, theta, *, n_docs: int,
         acc = acc1 + _score_pass(d, s, live, extra, n_docs, block_size)
         sc, ids = jax.lax.top_k(acc, k)
     else:
-        interpret = backend == "interpret"
         qterms = jnp.where(mask > 0, terms, -1).astype(jnp.int32)
         acc1_t = blockmax_score_tiles(
             shard.tile_docs, shard.tile_terms, shard.tile_scores, qterms,

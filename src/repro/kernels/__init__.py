@@ -33,6 +33,13 @@ lane chunk keeps VMEM and compile time independent of ``tile_cap``.
   ragged-block pattern of paged attention), accumulating into the query's
   output column.  Its oracle is the numpy ``repro.ltr.ranker.qd_features``
   loop, which it matches bit for bit.
+* ``block_bounds`` — BMW's per-(query, block) upper bounds and candidate
+  counts, read from the shard's block-max CSR in place as ``(rows, 128)``
+  tables with ``qd_feature_gather``'s step tables: each step folds one
+  1,024-entry tile of one query term's entries into the query's blocks
+  with bf16 one-hot MXU passes over the 128-block chunks it touches.  The
+  tests hold it to a numpy loop over the slots, bit for bit, and to the
+  jnp backend's ``repro.isn.daat._block_bounds_batched``.
 * ``dense_topk`` — dense Stage-1: tiled query×doc scores on the MXU, then
   the tiled top-k merge (``repro.kernels.topk.topk_from_tiles``).
 * ``topk`` — the tiled top-k merge over the kernels' accumulator tiles.

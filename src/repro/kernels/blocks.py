@@ -18,6 +18,9 @@ LANES = 128
 TILES_PER_STEP = 8        # doc tiles per grid step (a sublane group)
 MAX_QUERY_ROWS = 64       # query rows sharing one pass over the buckets
 LANE_CHUNKS = (2048, 1024, 512, 256, 128)
+# entries of a CSR read in place as (rows, 128) tables, one (8, 128) tile a
+# grid step (``qd_feature_gather``'s postings, ``block_bounds``' block maxima)
+CSR_BLOCK = SUBLANES * LANES
 
 
 def round_up(x: int, m: int) -> int:

@@ -40,9 +40,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.blocks import LANES, SUBLANES
+from repro.kernels.blocks import CSR_BLOCK, LANES, SUBLANES
 
-BLOCK = SUBLANES * LANES        # postings per step: one (8, 128) tile
+BLOCK = CSR_BLOCK               # postings per step: one (8, 128) tile
 
 
 def _qd_gather_kernel(blk_ref, lo_ref, hi_ref, cand_ref, docs_ref,

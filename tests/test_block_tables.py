@@ -64,12 +64,18 @@ def test_shard_block_maxima_are_exact_scores(small_collection, part):
     np.add.at(want_cnt, (term, blk), 1)
     off = np.asarray(shard.bm_offsets)
     row = np.repeat(np.arange(index.vocab), np.diff(off))
+    # the CSR is padded to whole 1,024-entry tiles with inert entries
+    n = int(off[-1])
+    assert spec.n_block_entries == len(shard.bm_block_id)
+    assert len(shard.bm_block_id) % 1024 == 0
+    assert len(shard.bm_block_id) - n < 1024
+    for col in (shard.bm_block_id, shard.bm_block_max, shard.bm_block_cnt):
+        assert not np.asarray(col)[n:].any()
+    bid = np.asarray(shard.bm_block_id)[:n]
     got_max = np.zeros_like(want_max)
-    got_max[row, np.asarray(shard.bm_block_id)] = np.asarray(
-        shard.bm_block_max)
+    got_max[row, bid] = np.asarray(shard.bm_block_max)[:n]
     got_cnt = np.zeros_like(want_cnt)
-    got_cnt[row, np.asarray(shard.bm_block_id)] = np.asarray(
-        shard.bm_block_cnt)
+    got_cnt[row, bid] = np.asarray(shard.bm_block_cnt)[:n]
     np.testing.assert_array_equal(got_max, want_max)
     np.testing.assert_array_equal(got_cnt, want_cnt)
     assert spec.max_blocks_per_term == int((want_cnt > 0).sum(axis=1).max())

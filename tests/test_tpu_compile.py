@@ -25,6 +25,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import gbrt
 from repro.core import trees as T
+from repro.index.postings import IndexShardSpec, shard_specs
+from repro.isn.daat import daat_serve
 from repro.kernels.blockmax_score.ops import blockmax_score_tiles
 from repro.kernels.dense_topk.ops import dense_topk
 from repro.kernels.impact_accumulate.ops import impact_accumulate_tiles
@@ -118,6 +120,56 @@ def test_blockmax_score_compiles_one_sublane_group(one_chip):
     _compile(fn, "blockmax_score_batched", one_chip, (mirror, jnp.int32),
              (mirror, jnp.int32), (mirror, jnp.float32), ((q, L), jnp.int32),
              ((q, n_blocks), jnp.bool_))
+
+
+# BMW in the benchmark's cells: docs, terms, postings after the stoplist,
+# block-max entries (whole 1,024-entry tiles), max df, tile lane capacity,
+# served depth
+BMW_CELLS = {
+    "msmarco": (1 << 18, 998_341, 7_154_366, 5_128_192, 200_000, 8192, 1000),
+    "cw09b": (S2_DOCS, S2_VOCAB, 9_889_794, 6_001_664, 30_000,
+              CELL_TILE_CAP, C),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(BMW_CELLS))
+def test_bmw_program_reads_block_maxima_in_place(one_chip, cell):
+    """The served BMW program at a cell's shard (its block-max CSR, 64-doc
+    blocks, a term in every block) for an 8-query batch: block bounds come
+    from the ``bmw_block_bounds`` kernel under its stable name, beside the
+    scoring kernel, and the CSR's columns reach it as bitcasts of the
+    shard's arrays: no op relayouts, gathers from or pads a table-sized
+    array.  (XLA may move one into fast memory ahead of its use, an async
+    ``copy-start``/``copy-done`` pair, as it does with the scoring kernel's
+    mirror at some batch widths.)"""
+    n_docs, vocab, postings, entries, max_df, tile_cap, k = BMW_CELLS[cell]
+    n_blocks = n_docs // BLOCK
+    spec = IndexShardSpec(
+        n_docs=n_docs, vocab=vocab, n_postings=postings, n_blocks=n_blocks,
+        n_block_entries=entries, n_levels=256, block_size=BLOCK,
+        max_df=max_df, max_blocks_per_term=n_blocks, quant_scale=1.0,
+        tile_d=TILE_D, tile_cap=tile_cap, n_tiles=n_docs // TILE_D)
+    shard = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        shard_specs(spec))
+
+    def fn(shard, terms, mask, theta):
+        return daat_serve(shard, terms, mask, theta, n_docs=n_docs,
+                          n_blocks=n_blocks, block_size=BLOCK, k=k,
+                          cap=max_df, bcap=n_blocks, tile_d=TILE_D,
+                          backend="pallas")
+    q = 8
+    text = jax.jit(fn).lower(shard, *[
+        jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in (
+            ((q, L), jnp.int32), ((q, L), jnp.float32),
+            ((q,), jnp.float32))]).compile().as_text()
+    for kernel in ("bmw_block_bounds", "blockmax_score_batched"):
+        assert re.search(rf"%{kernel}(\.\d+)? = [^\n]*"
+                         r'custom_call_target="tpu_custom_call"', text)
+    rows = entries // 128
+    ops = re.findall(rf"%\S+ = \(?\w+\[(?:{entries}|{rows},128)\]\S* "
+                     r"([\w-]+)\(", text)
+    assert ops and set(ops) <= {"parameter", "bitcast", "copy-done"}, ops
 
 
 @pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32])
