@@ -27,7 +27,7 @@ from repro.dense.embeddings import embed_queries
 from repro.isn.backend import merge_shard_topk
 from repro.kernels.dense_topk.ops import dense_topk
 from repro.kernels.dense_topk.ref import dense_topk_oracle
-from repro.serving.telemetry.spans import fetch
+from repro.serving.telemetry.spans import DISPATCH, fetch, span
 
 SCORE_FILL = float(np.finfo(np.float32).min)
 
@@ -110,11 +110,13 @@ class DenseEngine:
         already enforces for the lexical grid.
         """
         sc_list, id_list = [], []
-        for s in range(self.n_shards):
-            sc, ids = dense_topk(jnp.asarray(q_emb), self.shard_emb[s], k,
-                                 tile_d=self.tile_d, backend=self.backend)
-            sc_list.append(sc)
-            id_list.append(ids + self.doc_lo[s])
+        with span(DISPATCH):
+            for s in range(self.n_shards):
+                sc, ids = dense_topk(jnp.asarray(q_emb), self.shard_emb[s],
+                                     k, tile_d=self.tile_d,
+                                     backend=self.backend)
+                sc_list.append(sc)
+                id_list.append(ids + self.doc_lo[s])
         if self.n_shards == 1 and self.delta_emb is None:
             ids, sc = fetch(id_list[0], sc_list[0])
             ids = ids.astype(np.int64)
@@ -130,8 +132,10 @@ class DenseEngine:
             # docs from the candidate list. A full ranking plus post-mask
             # makes padding provably inert.
             cap = int(self.delta_emb.shape[0])
-            dsc, dids = dense_topk(jnp.asarray(q_emb), self.delta_emb, cap,
-                                   tile_d=self.tile_d, backend=self.backend)
+            with span(DISPATCH):
+                dsc, dids = dense_topk(jnp.asarray(q_emb), self.delta_emb,
+                                       cap, tile_d=self.tile_d,
+                                       backend=self.backend)
             dsc, dids = fetch(dsc, dids)
             dsc = dsc.copy()
             ghost = dids >= self.delta_live
@@ -143,7 +147,9 @@ class DenseEngine:
                 drop = np.concatenate(
                     [np.asarray(drop),
                      np.zeros((1, np.asarray(drop).shape[1]), bool)])
-        ids, sc = fetch(*merge_shard_topk(sc_list, id_list, k, drop=drop))
+        with span(DISPATCH):
+            merged = merge_shard_topk(sc_list, id_list, k, drop=drop)
+        ids, sc = fetch(*merged)
         return ids.astype(np.int64), sc
 
     def oracle(self, q_emb: np.ndarray, k: int):

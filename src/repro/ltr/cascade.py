@@ -27,7 +27,7 @@ import numpy as np
 from repro.core import gbrt
 from repro.ltr.ranker import (LTRModel, Stage2Arrays, csr_search_iters,
                               qd_features, qd_features_batched)
-from repro.serving.telemetry.spans import fetch
+from repro.serving.telemetry.spans import DISPATCH, fetch, span
 
 
 @dataclass
@@ -128,11 +128,13 @@ def rerank_batched(arrs: Stage2Arrays, ltr: LTRModel, terms, mask, topics,
                 f"total ({lane_need}); size it with "
                 f"repro.isn.backend.query_lane_budget")
     model = ltr.model
-    final, used = fetch(*_rerank_program(
-        arrs, model.forest, model.base, model.bin_edges,
-        np.asarray(terms, np.int32), np.asarray(mask, np.float32),
-        np.asarray(topics, np.int32), np.asarray(cand, np.int32),
-        np.asarray(k_per_query, np.int32), params=model.params,
-        t_final=t_final, n_iter=n_iter, backend=backend, qcap=qcap))
+    with span(DISPATCH):
+        out = _rerank_program(
+            arrs, model.forest, model.base, model.bin_edges,
+            np.asarray(terms, np.int32), np.asarray(mask, np.float32),
+            np.asarray(topics, np.int32), np.asarray(cand, np.int32),
+            np.asarray(k_per_query, np.int32), params=model.params,
+            t_final=t_final, n_iter=n_iter, backend=backend, qcap=qcap)
+    final, used = fetch(*out)
     return CascadeResult(final=final.astype(np.int64),
                          candidates_used=used.astype(np.int64))

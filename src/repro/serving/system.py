@@ -76,9 +76,10 @@ from repro.serving.scheduler import (RoutedBatch, SchedulerConfig,
                                      StageZeroScheduler)
 from repro.serving.spec import CascadeSpec, RoutingSpec
 from repro.serving.telemetry import QueryTrace, Span, Telemetry
-from repro.serving.telemetry.spans import (ACCOUNT, CACHE, REPLICAS, SERVE,
-                                           STAGE0, STAGE1, STAGE1_BMW,
-                                           STAGE1_JASS, STAGE2, fetch, span)
+from repro.serving.telemetry.spans import (ACCOUNT, CACHE, DISPATCH,
+                                           REPLICAS, SERVE, STAGE0, STAGE1,
+                                           STAGE1_BMW, STAGE1_JASS, STAGE2,
+                                           fetch, span)
 from repro.serving.telemetry.export import (legacy_stats_view,
                                             render_json,
                                             render_prometheus)
@@ -485,14 +486,17 @@ class SearchSystem:
         if self.models is None:
             raise RuntimeError("no Stage-0 models: call fit() or "
                                "set_models() first")
-        x = F.extract(self.term_stats, self.df, jnp.asarray(terms),
-                      jnp.asarray(mask))
-        if self._stacked is not None:
-            p = np.expm1(fetch(gbrt.predict_stacked(
-                self._stacked, x, self._stack_depth))[0])
-            return p[0], p[1], p[2]
-        return tuple(np.expm1(p) for p in fetch(
-            *(gbrt.predict(self.models[n], x) for n in ("k", "rho", "t"))))
+        with span(DISPATCH):
+            x = F.extract(self.term_stats, self.df, jnp.asarray(terms),
+                          jnp.asarray(mask))
+            if self._stacked is not None:
+                preds = (gbrt.predict_stacked(self._stacked, x,
+                                              self._stack_depth),)
+            else:
+                preds = tuple(gbrt.predict(self.models[n], x)
+                              for n in ("k", "rho", "t"))
+        p = [np.expm1(a) for a in fetch(*preds)]
+        return tuple(p[0]) if self._stacked is not None else tuple(p)
 
     def _modality(self, pt: np.ndarray) -> np.ndarray:
         """Stage-0 modality dispatch from the predicted lexical time:
@@ -656,31 +660,35 @@ class SearchSystem:
                             for w in work_s]
                 t_q, m_q, dr, pad = padded(rows)
                 # pad rows have no terms: any budget scores nothing
-                out = saat_serve_segments(
-                    segs, t_q, m_q, [np.pad(r, (0, pad)) for r in rhos],
-                    k=self.k_serve, cap=int(self.sched.cfg.rho_max),
-                    backend=self.backend, drop=dr, engine=saat_serve)
+                with span(DISPATCH):
+                    out = saat_serve_segments(
+                        segs, t_q, m_q, [np.pad(r, (0, pad)) for r in rhos],
+                        k=self.k_serve, cap=int(self.sched.cfg.rho_max),
+                        backend=self.backend, drop=dr, engine=saat_serve)
                 work, _ = gather(rows, out)
-                self._stage1_counters["deep_select_rows"] += \
-                    len(rows) * out.deep_select
-                stats["jass_pad_rows"] += pad
-                stats["jass_postings"] += int(sum(w.sum() for w in work))
-                t_shards[:, rows] = self._shard_times(work)
+                with span(ACCOUNT):
+                    self._stage1_counters["deep_select_rows"] += \
+                        len(rows) * out.deep_select
+                    stats["jass_pad_rows"] += pad
+                    stats["jass_postings"] += int(sum(w.sum() for w in work))
+                    t_shards[:, rows] = self._shard_times(work)
 
         if len(routed.bmw_rows):
             with span(STAGE1_BMW):
                 rows = routed.bmw_rows
                 t_q, m_q, dr, pad = padded(rows)
-                out = daat_serve_segments(
-                    segs, t_q, m_q, jnp.ones(len(t_q), jnp.float32),
-                    k=self.k_serve, backend=self.backend, drop=dr,
-                    engine=daat_serve)
+                with span(DISPATCH):
+                    out = daat_serve_segments(
+                        segs, t_q, m_q, jnp.ones(len(t_q), jnp.float32),
+                        k=self.k_serve, backend=self.backend, drop=dr,
+                        engine=daat_serve)
                 work, blocks = gather(rows, out)
-                self._stage1_counters["deep_select_rows"] += \
-                    len(rows) * out.deep_select
-                stats["bmw_pad_rows"] += pad
-                t_shards[:, rows] = self._shard_times(work, blocks)
-                t_bmw[rows] = self.cost.gather_time(t_shards[:, rows])
+                with span(ACCOUNT):
+                    self._stage1_counters["deep_select_rows"] += \
+                        len(rows) * out.deep_select
+                    stats["bmw_pad_rows"] += pad
+                    t_shards[:, rows] = self._shard_times(work, blocks)
+                    t_bmw[rows] = self.cost.gather_time(t_shards[:, rows])
         return topk, topk_sc, t_bmw, t_shards
 
     def stage2(self, terms, mask, topics, cand, k_per_query) -> CascadeResult:
@@ -792,9 +800,10 @@ class SearchSystem:
                 self.pool.complete(r, latency=float(t_shards[s, i]))
         if hedge_picks:
             rows = np.fromiter(hedge_picks, dtype=np.int64)
-            work_s, _ = self._jass_split(terms, mask, rows,
-                                         routed.rho[rows], cache)
-            t_h = self._shard_times(work_s)
+            with span(ACCOUNT):
+                work_s, _ = self._jass_split(terms, mask, rows,
+                                             routed.rho[rows], cache)
+                t_h = self._shard_times(work_s)
             for j, i in enumerate(rows):
                 reps = hedge_picks[int(i)]
                 if reps is None:
@@ -928,10 +937,11 @@ class SearchSystem:
                     drop=None if drop is None else drop[:, d_rows])
                 # shape-static per-shard dense time: every query scores every
                 # tile of every shard, so the matrix is query-independent
-                t_dense_mat = np.zeros((ns, q))
-                for s in range(ns):
-                    t_dense_mat[s, d_rows] = float(
-                        self.cost.dense_time(self.dense.n_tiles(s)))
+                with span(ACCOUNT):
+                    t_dense_mat = np.zeros((ns, q))
+                    for s in range(ns):
+                        t_dense_mat[s, d_rows] = float(
+                            self.cost.dense_time(self.dense.n_tiles(s)))
                 dmod = modality[d_rows]
                 only_rows = d_rows[dmod == M_DENSE]
                 both_rows = d_rows[dmod == M_BOTH]
@@ -970,8 +980,9 @@ class SearchSystem:
                             terms, mask, fb_routed, split_cache)
                         topk[fb_rows] = fb_topk[fb_rows]
                         topk_sc[fb_rows] = fb_sc[fb_rows]
-                        fb_extra[fb_rows] = self.cost.gather_time(
-                            fb_tsh[:, fb_rows])
+                        with span(ACCOUNT):
+                            fb_extra[fb_rows] = self.cost.gather_time(
+                                fb_tsh[:, fb_rows])
                         fallback[fb_rows] = True
 
         with span(ACCOUNT):
@@ -1099,10 +1110,12 @@ class SearchSystem:
                     # push it over.
                     # When the Stage-1 bound holds, the Stage-2 reservation
                     # guarantees afford >= k_serve and this is a no-op.
-                    afford = stage2_afford(self.cost, self.budget - lat01,
-                                           self.k_serve)
-                    trimmed = int(np.sum((0 < afford) & (afford < k2)))
-                    skipped = int(np.sum((afford == 0) & (k2 > 0)))
+                    with span(ACCOUNT):
+                        afford = stage2_afford(self.cost,
+                                               self.budget - lat01,
+                                               self.k_serve)
+                        trimmed = int(np.sum((0 < afford) & (afford < k2)))
+                        skipped = int(np.sum((afford == 0) & (k2 > 0)))
                     k2 = np.minimum(k2, afford)
                 cand = topk if drop is None else np.where(topk >= 0, topk, 0)
                 res2 = self.stage2(terms, mask, topics,
@@ -1114,8 +1127,9 @@ class SearchSystem:
                     # stage1-only rung) serve their Stage-1 order directly
                     # (the rank-safe list) at zero Stage-2 cost
                     final[skip_rows] = topk[skip_rows, :self.t_final]
-                stage_latency["stage2"] = np.where(
-                    used > 0, self.cost.ltr_time(used), 0.0)
+                with span(ACCOUNT):
+                    stage_latency["stage2"] = np.where(
+                        used > 0, self.cost.ltr_time(used), 0.0)
             else:
                 stage_latency["stage2"] = np.zeros(q)
 
@@ -1356,12 +1370,13 @@ class SearchSystem:
                     # same enforcement as the cold path, priced at the hit's
                     # actual stage-1 cost — a hit has the slack to afford the
                     # full grid whenever the reserve holds
-                    afford = stage2_afford(
-                        self.cost,
-                        self.budget - (self.cost.predict_us + hit_us),
-                        self.k_serve)
-                    trimmed += int(np.sum((0 < afford) & (afford < k2)))
-                    skipped += int(np.sum((afford == 0) & (k2 > 0)))
+                    with span(ACCOUNT):
+                        afford = stage2_afford(
+                            self.cost,
+                            self.budget - (self.cost.predict_us + hit_us),
+                            self.k_serve)
+                        trimmed += int(np.sum((0 < afford) & (afford < k2)))
+                        skipped += int(np.sum((afford == 0) & (k2 > 0)))
                     k2 = np.minimum(k2, afford)
                 res2 = self.stage2(terms[rows2], mask[rows2], topics[rows2],
                                    cand.astype(np.int32), k2)
@@ -1372,7 +1387,9 @@ class SearchSystem:
                 for j, i in enumerate(rows2):
                     final_rows[i] = f2[j]
                     used[i] = u2[j]
-                t2[rows2] = np.where(u2 > 0, self.cost.ltr_time(u2), 0.0)
+                with span(ACCOUNT):
+                    t2[rows2] = np.where(u2 > 0, self.cost.ltr_time(u2),
+                                         0.0)
             with span(CACHE):
                 # promote: the fresh full-coverage re-rank is exactly an L1
                 # entry for this (query, route, stage-2 params) point

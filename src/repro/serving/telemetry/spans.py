@@ -10,10 +10,19 @@ microsecond) and records nothing.
 
 ``cascade.serve`` is the root of one ``SearchSystem.serve`` call; the
 stage spans below it are disjoint.  Inside ``cascade.stage1`` each engine
-branch that serves rows is one ``cascade.jass`` or ``cascade.bmw``, and
-``cascade.sync`` nests inside whichever span waits for the device
-(:func:`fetch`, the serve path's one blocking read per stage or engine
-branch).
+branch that serves rows is one ``cascade.jass`` or ``cascade.bmw``.  Three
+spans may nest inside any stage: ``cascade.dispatch`` where the host builds
+arguments, copies them to the device and enqueues device programs, ending
+before the read-back it feeds; ``cascade.sync`` where it waits for the
+device (:func:`fetch`, the serve path's one blocking read per stage or
+engine branch); and ``cascade.account`` where it does virtual-clock
+bookkeeping (cost-model times, stats), which also has spans of its own
+between the stages.  A stage's time outside its child spans is its host
+numpy work.
+
+Every Python garbage collection is a ``python.gc`` span, in every run,
+carrying the collection's ``generation``: the hook is installed when this
+module is imported.
 """
 
 from __future__ import annotations
@@ -33,9 +42,10 @@ REPLICAS = "cascade.replicas"  # replica picks, fault plan, pool feedback
 ACCOUNT = "cascade.account"    # virtual-clock latencies, stats, traces
 CACHE = "cascade.cache"        # serving-cache lookup and fill
 SYNC = "cascade.sync"          # one blocking device-to-host read
-GC = "python.gc"               # a Python garbage collection (gc_spans)
+DISPATCH = "cascade.dispatch"  # arguments built, copied in, programs enqueued
+GC = "python.gc"               # a Python garbage collection
 NAMES = (SERVE, STAGE0, STAGE1, STAGE1_JASS, STAGE1_BMW, STAGE2, REPLICAS,
-         ACCOUNT, CACHE, SYNC, GC)
+         ACCOUNT, CACHE, SYNC, DISPATCH, GC)
 
 
 def span(name: str) -> TraceAnnotation:
@@ -54,18 +64,14 @@ _gc_open: list = []
 
 
 def _gc_hook(phase: str, info: dict) -> None:
+    """Open a ``python.gc`` span when a collection starts, close it when
+    the collection stops (both on the collecting thread)."""
     if phase == "start":
-        ann = TraceAnnotation(GC)
+        ann = TraceAnnotation(GC, generation=info["generation"])
         ann.__enter__()
         _gc_open.append(ann)
     elif _gc_open:
         _gc_open.pop().__exit__(None, None, None)
 
 
-def gc_spans(on: bool) -> None:
-    """Mark every Python garbage collection as a ``python.gc`` span
-    (``on``), or stop marking them."""
-    if on and _gc_hook not in gc.callbacks:
-        gc.callbacks.append(_gc_hook)
-    elif not on and _gc_hook in gc.callbacks:
-        gc.callbacks.remove(_gc_hook)
+gc.callbacks.append(_gc_hook)

@@ -1,8 +1,11 @@
 """Wall-clock spans of ``SearchSystem.serve`` in the JAX profiler trace:
 each call is one ``cascade.serve`` holding the stage spans in order, every
-blocking read is one ``cascade.sync``, garbage collections show as
-``python.gc``, and none of it changes an answer."""
+blocking read is one ``cascade.sync`` after the ``cascade.dispatch`` that
+launched its work, the virtual-clock bookkeeping runs inside
+``cascade.account``, garbage collections show as ``python.gc`` in every
+run, and none of it changes an answer."""
 
+import contextlib
 import dataclasses
 import gc
 import sys
@@ -102,6 +105,9 @@ def test_serve_spans_nest_in_profiler_trace(tiny, tmp_path, cached):
 
 
 def test_spans_and_gc_spans_leave_answers_bit_identical(tiny, tmp_path):
+    """The collection hook is installed on import, with no switch; serving
+    traced, with it and a forced collection, answers as serving untraced
+    without it."""
     corpus, index, ql, system = tiny
 
     def serve_all():
@@ -110,15 +116,15 @@ def test_spans_and_gc_spans_leave_answers_bit_identical(tiny, tmp_path):
         return [fresh.serve(ql.terms[b], ql.mask[b], ql.topic[b])
                 for b in (slice(0, 8), slice(8, 16))]
 
-    plain = serve_all()
-    S.gc_spans(True)
+    assert gc.callbacks.count(S._gc_hook) == 1
+    gc.callbacks.remove(S._gc_hook)
     try:
-        with jax.profiler.trace(str(tmp_path)):
-            gc.collect()
-            marked = serve_all()
+        plain = serve_all()
     finally:
-        S.gc_spans(False)
-    assert S._gc_hook not in gc.callbacks
+        gc.callbacks.append(S._gc_hook)
+    with jax.profiler.trace(str(tmp_path)):
+        gc.collect()
+        marked = serve_all()
     for a, b in zip(plain, marked):
         np.testing.assert_array_equal(a.topk, b.topk)
         np.testing.assert_array_equal(a.final, b.final)
@@ -126,6 +132,87 @@ def test_spans_and_gc_spans_leave_answers_bit_identical(tiny, tmp_path):
         np.testing.assert_array_equal(a.latency, b.latency)
     names = [ev[0] for ev in hostspans.load(hostspans.newest(tmp_path))]
     assert S.GC in names and S.SERVE in names
+
+
+def test_gc_spans_carry_their_generation_without_a_switch(tmp_path):
+    """A trace with nothing turned on holds one ``python.gc`` span around
+    a forced full collection, with ``generation`` 2 as its argument."""
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(tmp_path)):
+        gc.collect()
+    pd = ProfileData.from_file(hostspans.newest(tmp_path))
+    gens = [dict(ev.stats).get("generation")
+            for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.name == S.GC]
+    assert 2 in gens
+
+
+def test_every_read_back_follows_its_dispatch(tiny, tmp_path):
+    """Each ``cascade.sync`` (Stage-0's, each engine branch's, Stage-2's)
+    has a ``cascade.dispatch`` before it inside the same stage or engine
+    span, ended before the wait starts."""
+    corpus, index, ql, system = tiny
+    _, spans = _traced(system, ql, tmp_path, [slice(0, 8), slice(8, 16)])
+    holders = (S.STAGE0, S.STAGE1_JASS, S.STAGE1_BMW, S.STAGE2)
+    syncs = [(s, d) for name, s, d in spans if name == S.SYNC]
+    # both engines serve rows of each batch: four read-backs a call
+    assert len(syncs) == 8
+    held = []
+    for s, d in syncs:
+        (name, hs, hd), = [(n2, s2, d2) for n2, s2, d2 in spans
+                           if n2 in holders and s2 <= s
+                           and s + d <= s2 + d2]
+        held.append(name)
+        assert any(n2 == S.DISPATCH and hs <= s2 and s2 + d2 <= s
+                   for n2, s2, d2 in spans)
+    assert held == list(holders) * 2
+
+
+def test_virtual_clock_work_runs_inside_account_spans(tiny, monkeypatch):
+    """``_build_stats``, ``_shard_times``, ``stage2_afford``, ``ltr_time``
+    and ``gather_time`` run only inside a ``cascade.account`` span,
+    wherever in the call they run, and each runs."""
+    import repro.serving.system as system_mod
+
+    corpus, index, ql, system = tiny
+    spec = system.cascade_spec
+    assert spec.routing.enforce_budget
+    fresh = build_system(spec, index, corpus=corpus, models=system.models,
+                         ltr=system.ltr)
+    open_spans, calls = [], []
+    real_span = system_mod.span
+
+    @contextlib.contextmanager
+    def counted(name):
+        with real_span(name):
+            open_spans.append(name)
+            try:
+                yield
+            finally:
+                open_spans.pop()
+
+    def watch(owner, name):
+        fn = getattr(owner, name)
+
+        def wrapped(*args, **kw):
+            calls.append((name, S.ACCOUNT in open_spans))
+            return fn(*args, **kw)
+        monkeypatch.setattr(owner, name, wrapped)
+
+    monkeypatch.setattr(system_mod, "span", counted)
+    watch(system_mod.SearchSystem, "_build_stats")
+    watch(system_mod.SearchSystem, "_shard_times")
+    watch(system_mod, "stage2_afford")
+    watch(type(fresh.cost), "ltr_time")
+    watch(type(fresh.cost), "gather_time")
+    for b in (slice(0, 8), slice(8, 16)):
+        fresh.serve(ql.terms[b], ql.mask[b], ql.topic[b])
+    assert {name for name, _ in calls} == {
+        "_build_stats", "_shard_times", "stage2_afford", "ltr_time",
+        "gather_time"}
+    assert [name for name, inside in calls if not inside] == []
 
 
 def test_fetch_reads_every_leaf_in_one_sync_span(tmp_path):
@@ -138,7 +225,7 @@ def test_fetch_reads_every_leaf_in_one_sync_span(tmp_path):
     np.testing.assert_array_equal(d["y"], np.asarray(x) * 2)
     names = [ev[0] for ev in hostspans.load(hostspans.newest(tmp_path))]
     assert names.count(S.SYNC) == 1
-    assert set(S.NAMES) >= {S.SERVE, S.SYNC, S.GC}
+    assert set(S.NAMES) >= {S.SERVE, S.SYNC, S.DISPATCH, S.GC}
 
 
 def test_engine_spans_and_pad_counters(tiny, tmp_path):
